@@ -32,10 +32,9 @@ XLA pipeline. The env fingerprint (jax, jaxlib, backend, device count;
 `store.keys.env_fingerprint`) gates deserialization exactly as
 `utils/compile_cache_dir.py` gates the jax-internal persistent cache:
 an executable from a different build or topology is unreachable, never
-fatal. Serialization support varies by backend/version, so both
-directions degrade silently to a plain compile (`store_errors` counts
-the degradations; hit/miss accounting feeds `bench.py`'s `warm_start`
-section).
+fatal. A blob that cannot be loaded or published degrades to a plain
+compile with a warning, and `store_errors` counts it (hit/miss
+accounting feeds `bench.py`'s `warm_start` section).
 """
 
 from __future__ import annotations
@@ -125,9 +124,9 @@ class CompileCache:
         #: Persistent-tier accounting: `store_hits` skipped an XLA
         #: compile entirely (deserialized from the shared store);
         #: `store_misses` compiled fresh (and, when serializable,
-        #: published); `store_errors` counts silent degradations
-        #: (serialize/deserialize unsupported or a corrupt/unhealable
-        #: blob) — those fall back to a plain compile.
+        #: published); `store_errors` counts degradations (a failed
+        #: serialize/deserialize or a corrupt/unhealable blob) — those
+        #: fall back to a plain compile with a warning.
         self._m_store_hits = reg.counter("compile_cache.store_hits").child()
         self._m_store_misses = reg.counter(
             "compile_cache.store_misses"
@@ -158,7 +157,7 @@ class CompileCache:
 
     @property
     def store_errors(self) -> int:
-        """Silent persistent-tier degradations to a plain compile."""
+        """Persistent-tier degradations to a plain compile."""
         return self._m_store_errors.value
 
     def _store_ref_name(self, digest: str, device_fp, in_tree, out_tree):
@@ -178,8 +177,9 @@ class CompileCache:
             store_keys.env_fingerprint()[:16],
         )
 
-    def _store_load(self, ref_name: str):
-        """Deserializes a previously published executable, or None."""
+    def _store_load(self, ref_name: str, devices):
+        """Deserializes a previously published executable onto `devices`
+        (the ones the program was lowered for), or None."""
         entry = self._store.get_ref(AOT_REF_KIND, ref_name)
         if entry is None:
             return None
@@ -191,8 +191,11 @@ class CompileCache:
             from jax.experimental import serialize_executable
 
             payload, in_tree, out_tree = pickle.loads(blob)
+            # Without `execution_devices` the executable loads onto
+            # EVERY device of the backend and then rejects its own
+            # single-device arguments.
             return serialize_executable.deserialize_and_load(
-                payload, in_tree, out_tree
+                payload, in_tree, out_tree, execution_devices=devices
             )
         except Exception as exc:
             # Unsupported backend, corrupt-and-unhealable blob, or a
@@ -273,7 +276,9 @@ class CompileCache:
                 ref_name = self._store_ref_name(
                     digest, device_fp, in_tree, out_tree
                 )
-                executable = self._store_load(ref_name)
+                executable = self._store_load(
+                    ref_name, list(lowered._lowering._device_list)
+                )
             if executable is not None:
                 self._m_store_hits.inc()
             else:

@@ -21,7 +21,6 @@ Run (8 virtual devices):
 from __future__ import annotations
 
 import argparse
-import os
 import tempfile
 
 import jax
@@ -44,24 +43,11 @@ def main():
     # Provision a virtual mesh when the backend is uninitialized (the
     # tests/conftest.py pattern; on a real pod, skip this and use the
     # live devices).
-    try:
-        from jax._src import xla_bridge
+    from jax._src import xla_bridge
 
-        if not xla_bridge._backends:
-            jax.config.update("jax_platforms", "cpu")
-            try:
-                jax.config.update("jax_num_cpu_devices", args.devices)
-            except AttributeError:
-                # Pre-0.5 JAX: no jax_num_cpu_devices option; the XLA
-                # flag is honored because the CPU backend has not
-                # initialized yet.
-                os.environ["XLA_FLAGS"] = os.environ.get(
-                    "XLA_FLAGS", ""
-                ) + " --xla_force_host_platform_device_count=%d" % (
-                    args.devices
-                )
-    except Exception:
-        pass
+    if not xla_bridge._backends:
+        jax.config.update("jax_platforms", "cpu")
+        jax.config.update("jax_num_cpu_devices", args.devices)
 
     import numpy as np
     import optax

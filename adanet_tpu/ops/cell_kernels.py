@@ -34,34 +34,24 @@ tolerance, tying the oracle to the framework's convolution semantics.
 
 Differentiability: custom VJP whose backward re-derives gradients from
 the reference (one extra forward — the NasNetConfig.remat trade), like
-`fused_sep_conv`. Graceful degradation mirrors `_tpu_lowering_ok`: a
-shape the Mosaic pipeline rejects falls back to the XLA reference path
-with a warning. Block sizes consult the store-persisted autotuner
-(`ops/tuning.py`) before the static VMEM heuristic.
+`fused_sep_conv`. Which shapes take the kernel is a static rule
+(`kernel_takes`); inside it a TPU compiler refusal raises. Block sizes
+consult the store-persisted autotuner (`ops/tuning.py`) before the
+static VMEM heuristic.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
-import logging
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
 from adanet_tpu.ops import tuning
-from adanet_tpu.ops.sepconv_kernels import (
-    _HAS_PALLAS,
-    _live_mesh,
-    _platform_dependent_prunes,
-    _same_pads,
-)
-
-if _HAS_PALLAS:
-    from jax.experimental import pallas as pl
-
-_LOG = logging.getLogger(__name__)
+from adanet_tpu.ops.sepconv_kernels import _same_pads
 
 # Per-tile VMEM budget (bytes): the whole state list of one cell must
 # stay resident, so the budget is tighter per example than the single
@@ -528,77 +518,31 @@ def _pallas_forward(
     )(prev, cur, *leaves)
 
 
-# Per-signature Mosaic-lowering validation, mirroring
-# sepconv_kernels._tpu_lowering_ok: a shape the real TPU pipeline
-# rejects degrades to the XLA reference path with one warning.
-_lowering_ok_cache: Dict[Any, bool] = {}
+def kernel_takes(prev_shape, cur_shape, filters: int, spec: CellSpec) -> bool:
+    """The static rule for which cells the Pallas kernel takes.
 
+    - `spec.stride == 1`: a reduction cell needs stride-2 slices of
+      VMEM-resident VALUES (`_sepconv_layer`, `_pool`, `_conv1x1`,
+      `_factorized_reduction`), which the TPU compiler refuses
+      (`vector.extract_strided_slice` takes unit strides only; open item
+      in ROADMAP queue A);
+    - both inputs at the same spatial resolution (the model resolves a
+      mismatch upstream via `_reduce_prev_layer`);
+    - one example's state list inside the VMEM budget (the batch axis is
+      the only grid dimension).
 
-def _shard_batch(shape, sharding=None):
-    """Per-shard shape under the framework's batch-axis data-parallel
-    convention (sepconv_kernels._shard_shapes, single-operand form)."""
-    if sharding is not None:
-        try:
-            return tuple(sharding.shard_shape(tuple(shape)))
-        except Exception:
-            pass
-    mesh = _live_mesh()
-    if mesh is None:
-        return tuple(shape)
-    axes = dict(mesh.shape)
-    data_size = axes.get("data")
-    if data_size is None:
-        data_size = 1
-        for n in axes.values():
-            data_size *= int(n)
-    if data_size and shape and shape[0] % data_size == 0:
-        return (shape[0] // data_size,) + tuple(shape[1:])
-    return tuple(shape)
-
-
-def _cell_lowering_ok(prev, cur, params, spec: CellSpec) -> bool:
-    try:
-        if jax.default_backend() != "tpu":
-            return True
-        tpus = [d for d in jax.local_devices() if d.platform == "tpu"]
-    except Exception:  # backend init failure: nothing to lower for
-        return True
-    if not tpus:
-        return True
-    prev_shape = _shard_batch(prev.shape, getattr(prev, "sharding", None))
-    cur_shape = _shard_batch(cur.shape, getattr(cur, "sharding", None))
-    key = (prev_shape, str(prev.dtype), cur_shape, str(cur.dtype), spec)
-    ok = _lowering_ok_cache.get(key)
-    if ok is None:
-        try:
-            with jax.default_device(tpus[0]):
-                jax.jit(
-                    functools.partial(
-                        _pallas_forward, spec=spec, interpret=False
-                    )
-                ).lower(
-                    jax.ShapeDtypeStruct(prev_shape, prev.dtype),
-                    jax.ShapeDtypeStruct(cur_shape, cur.dtype),
-                    jax.tree_util.tree_map(
-                        lambda leaf: jax.ShapeDtypeStruct(
-                            leaf.shape, leaf.dtype
-                        ),
-                        params,
-                    ),
-                ).compile()
-            ok = True
-        except Exception as exc:
-            _LOG.warning(
-                "Pallas fused cell failed to lower for TPU at signature "
-                "%s (%s: %s); using the XLA reference path for this "
-                "shape.",
-                key,
-                type(exc).__name__,
-                exc,
-            )
-            ok = False
-        _lowering_ok_cache[key] = ok
-    return ok
+    A cell inside the rule that the compiler refuses is an error, not a
+    fallback (tests/test_chip_compile.py compiles the real widths).
+    """
+    if spec.stride != 1:
+        return False
+    if tuple(prev_shape[1:3]) != tuple(cur_shape[1:3]):
+        return False
+    h, w = cur_shape[1], cur_shape[2]
+    per_example = _bytes_per_example(
+        spec, h, w, prev_shape[-1], cur_shape[-1], filters
+    )
+    return per_example <= _VMEM_BUDGET
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -642,35 +586,20 @@ def fused_cell(
 
     prev: [B, H, W, C_prev]; cur: [B, H, W, C_cur]; params from
     `init_cell_params`. Returns [B, H', W', filters * num_unused] in
-    cur's dtype. Falls back to the unfused `cell_reference` when Pallas
-    is unavailable, the inputs' spatial resolutions differ (the model
-    resolves that upstream via `_reduce_prev_layer` — out of this
-    kernel's scope), a single example overflows the VMEM budget, or the
-    live TPU rejects the lowering. `interpret=True` runs the kernel in
-    interpreter mode (the CPU oracle-test path). Platform choice is per
-    lowering platform (`jax.lax.platform_dependent`), matching
-    `fused_sep_conv`.
+    cur's dtype. Takes the Pallas kernel exactly when the cell is inside
+    `kernel_takes` (and `use_pallas`); otherwise the unfused
+    `cell_reference`, by that rule and never by a caught compiler error.
+    `interpret=True` runs the kernel in interpreter mode (the CPU
+    oracle-test path). Platform choice is per lowering platform
+    (`jax.lax.platform_dependent`), matching `fused_sep_conv`.
     """
-    if not (_HAS_PALLAS and use_pallas):
-        return cell_reference(prev, cur, params, spec)
-    if tuple(prev.shape[1:3]) != tuple(cur.shape[1:3]):
-        return cell_reference(prev, cur, params, spec)
-    h, w = cur.shape[1], cur.shape[2]
-    if (
-        _bytes_per_example(
-            spec, h, w, prev.shape[-1], cur.shape[-1], _cell_filters(params)
-        )
-        > _VMEM_BUDGET
+    if not (
+        use_pallas
+        and kernel_takes(prev.shape, cur.shape, _cell_filters(params), spec)
     ):
         return cell_reference(prev, cur, params, spec)
     if interpret:
         return _fused_cell_p(prev, cur, params, spec, True)
-    if not _cell_lowering_ok(prev, cur, params, spec):
-        return cell_reference(prev, cur, params, spec)
-    if not _platform_dependent_prunes():
-        if jax.default_backend() == "tpu":
-            return _fused_cell_p(prev, cur, params, spec, False)
-        return cell_reference(prev, cur, params, spec)
     return jax.lax.platform_dependent(
         prev,
         cur,

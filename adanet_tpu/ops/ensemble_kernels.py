@@ -9,24 +9,17 @@ a custom VJP so it stays differentiable for the mixture-weight solve
 XLA already fuses this pattern well; the kernel exists to (a) guarantee the
 fusion (one HBM read of the stacked logits, no [N, B, C] intermediates) and
 (b) serve as the repo's pattern for Pallas ops. On non-TPU backends the
-kernel runs in interpret mode or falls back to the jnp reference
-implementation, which is also the source of truth for tests.
+kernel runs in interpret mode (`kernel_is_interpreted`); the jnp
+reference implementation is the source of truth for tests.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
-
-try:  # Pallas is TPU/GPU-only at lowering time; import is safe everywhere.
-    from jax.experimental import pallas as pl
-
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
 
 
 def _combine_reference(stacked_logits, weights, bias):
@@ -80,6 +73,14 @@ def _combine_pallas(stacked_logits, weights, bias, interpret: bool):
     )(stacked_logits, weights, bias)
 
 
+def kernel_is_interpreted() -> bool:
+    """Whether `fused_weighted_combine` interprets its kernel in this
+    process: everywhere but on a TPU backend (the CPU tests have no
+    Mosaic). `chip_smoke.py` asserts this is False on the chip and that
+    the compiled program holds the kernel."""
+    return jax.default_backend() != "tpu"
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def fused_weighted_combine(
     stacked_logits, weights, bias, use_pallas: bool = True
@@ -93,10 +94,11 @@ def fused_weighted_combine(
       use_pallas: run the Pallas kernel (interpret mode off-TPU); False
         uses the jnp reference implementation.
     """
-    if not use_pallas or not _HAS_PALLAS:
+    if not use_pallas:
         return _combine_reference(stacked_logits, weights, bias)
-    interpret = jax.default_backend() != "tpu"
-    return _combine_pallas(stacked_logits, weights, bias, interpret)
+    return _combine_pallas(
+        stacked_logits, weights, bias, kernel_is_interpreted()
+    )
 
 
 def _fwd(stacked_logits, weights, bias, use_pallas):

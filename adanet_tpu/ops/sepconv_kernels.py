@@ -28,57 +28,16 @@ NasNetConfig.remat). The reference implementation is also the test oracle
 from __future__ import annotations
 
 import functools
-import logging
 
 import jax
 import jax.numpy as jnp
-
-_LOG = logging.getLogger(__name__)
-
-try:  # Pallas is TPU/GPU-only at lowering time; import is safe everywhere.
-    from jax.experimental import pallas as pl
-
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # Per-tile VMEM budget for choosing the batch block (bytes). Conservative:
-# input tile + f32 accumulator + output tile must fit alongside the
-# kernels in ~16 MB of VMEM.
+# input tile + f32 staging copy + f32 accumulator + output tile must fit
+# alongside the kernels in ~16 MB of VMEM.
 _VMEM_BUDGET = 6 * 1024 * 1024
-
-
-@functools.lru_cache(maxsize=None)
-def _platform_dependent_prunes() -> bool:
-    """Whether `lax.platform_dependent` drops dead branches at lowering.
-
-    Pre-0.5 JAX lowers EVERY branch on every platform, so a TPU-only
-    Pallas branch poisons CPU lowering ("Only interpret mode is
-    supported on CPU backend"). Probed once with a trivial kernel; when
-    False, `fused_sep_conv` picks its path at trace time from the
-    default backend instead.
-    """
-    if not _HAS_PALLAS:
-        return False
-
-    def _kernel(x_ref, o_ref):
-        o_ref[...] = x_ref[...]
-
-    def _tpu_branch(x):
-        return pl.pallas_call(
-            _kernel, out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype)
-        )(x)
-
-    def _probe(x):
-        return jax.lax.platform_dependent(
-            x, tpu=_tpu_branch, default=lambda y: y
-        )
-
-    try:
-        jax.jit(_probe).lower(jnp.zeros((8,), jnp.float32))
-        return True
-    except Exception:
-        return False
 
 
 def _same_pads(size: int, kernel: int, stride: int):
@@ -115,24 +74,28 @@ def sep_conv_reference(x, dw, pw, stride: int):
     )
 
 
-def _sepconv_kernel(x_ref, dw_ref, pw_ref, o_ref, *, kernel, stride, h_out, w_out):
-    """One batch tile: relu + depthwise MACs in f32, pointwise on the MXU."""
-    x = jnp.maximum(x_ref[...], 0).astype(jnp.float32)  # [bb, Hp, Wp, C]
-    bb, c = x.shape[0], x.shape[-1]
+def _sepconv_kernel(
+    x_ref, dw_ref, pw_ref, o_ref, relu_ref, *, kernel, stride, h_out, w_out
+):
+    """One batch tile: relu + depthwise MACs in f32, pointwise on the MXU.
+
+    The relu'd tile is staged once in an f32 VMEM scratch and every
+    shifted patch is a (strided) LOAD from that ref: Mosaic has strided
+    loads for 32-bit refs, but refuses a strided slice of a value
+    (`vector.extract_strided_slice` takes unit strides only), which is
+    what stride 2 would otherwise need.
+    """
+    relu_ref[...] = jnp.maximum(x_ref[...], 0).astype(jnp.float32)
+    bb, c = relu_ref.shape[0], relu_ref.shape[-1]
     acc = jnp.zeros((bb, h_out, w_out, c), jnp.float32)
     for i in range(kernel):  # static unroll: k² shifted MACs on the VPU
         for j in range(kernel):
-            patch = jax.lax.slice(
-                x,
-                (0, i, j, 0),
-                (
-                    bb,
-                    i + (h_out - 1) * stride + 1,
-                    j + (w_out - 1) * stride + 1,
-                    c,
-                ),
-                (1, stride, stride, 1),
-            )
+            patch = relu_ref[
+                :,
+                pl.ds(i, h_out, stride=stride),
+                pl.ds(j, w_out, stride=stride),
+                :,
+            ]
             acc = acc + patch * dw_ref[i, j, 0, :].astype(jnp.float32)
     # Pointwise: one MXU contraction over channels for the whole tile.
     pw = pw_ref[0, 0].astype(jnp.float32)  # [C, F]
@@ -198,143 +161,28 @@ def _pallas_forward(x, dw, pw, stride: int, interpret: bool, block_b=None):
         out_specs=pl.BlockSpec(
             (block_b, h_out, w_out, f), lambda i: (i, 0, 0, 0)
         ),
+        scratch_shapes=[pltpu.VMEM((block_b, hp, wp, c), jnp.float32)],
         interpret=interpret,
     )(xp, dw, pw)
 
 
-# Per-shape Mosaic-lowering validation results for this process. The
-# kernel had only ever lowered in interpret mode until a TPU was live
-# (round-4 advice): a shape the real Mosaic pipeline rejects must degrade
-# to the XLA reference path with a warning, not crash the training run.
-_lowering_ok_cache = {}
+def kernel_takes(x_shape, kernel: int, filters: int, stride: int) -> bool:
+    """The static rule for which shapes the Pallas kernel takes.
 
-
-def _live_mesh():
-    """The `jax.sharding.Mesh` context the caller is tracing under, or
-    None (private-API access tolerated: absence just means global-shape
-    validation, never a crash)."""
-    try:
-        from jax._src.mesh import thread_resources
-
-        mesh = thread_resources.env.physical_mesh
-        return None if mesh.empty else mesh
-    except Exception:  # pragma: no cover - private-API drift
-        return None
-
-
-def _shard_shapes(x, dw, pw):
-    """The per-shard shapes GSPMD will actually lower the kernel at.
-
-    Two detection sources, first hit wins per operand:
-
-    1. A concrete operand's own sharding (`Sharding.shard_shape`) — the
-       partitioner's exact answer, available on eager / `device_put`
-       operands.
-    2. A live `Mesh` context around the trace: the framework's
-       data-parallel convention (`distributed/mesh.py::shard_batch`) —
-       `x`'s leading batch axis shards over the `data` axis iff evenly
-       divisible (uneven batches replicate), conv weights replicate.
-
-    Operands that are plain-`jit` tracers outside any mesh context carry
-    no sharding on this jax and fall through to their global shapes —
-    the residual caveat documented in `_tpu_lowering_ok`.
+    The batch axis is the kernel's only grid dimension, so ONE example's
+    f32 working set (padded input + accumulator + output) has to fit the
+    VMEM budget; a larger example — e.g. an early ImageNet-resolution
+    cell with wide channels — goes to XLA. Every kernel size and stride
+    the NASNet cells use is inside the rule, and a shape inside it that
+    the TPU compiler refuses is an error, not a fallback
+    (tests/test_chip_compile.py compiles the real widths for a v5e).
     """
-    mesh = _live_mesh()
-    data_size = None
-    if mesh is not None:
-        axes = dict(mesh.shape)
-        data_size = axes.get("data")
-        if data_size is None:  # non-"data" mesh: full device product
-            size = 1
-            for n in axes.values():
-                size *= int(n)
-            data_size = size
-    shapes = []
-    for i, a in enumerate((x, dw, pw)):
-        shape = tuple(a.shape)
-        sharding = getattr(a, "sharding", None)
-        if sharding is not None:
-            try:
-                shapes.append(tuple(sharding.shard_shape(shape)))
-                continue
-            except Exception:
-                pass  # e.g. shape not partitionable by this sharding
-        if (
-            i == 0
-            and data_size
-            and shape
-            and shape[0] % data_size == 0
-        ):
-            shape = (shape[0] // data_size,) + shape[1:]
-        shapes.append(shape)
-    return tuple(shapes)
-
-
-def _tpu_lowering_ok(x, dw, pw, stride: int) -> bool:
-    """AOT-compiles the kernel for the live TPU at the PER-SHARD
-    shapes/dtypes the partitioner will hand it (once per shape signature
-    per process). True when TPU is not this process's default backend:
-    `platform_dependent`'s default branch serves the other platforms, so
-    there is nothing to validate (and a CPU-targeted trace on a TPU host
-    must not pay TPU compiles). LOCAL devices only — under multi-host
-    SPMD every process validates against its own addressable chip, so
-    the verdict (and therefore the traced branch) is identical across
-    processes.
-
-    Under jit + SPMD partitioning the caller's trace-time shapes are the
-    GLOBAL array shapes while GSPMD lowers the kernel at per-shard
-    shapes, so validation runs on `_shard_shapes` (ADVICE r5): exact for
-    unpartitioned calls, for concrete sharded operands, and for traces
-    inside a live `Mesh` context following the framework's batch-axis
-    data-parallel convention. The residual gap is a partitioned call
-    from a plain-`jit` tracer outside any mesh context (no sharding is
-    observable there) — that still validates at global shapes."""
-    try:
-        if jax.default_backend() != "tpu":
-            return True
-        tpus = [d for d in jax.local_devices() if d.platform == "tpu"]
-    except Exception:  # backend init failure: nothing to lower for
-        return True
-    if not tpus:
-        return True
-    x_shape, dw_shape, pw_shape = _shard_shapes(x, dw, pw)
-    key = (
-        x_shape,
-        str(x.dtype),
-        dw_shape,
-        str(dw.dtype),
-        pw_shape,
-        str(pw.dtype),
-        stride,
+    h, w, c = x_shape[1], x_shape[2], x_shape[3]
+    out_hw = -(-h // stride) * -(-w // stride)
+    bytes_per_example = 4 * (
+        (h + kernel) * (w + kernel) * c + out_hw * (c + filters)
     )
-    ok = _lowering_ok_cache.get(key)
-    if ok is None:
-        specs = [
-            jax.ShapeDtypeStruct(shape, a.dtype)
-            for shape, a in zip(
-                (x_shape, dw_shape, pw_shape), (x, dw, pw)
-            )
-        ]
-        try:
-            with jax.default_device(tpus[0]):
-                jax.jit(
-                    functools.partial(
-                        _pallas_forward, stride=stride, interpret=False
-                    )
-                ).lower(*specs).compile()
-            ok = True
-        except Exception as exc:
-            _LOG.warning(
-                "Pallas fused sep-conv failed to lower for TPU at "
-                "signature %s (%s: %s); using the XLA reference path for "
-                "this shape.",
-                key,
-                type(exc).__name__,
-                exc,
-            )
-            ok = False
-        _lowering_ok_cache[key] = ok
-    return ok
+    return bytes_per_example <= _VMEM_BUDGET
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -371,35 +219,23 @@ def fused_sep_conv(
     """relu → depthwise(k×k, SAME, `stride`) → pointwise(1×1).
 
     Shapes: x [B, H, W, C]; dw [k, k, 1, C]; pw [1, 1, C, F] → out
-    [B, H', W', F]. With `use_pallas=False` (or Pallas unavailable) runs
-    the XLA reference path; `interpret=True` runs the kernel in
-    interpreter mode (the CPU equivalence-test path). The TPU-vs-other
-    choice is made PER LOWERING PLATFORM (`jax.lax.platform_dependent`),
-    not from the default backend: the same traced program serves both the
-    accelerator and the predict-on-CPU fallback
-    (core/estimator.py `predict(on_cpu=True)`).
+    [B, H', W', F]. Takes the Pallas kernel exactly when the shape is
+    inside `kernel_takes` (and `use_pallas`); otherwise the XLA
+    reference, by that rule and never by a caught compiler error.
+    `interpret=True` runs the kernel in interpreter mode (the CPU
+    equivalence-test path). The TPU-vs-other choice is made PER LOWERING
+    PLATFORM (`jax.lax.platform_dependent`), not from the default
+    backend: lowered for a TPU the program always holds the kernel, and
+    the same traced program serves the predict-on-CPU path
+    (core/estimator.py `predict(on_cpu=True)`) through the reference.
     """
-    if not (_HAS_PALLAS and use_pallas):
-        return sep_conv_reference(x, dw, pw, stride)
-    # A single example larger than the VMEM budget cannot tile on the
-    # batch axis alone (this kernel's only grid dimension) — e.g. early
-    # ImageNet-resolution cells with wide channels. XLA handles those.
-    h, w, c = x.shape[1], x.shape[2], x.shape[3]
-    k, f = dw.shape[0], pw.shape[-1]
-    out_hw = -(-h // stride) * -(-w // stride)
-    bytes_per_example = 4 * (
-        (h + k) * (w + k) * c + out_hw * (c + f)
-    )
-    if bytes_per_example > _VMEM_BUDGET:
+    if not (
+        use_pallas
+        and kernel_takes(x.shape, dw.shape[0], pw.shape[-1], stride)
+    ):
         return sep_conv_reference(x, dw, pw, stride)
     if interpret:
         return _fused_sep_conv_p(x, dw, pw, stride, True)
-    if not _tpu_lowering_ok(x, dw, pw, stride):
-        return sep_conv_reference(x, dw, pw, stride)
-    if not _platform_dependent_prunes():
-        if jax.default_backend() == "tpu":
-            return _fused_sep_conv_p(x, dw, pw, stride, False)
-        return sep_conv_reference(x, dw, pw, stride)
     return jax.lax.platform_dependent(
         x,
         dw,

@@ -214,8 +214,11 @@ def _export_cascade(
     finally:
         shutil.rmtree(cheap_dir, ignore_errors=True)
     features = cascade.calibration_features
-    cheap_out = jax.device_get(cascade.predict_fn(features))
-    full_out = jax.device_get(predict_fn(features))
+    # Jitted: called bare, these forwards run op by op, which on an
+    # accelerator is one dispatch (and one small compile) per op of
+    # every member.
+    cheap_out = jax.device_get(jax.jit(cascade.predict_fn)(features))
+    full_out = jax.device_get(jax.jit(predict_fn)(features))
 
     def leaf(outputs):
         if isinstance(outputs, dict):

@@ -27,7 +27,19 @@ import uuid
 import jax
 
 
-def versioned_cache_dir(base: str) -> str:
+#: The one base directory of this checkout's compile cache (git-ignored).
+#: Fixed on purpose: the directory is part of every entry's cache key, so
+#: a temporary, pid- or time-derived path would never hit.
+CHECKOUT_CACHE_BASE = os.path.join(
+    os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    ),
+    "tests",
+    ".jax_cache",
+)
+
+
+def versioned_cache_dir(base: str = CHECKOUT_CACHE_BASE) -> str:
     """`<base>/<jax>-<jaxlib>-<backend><ndevices>` for THIS process.
 
     Calling this initializes jax's backend: call it only after platform
@@ -65,20 +77,17 @@ def _write_bytes_atomic(path: str, data: bytes) -> None:
 
 def install_atomic_cache_writes() -> bool:
     """Replaces jax's persistent-cache entry write with a crash-atomic
-    one (see module docstring). Idempotent; returns whether the atomic
-    path is installed. If jax's cache internals have moved (different
-    version), installs nothing and returns False — the cache degrades
-    to upstream's non-atomic writes rather than breaking.
+    one (see module docstring). Idempotent; returns True once the atomic
+    path is installed. Written against the installed jax's
+    `jax._src.lru_cache`: if those internals move, this raises rather
+    than leaving torn-entry protection off in silence.
     """
-    try:
-        from jax._src import lru_cache as _lru
+    from jax._src import lru_cache as _lru
 
-        cache_cls = _lru.LRUCache
-        cache_suffix = _lru._CACHE_SUFFIX
-        atime_suffix = _lru._ATIME_SUFFIX
-        original_put = cache_cls.put
-    except Exception:
-        return False
+    cache_cls = _lru.LRUCache
+    cache_suffix = _lru._CACHE_SUFFIX
+    atime_suffix = _lru._ATIME_SUFFIX
+    original_put = cache_cls.put
     if getattr(original_put, "_adanet_atomic", False):
         return True
 
@@ -121,21 +130,22 @@ def install_atomic_cache_writes() -> bool:
     return True
 
 
-def enable_persistent_cache(base: str, min_compile_secs: float = 1.0) -> str:
-    """Points jax's persistent compile cache at the versioned subdir.
+def enable_persistent_cache() -> str:
+    """Turns on jax's persistent compile cache; returns its directory.
 
-    Returns the directory actually configured. No-op on the cache-dir
-    setting if one is already configured (e.g. via
-    JAX_COMPILATION_CACHE_DIR at jax import time) — an explicit caller
-    choice wins. Either way, entry writes become crash-atomic
-    (`install_atomic_cache_writes`).
+    The directory is placed from OUTSIDE the program: when
+    `JAX_COMPILATION_CACHE_DIR` is set (jax reads it into its config at
+    import), that directory is used exactly as given and nothing here
+    sets another. Only when none is configured does the cache go to the
+    fixed `versioned_cache_dir()` inside this checkout. Every entry point
+    (tests, `chip_smoke.py`, the trainer CLI, `bench.py`) comes through
+    here, so they all share one cache. Either way, entry writes become
+    crash-atomic (`install_atomic_cache_writes`).
     """
     install_atomic_cache_writes()
     if jax.config.jax_compilation_cache_dir is not None:
         return jax.config.jax_compilation_cache_dir
-    path = versioned_cache_dir(base)
+    path = versioned_cache_dir()
     jax.config.update("jax_compilation_cache_dir", path)
-    jax.config.update(
-        "jax_persistent_cache_min_compile_time_secs", min_compile_secs
-    )
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
     return path

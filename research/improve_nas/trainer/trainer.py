@@ -27,6 +27,7 @@ from adanet_tpu.ensemble import (
     GrowStrategy,
     MixtureWeightType,
 )
+from adanet_tpu.utils.compile_cache_dir import enable_persistent_cache
 
 from research.improve_nas.trainer import fake_data, improve_nas, optimizer
 
@@ -70,8 +71,12 @@ flags.DEFINE_integer("seed", 42, "Random seed.")
 
 def _provider():
     if FLAGS.dataset == "fake":
+        # CIFAR-shaped (32x32x3, 10 classes), so a fake-data run builds
+        # the same programs a CIFAR-10 run does.
         return fake_data.FakeImageProvider(
             num_examples=max(64, FLAGS.batch_size * 4),
+            image_size=32,
+            num_classes=10,
             batch_size=FLAGS.batch_size,
             seed=FLAGS.seed,
         )
@@ -86,8 +91,14 @@ def _provider():
     raise ValueError("Unknown dataset %r" % FLAGS.dataset)
 
 
-def main(argv):
-    del argv
+def build_search(**estimator_kwargs):
+    """The data provider and the `Estimator` the parsed flags describe.
+
+    `main` and `chip_smoke.py` both build the search here, so the smoke
+    drives exactly what the CLI runs. `estimator_kwargs` reach the
+    `Estimator` for options the CLI has no flag for (the smoke passes
+    `export_serving=True`).
+    """
     provider = _provider()
     max_iteration_steps = max(
         1, FLAGS.train_steps // FLAGS.boosting_iterations
@@ -139,8 +150,15 @@ def main(argv):
         force_grow=FLAGS.force_grow,
         model_dir=FLAGS.model_dir,
         random_seed=FLAGS.seed,
+        **estimator_kwargs,
     )
+    return provider, estimator
 
+
+def main(argv):
+    del argv
+    logging.info("Compile cache: %s", enable_persistent_cache())
+    provider, estimator = build_search()
     estimator.train(
         provider.get_input_fn("train"), max_steps=FLAGS.train_steps
     )

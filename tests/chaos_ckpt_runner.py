@@ -29,9 +29,7 @@ except AttributeError:
 
 from adanet_tpu.utils.compile_cache_dir import enable_persistent_cache
 
-enable_persistent_cache(
-    os.path.join(os.path.dirname(os.path.abspath(__file__)), ".jax_cache")
-)
+enable_persistent_cache()
 
 from chaos_common import build_estimator, input_fn
 

@@ -59,11 +59,7 @@ def main():
 
     from adanet_tpu.utils.compile_cache_dir import enable_persistent_cache
 
-    enable_persistent_cache(
-        os.path.join(
-            os.path.dirname(os.path.abspath(__file__)), ".jax_cache"
-        )
-    )
+    enable_persistent_cache()
 
     from adanet_tpu.distributed import RoundRobinStrategy
     from adanet_tpu.robustness import faults

@@ -27,11 +27,7 @@ sys.path.insert(
 # runs) reuse this single-device subprocess's compiled programs.
 from adanet_tpu.utils.compile_cache_dir import enable_persistent_cache
 
-enable_persistent_cache(
-    os.path.join(
-        os.path.dirname(os.path.abspath(__file__)), ".jax_cache"
-    )
-)
+enable_persistent_cache()
 
 import optax
 

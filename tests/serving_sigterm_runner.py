@@ -23,11 +23,7 @@ sys.path.insert(
 
 from adanet_tpu.utils.compile_cache_dir import enable_persistent_cache
 
-enable_persistent_cache(
-    os.path.join(
-        os.path.dirname(os.path.abspath(__file__)), ".jax_cache"
-    )
-)
+enable_persistent_cache()
 
 import numpy as np
 import jax.numpy as jnp

@@ -53,6 +53,13 @@ def _jitted_reference(spec):
     return jax.jit(functools.partial(cell_reference, spec=spec))
 
 
+def _kernel(prev, cur, params, spec):
+    """The interpret-mode kernel itself, past the dispatcher's rule: the
+    rule sends reduction cells to the reference (the TPU compiler refuses
+    them), but the interpreter still checks their shared branch math."""
+    return ck._fused_cell_p(prev, cur, params, spec, True)
+
+
 @pytest.mark.parametrize(
     "spec,filters",
     [
@@ -67,7 +74,7 @@ def _jitted_reference(spec):
 def test_interpret_kernel_bit_identical_to_jitted_reference(spec, filters):
     prev, cur, params = _inputs(spec, filters=filters)
     want = _jitted_reference(spec)(prev, cur, params)
-    got = fused_cell(prev, cur, params, spec, interpret=True)
+    got = _kernel(prev, cur, params, spec)
     assert got.shape == output_shape(
         spec, prev.shape[0], prev.shape[1], prev.shape[2], filters
     )
@@ -95,7 +102,7 @@ def test_reduction_cell_factorized_reduction_edge():
     # resolution) as unused: the reduction params must exist.
     assert "0" in params["reductions"]
     want = _jitted_reference(TINY_REDUCTION)(prev, cur, params)
-    got = fused_cell(prev, cur, params, TINY_REDUCTION, interpret=True)
+    got = _kernel(prev, cur, params, TINY_REDUCTION)
     # Odd spatial input: ceil-div output resolution.
     assert got.shape[1] == 5 and got.shape[2] == 5
     assert np.array_equal(np.asarray(got), np.asarray(want))
@@ -142,9 +149,7 @@ def test_vjp_reduction_cell():
     prev, cur, params = _inputs(TINY_REDUCTION)
 
     def loss(p, c, par):
-        return jnp.sum(
-            fused_cell(p, c, par, TINY_REDUCTION, interpret=True)
-        )
+        return jnp.sum(_kernel(p, c, par, TINY_REDUCTION))
 
     grads = jax.jit(jax.grad(loss, argnums=2))(prev, cur, params)
     leaves = jax.tree_util.tree_leaves(grads)
@@ -230,21 +235,39 @@ def test_spatial_mismatch_falls_back_to_reference():
         fused_cell(prev, cur, params, TINY_CELL, interpret=True)
 
 
-def test_oversized_example_falls_back_to_xla(monkeypatch):
-    prev, cur, params = _inputs(TINY_CELL)
-    monkeypatch.setattr(ck, "_VMEM_BUDGET", 1)
-    called = {"pallas": False}
-    real = ck._pallas_forward
+@pytest.mark.parametrize(
+    "spec,budget",
+    [(TINY_CELL, 1), (TINY_REDUCTION, None)],
+    ids=["oversized_example", "reduction_cell"],
+)
+def test_outside_the_rule_takes_the_reference(monkeypatch, spec, budget):
+    """`kernel_takes` is the only thing that sends a cell to the XLA
+    reference: one example over the VMEM budget, or a stride-2 cell."""
+    prev, cur, params = _inputs(spec)
+    if budget is not None:
+        monkeypatch.setattr(ck, "_VMEM_BUDGET", budget)
+    assert not ck.kernel_takes(prev.shape, cur.shape, 8, spec)
 
-    def spy(*args, **kwargs):
-        called["pallas"] = True
-        return real(*args, **kwargs)
+    def boom(*args, **kwargs):
+        raise AssertionError("pallas path must not be taken")
 
-    monkeypatch.setattr(ck, "_pallas_forward", spy)
-    want = cell_reference(prev, cur, params, TINY_CELL)
-    got = fused_cell(prev, cur, params, TINY_CELL, interpret=True)
-    assert not called["pallas"]
+    monkeypatch.setattr(ck, "_pallas_forward", boom)
+    want = cell_reference(prev, cur, params, spec)
+    got = fused_cell(prev, cur, params, spec, interpret=True)
     assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_compiler_refusal_inside_the_rule_raises(monkeypatch):
+    """No catch-and-fall-back: a kernel the rule takes and the compiler
+    refuses is an error the caller sees."""
+    prev, cur, params = _inputs(TINY_CELL)
+
+    def refuse(*args, **kwargs):
+        raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+    monkeypatch.setattr(ck, "_pallas_forward", refuse)
+    with pytest.raises(RuntimeError, match="Mosaic failed"):
+        fused_cell(prev, cur, params, TINY_CELL, interpret=True)
 
 
 def test_batch_not_divisible_by_block_still_works():

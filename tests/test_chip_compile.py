@@ -1,0 +1,176 @@
+"""The Pallas kernels compiled for a DESCRIBED v5e chip (no chip attached).
+
+Interpret mode cannot see what the TPU's compiler refuses (a strided
+slice of a value, too much VMEM, a misaligned slice); the compiler is
+installed here and compiles for a topology that is described, not
+attached (on-chip-measurement guide, section 2). These cases pin the
+kernels' static rules (`kernel_takes`) to what really compiles, at the
+widths NASNet-A (6@768) runs: every later PR is held to them at no chip
+time.
+
+The topology is described inside a module-scoped fixture of THIS file
+and nowhere else: only one process may load the TPU's library, and with
+several pytest workers only the worker that is given this file runs the
+fixture. So: one file, never at import or collection, never in a child.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from adanet_tpu.ops import cell_kernels, ensemble_kernels, sepconv_kernels
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A sharding on one chip of a described `v5e:2x2`, with the
+    persistent compile cache off around the compiles (a compile for a
+    described chip is written to the cache but cannot be read back
+    without the chip, and the next one would warn)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as exc:
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % exc)
+    was_enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield SingleDeviceSharding(topo.devices[0])
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was_enabled)
+        compilation_cache.reset_cache()
+
+
+def _compile(fn, sharding, *shapes):
+    """Compiles `fn` for the described chip; `shapes` are pytrees of
+    (shape, dtype) leaves. Raises what the chip's compiler would."""
+    args = jax.tree_util.tree_map(
+        lambda leaf: jax.ShapeDtypeStruct(
+            leaf.shape, leaf.dtype, sharding=sharding
+        ),
+        shapes,
+    )
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def _sds(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+# (batch, h, w, c, kernel, filters, stride): NASNet-A's own stages
+# (32x32x32, 16x16x64, 8x8x128 and the 96-channel stem output), the
+# mobile-ImageNet 44-filter width (not a multiple of anything Mosaic
+# likes), the 768-wide deep cell, and both strides.
+SEPCONV_SHAPES = [
+    (8, 32, 32, 96, 3, 32, 1),
+    (8, 32, 32, 32, 5, 32, 1),
+    (8, 16, 16, 64, 5, 64, 1),
+    (4, 16, 16, 44, 3, 44, 1),
+    (2, 8, 8, 768, 3, 768, 1),
+    (128, 32, 32, 32, 3, 32, 1),
+    (128, 8, 8, 128, 5, 128, 1),
+    (8, 32, 32, 32, 7, 64, 2),  # the first reduction cell's 7x7
+    (128, 16, 16, 64, 3, 64, 2),
+]
+
+
+@pytest.mark.parametrize(
+    "shape", SEPCONV_SHAPES, ids=lambda s: "x".join(map(str, s))
+)
+def test_sep_conv_kernel_compiles_for_v5e(one_chip, shape):
+    b, h, w, c, k, f, stride = shape
+    assert sepconv_kernels.kernel_takes((b, h, w, c), k, f, stride)
+    _compile(
+        functools.partial(
+            sepconv_kernels._pallas_forward, stride=stride, interpret=False
+        ),
+        one_chip,
+        _sds((b, h, w, c), jnp.bfloat16),
+        _sds((k, k, 1, c), jnp.bfloat16),
+        _sds((1, 1, c, f), jnp.bfloat16),
+    )
+
+
+@pytest.mark.parametrize(
+    "shape", [(4, 128, 10), (8, 256, 1000)], ids=lambda s: "x".join(map(str, s))
+)
+def test_combine_kernel_compiles_for_v5e(one_chip, shape):
+    n, b, c = shape
+    _compile(
+        functools.partial(ensemble_kernels._combine_pallas, interpret=False),
+        one_chip,
+        _sds((n, b, c), jnp.float32),
+        _sds((n,), jnp.float32),
+        _sds((c,), jnp.float32),
+    )
+
+
+def _cell_args(spec, b, hw, channels, filters):
+    params = jax.eval_shape(
+        lambda: cell_kernels.init_cell_params(
+            jax.random.PRNGKey(0), spec, channels, channels, filters,
+            jnp.bfloat16,
+        )
+    )
+    x = _sds((b, hw, hw, channels), jnp.bfloat16)
+    return x, x, params
+
+
+# The first and the last stage of NASNet-A (6@768) at batch 128.
+@pytest.mark.parametrize(
+    "shape",
+    [(128, 32, 192, 32), (128, 8, 768, 128)],
+    ids=lambda s: "x".join(map(str, s)),
+)
+def test_normal_cell_kernel_compiles_for_v5e(one_chip, shape):
+    b, hw, channels, filters = shape
+    spec = cell_kernels.NORMAL_CELL
+    prev, cur, params = _cell_args(spec, b, hw, channels, filters)
+    assert cell_kernels.kernel_takes(prev.shape, cur.shape, filters, spec)
+    _compile(
+        functools.partial(
+            cell_kernels._pallas_forward, spec=spec, interpret=False
+        ),
+        one_chip,
+        prev,
+        cur,
+        params,
+    )
+
+
+def test_reduction_cell_goes_to_the_reference_by_rule():
+    """The dispatcher's half of the stride-2 rule (needs no compiler):
+    a reduction cell is outside `kernel_takes`."""
+    spec = cell_kernels.REDUCTION_CELL
+    shape = (128, 32, 32, 192)
+    assert not cell_kernels.kernel_takes(shape, shape, 64, spec)
+
+
+@pytest.mark.xfail(
+    strict=True,
+    reason="vector.extract_strided_slice takes unit strides only: the "
+    "reduction cell's stride-2 value slices do not compile. The day this "
+    "passes, take `spec.stride == 1` out of cell_kernels.kernel_takes.",
+)
+def test_reduction_cell_kernel_compiles_for_v5e(one_chip):
+    spec = cell_kernels.REDUCTION_CELL
+    prev, cur, params = _cell_args(spec, 128, 32, 192, 64)
+    _compile(
+        functools.partial(
+            cell_kernels._pallas_forward, spec=spec, interpret=False
+        ),
+        one_chip,
+        prev,
+        cur,
+        params,
+    )

@@ -70,10 +70,69 @@ def test_interrupted_write_leaves_no_torn_entry(tmp_path, monkeypatch):
     assert cache.get("hot-key") == b"y" * 4096
 
 
-def test_enable_persistent_cache_reports_configured_dir(tmp_path):
+def test_enable_persistent_cache_reports_configured_dir():
     import jax
 
     # conftest configured the cache at import; a second enable is a
     # no-op on the directory but must still return the live setting.
-    configured = ccd.enable_persistent_cache(str(tmp_path / "unused"))
-    assert configured == jax.config.jax_compilation_cache_dir
+    assert ccd.enable_persistent_cache() == (
+        jax.config.jax_compilation_cache_dir
+    )
+
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+_PRINT_CACHE_DIR = """
+import json, jax
+from adanet_tpu.utils.compile_cache_dir import enable_persistent_cache
+returned = enable_persistent_cache()
+print(json.dumps([returned, jax.config.jax_compilation_cache_dir]))
+"""
+
+
+def _cache_dir_in_fresh_process(env_dir):
+    """(returned, configured) cache directory of a fresh CPU process."""
+    import json
+    import subprocess
+    import sys
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if env_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+    proc = subprocess.run(
+        [sys.executable, "-c", _PRINT_CACHE_DIR],
+        cwd=_REPO,
+        env=env,
+        capture_output=True,
+        text=True,
+        timeout=120,
+    )
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+def test_cache_dir_from_the_environment_is_used_as_given(tmp_path):
+    """`JAX_COMPILATION_CACHE_DIR` places the cache from outside: that
+    directory exactly (no versioned subdirectory), and nothing else is
+    set in code."""
+    given = str(tmp_path / "placed-from-outside")
+    assert _cache_dir_in_fresh_process(given) == [given, given]
+
+
+def test_cache_dir_unset_is_one_fixed_path_inside_the_checkout():
+    """Without the variable: `<checkout>/tests/.jax_cache/<jax>-<jaxlib>-
+    <backend><n>`, the same in every process (the path is part of the
+    cache key: a temporary, pid- or time-derived one would never hit)."""
+    import jax
+    import jaxlib
+
+    first = _cache_dir_in_fresh_process(None)
+    second = _cache_dir_in_fresh_process(None)
+    want = os.path.join(
+        _REPO,
+        "tests",
+        ".jax_cache",
+        "%s-%s-cpu1" % (jax.__version__, jaxlib.__version__),
+    )
+    assert first == second == [want, want]

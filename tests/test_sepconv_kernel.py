@@ -117,8 +117,9 @@ def test_reference_matches_flax_sepconv_layer():
     )
 
 
-def test_non_tpu_backend_falls_back_to_reference():
-    """On CPU without interpret, the op must silently use the XLA path."""
+def test_lowered_for_cpu_takes_the_reference_branch():
+    """`platform_dependent` picks per LOWERING platform: the same traced
+    program holds the kernel for a TPU and the XLA path for the CPU."""
     x, dw, pw = _random_inputs(2, 8, 8, 8, f=8, k=3)
     got = fused_sep_conv(x, dw, pw, 1, use_pallas=True, interpret=False)
     want = sep_conv_reference(x, dw, pw, 1)
@@ -208,6 +209,7 @@ def test_oversized_example_falls_back_to_xla(monkeypatch):
 
     monkeypatch.setattr(sepconv_kernels, "_pallas_forward", boom)
     x, dw, pw = _random_inputs(1, 64, 64, 512, f=512, k=3)
+    assert not sepconv_kernels.kernel_takes(x.shape, 3, 512, 1)
     got = sepconv_kernels.fused_sep_conv(
         x, dw, pw, 1, use_pallas=True, interpret=True
     )
@@ -217,61 +219,19 @@ def test_oversized_example_falls_back_to_xla(monkeypatch):
     )
 
 
-def test_shard_shapes_detect_partitioning():
-    """`_tpu_lowering_ok` validates at PER-SHARD shapes (ADVICE r5): a
-    concrete operand's own sharding answers exactly; a trace inside a
-    live Mesh context follows the framework's batch-axis data-parallel
-    convention (divisible batch shards, weights and uneven batches
-    replicate); unpartitioned calls pass through at global shapes."""
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+def test_compiler_refusal_inside_the_rule_raises(monkeypatch):
+    """No catch-and-fall-back: a kernel the rule takes and the compiler
+    refuses is an error the caller sees, not a silent XLA path."""
+    from adanet_tpu.ops import sepconv_kernels
 
-    from adanet_tpu.ops.sepconv_kernels import _shard_shapes
+    def refuse(*args, **kwargs):
+        raise RuntimeError("Mosaic failed to compile TPU kernel")
 
-    x, dw, pw = _random_inputs(8, 8, 8, 8, f=8, k=3)
-    want_global = (tuple(x.shape), tuple(dw.shape), tuple(pw.shape))
-
-    # Unpartitioned: global shapes pass through untouched.
-    assert _shard_shapes(x, dw, pw) == want_global
-
-    devices = np.array(jax.devices())
-    mesh = Mesh(devices, ("data",))
-    n = len(devices)
-
-    # Source 1: concrete sharded operands (device_put) answer exactly.
-    xs = jax.device_put(x, NamedSharding(mesh, PartitionSpec("data")))
-    dws = jax.device_put(dw, NamedSharding(mesh, PartitionSpec()))
-    pws = jax.device_put(pw, NamedSharding(mesh, PartitionSpec()))
-    assert _shard_shapes(xs, dws, pws) == (
-        (x.shape[0] // n,) + tuple(x.shape[1:]),
-        tuple(dw.shape),
-        tuple(pw.shape),
-    )
-
-    # Source 2: tracers inside a live mesh context carry no sharding;
-    # the batch-axis convention applies.
-    seen = {}
-
-    def probe(a, b, c):
-        seen["shapes"] = _shard_shapes(a, b, c)
-        return a
-
-    with mesh:
-        jax.eval_shape(probe, x, dw, pw)
-    assert seen["shapes"] == (
-        (x.shape[0] // n,) + tuple(x.shape[1:]),
-        tuple(dw.shape),
-        tuple(pw.shape),
-    )
-
-    # Uneven batch under a live mesh replicates (shard_batch's rule).
-    x7, dw7, pw7 = _random_inputs(7, 8, 8, 8, f=8, k=3)
-    with mesh:
-        jax.eval_shape(probe, x7, dw7, pw7)
-    if n > 1:
-        assert seen["shapes"][0] == tuple(x7.shape)
-
-    # Outside the context the live-mesh source disarms again.
-    assert _shard_shapes(x, dw, pw) == want_global
+    monkeypatch.setattr(sepconv_kernels, "_pallas_forward", refuse)
+    x, dw, pw = _random_inputs(2, 8, 8, 8, f=8, k=3)
+    assert sepconv_kernels.kernel_takes(x.shape, 3, 8, 2)
+    with pytest.raises(RuntimeError, match="Mosaic failed"):
+        sepconv_kernels.fused_sep_conv(x, dw, pw, 2, interpret=True)
 
 
 def test_batch_not_divisible_by_block_still_works():

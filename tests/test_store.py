@@ -483,6 +483,31 @@ def test_compile_cache_persistent_tier_across_instances(tmp_path):
     assert second.store_errors == 0
 
 
+def test_stored_executable_loads_onto_the_device_it_was_compiled_for(
+    tmp_path,
+):
+    """A stored executable compiled for ONE of the eight devices (not
+    device 0) loads onto that device and runs there, whatever the
+    backend's device count."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from adanet_tpu.core.compile_cache import CachedStep, CompileCache
+
+    device = jax.devices()[5]
+    store = ArtifactStore(str(tmp_path / "store"))
+    x = jax.device_put(jnp.arange(8, dtype=jnp.float32), device)
+
+    CachedStep(lambda v: v * 2 - 1, CompileCache(store=store))(x)
+
+    fresh = CompileCache(store=store)
+    out = CachedStep(lambda v: v * 2 - 1, fresh)(x)
+    np.testing.assert_allclose(np.asarray(out), np.arange(8) * 2 - 1)
+    assert out.devices() == {device}
+    assert (fresh.misses, fresh.store_hits, fresh.store_errors) == (0, 1, 0)
+
+
 # ---------------------------------------------- serving closure publication
 
 

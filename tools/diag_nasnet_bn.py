@@ -25,13 +25,7 @@ jax.config.update("jax_platforms", "cpu")
 # directly (and the dir is topology-keyed; see compile_cache_dir).
 from adanet_tpu.utils.compile_cache_dir import enable_persistent_cache
 
-enable_persistent_cache(
-    os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "tests",
-        ".jax_cache",
-    )
-)
+enable_persistent_cache()
 
 import jax.numpy as jnp
 import numpy as np

@@ -38,7 +38,7 @@ except AttributeError:
     ) + " --xla_force_host_platform_device_count=%d" % (8)
 from adanet_tpu.utils.compile_cache_dir import enable_persistent_cache
 
-enable_persistent_cache(os.path.join(_REPO, "tests", ".jax_cache"))
+enable_persistent_cache()
 
 # 20 steps demonstrates "runs + step time" but leaves the descent
 # ambiguous; 60 steps gives RMSProp's TF-style warm-started accumulator

@@ -177,9 +177,9 @@ class CompileCache:
             store_keys.env_fingerprint()[:16],
         )
 
-    def _store_load(self, ref_name: str, devices):
-        """Deserializes a previously published executable onto `devices`
-        (the ones the program was lowered for), or None."""
+    def _store_load(self, ref_name: str, lowered):
+        """Deserializes a previously published executable onto the
+        devices `lowered` was lowered for, or None."""
         entry = self._store.get_ref(AOT_REF_KIND, ref_name)
         if entry is None:
             return None
@@ -193,9 +193,15 @@ class CompileCache:
             payload, in_tree, out_tree = pickle.loads(blob)
             # Without `execution_devices` the executable loads onto
             # EVERY device of the backend and then rejects its own
-            # single-device arguments.
+            # single-device arguments. `Lowered` has no public name for
+            # its devices; should this one move, the load degrades like
+            # any other failure here (and test_store's cases, which
+            # hold `store_errors` to 0, say so).
             return serialize_executable.deserialize_and_load(
-                payload, in_tree, out_tree, execution_devices=devices
+                payload,
+                in_tree,
+                out_tree,
+                execution_devices=list(lowered._lowering._device_list),
             )
         except Exception as exc:
             # Unsupported backend, corrupt-and-unhealable blob, or a
@@ -276,9 +282,7 @@ class CompileCache:
                 ref_name = self._store_ref_name(
                     digest, device_fp, in_tree, out_tree
                 )
-                executable = self._store_load(
-                    ref_name, list(lowered._lowering._device_list)
-                )
+                executable = self._store_load(ref_name, lowered)
             if executable is not None:
                 self._m_store_hits.inc()
             else:

@@ -33,12 +33,11 @@ def one_chip():
     from jax.experimental.compilation_cache import compilation_cache
     from jax.sharding import SingleDeviceSharding
 
-    try:
-        topo = topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2"
-        )
-    except Exception as exc:
-        pytest.skip("no v5e:2x2 topology can be described here: %s" % exc)
+    # Raises where the TPU's compiler is missing: it is part of the one
+    # supported installation, and a skip here would be a hidden pass.
+    topo = topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2"
+    )
     was_enabled = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()

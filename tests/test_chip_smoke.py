@@ -77,11 +77,18 @@ def test_rehearsal_runs_every_one_chip_phase():
     assert search["fsck_ok"] is True
     assert search["best"]["0"].startswith("t0_")
     assert search["best"]["1"].startswith("t1_")
+    # The Estimator's default publication: generation 1 has a cascade.
+    assert search["cascade"]["program"] and search["cascade"]["threshold"]
 
     serve = phases["serve"]
     assert serve["bit_identical"] is True
     assert serve["statuses"] == {"ok": len(serve["requests"])}
     assert len({r["bucket"] for r in serve["requests"]}) >= 2
+    # ...and the default batcher answered through it.
+    cascade = serve["cascade"]
+    assert cascade["statuses"] == {"ok": len(cascade["levels"])}
+    assert cascade["levels"] and set(cascade["levels"]) <= {0, 1}
+    assert cascade["rollback"] is None
 
 
 def test_rehearsal_of_the_four_chip_phase_on_four_virtual_devices():

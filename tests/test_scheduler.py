@@ -543,40 +543,68 @@ def test_elastic_beats_lockstep_on_heterogeneous_budgets():
     assert ema_wq[name] == pytest.approx(ema_rr[name], rel=0.10)
 
 
-def test_elastic_estimator_full_search_and_resume(tmp_path):
-    """Full Estimator lifecycle on the elastic scheduler: selection
-    parity with the lockstep estimator, and an exact mid-iteration
-    budget-stop resume (per-candidate steps restored from the
-    checkpointed state, re-joining the window grid)."""
+def _elastic_parity_estimator(d, strategy, **kwargs):
+    return adanet_tpu.Estimator(
+        head=RegressionHead(),
+        subnetwork_generator=adanet_tpu.subnetwork.SimpleGenerator(
+            [DNNBuilder("a", 1), DNNBuilder("b", 2)]
+        ),
+        max_iteration_steps=8,
+        ensemblers=[
+            ComplexityRegularizedEnsembler(optimizer=optax.sgd(0.05))
+        ],
+        max_iterations=2,
+        model_dir=d,
+        log_every_steps=0,
+        placement_strategy=strategy,
+        **kwargs,
+    )
+
+
+def _members(d, t):
     import json
     import os
 
-    def build(d, strategy):
-        return adanet_tpu.Estimator(
-            head=RegressionHead(),
-            subnetwork_generator=adanet_tpu.subnetwork.SimpleGenerator(
-                [DNNBuilder("a", 1), DNNBuilder("b", 2)]
-            ),
-            max_iteration_steps=8,
-            ensemblers=[
-                ComplexityRegularizedEnsembler(optimizer=optax.sgd(0.05))
-            ],
-            max_iterations=2,
-            model_dir=d,
-            log_every_steps=0,
-            placement_strategy=strategy,
-            # Parity is judged among the NEW candidates. The carried-over
-            # previous ensemble's EMA is the tail of an 8-step descent,
-            # and where each placement samples that descent moves it by
-            # 3x (0.21 work-queue, 0.31 RoundRobin, 0.60 fused), so
-            # "keep or grow" was a 0.004 near-tie under the work queue;
-            # the new candidates are 0.05 apart under every placement.
-            force_grow=True,
-        )
+    with open(os.path.join(d, "architecture-%d.json" % t)) as f:
+        return json.load(f)["subnetworks"]
 
-    def arch(d, t):
-        with open(os.path.join(d, "architecture-%d.json" % t)) as f:
-            return json.load(f)
+
+@pytest.mark.parametrize(
+    "force_grow",
+    [
+        pytest.param(
+            False,
+            id="keep_or_grow",
+            marks=pytest.mark.xfail(
+                strict=True,
+                reason="The carried-over ensemble's EMA depends on the "
+                "dispatch window (the ensemble group reads members at "
+                "the END of a window): t0_a ends iteration 0 at 0.21199 "
+                "under 4-step windows, 0.30916 under single steps "
+                "(0.59884 fused) and 0.22309 when a stop at step 6 cuts "
+                "the window grid, against 0.21636 / 0.22508 for "
+                "iteration 1's best new candidate. So 4-step windows "
+                "keep [a] while single-step RoundRobin and the resumed "
+                "search grow to [a, b]. ROADMAP A10.",
+            ),
+        ),
+        pytest.param(True, id="force_grow"),
+    ],
+)
+def test_elastic_estimator_full_search_and_resume(tmp_path, force_grow):
+    """Full Estimator lifecycle on the elastic scheduler: selection
+    parity with the lockstep estimator, and an exact mid-iteration
+    budget-stop resume (per-candidate steps restored from the
+    checkpointed state, re-joining the window grid).
+
+    Among the NEW candidates (`force_grow`) both hold: those are 0.05
+    apart wherever the windows fall. With the carried-over ensemble in
+    the running they do not (the xfail);
+    `test_elastic_estimator_keep_or_grow_on_the_window_grid` guards
+    keep-or-grow where the windows coincide."""
+
+    def build(d, strategy):
+        return _elastic_parity_estimator(d, strategy, force_grow=force_grow)
 
     d_wq = str(tmp_path / "wq")
     build(d_wq, ElasticWorkQueueStrategy(window_steps=4)).train(
@@ -584,8 +612,8 @@ def test_elastic_estimator_full_search_and_resume(tmp_path):
     )
     d_rr = str(tmp_path / "rr")
     build(d_rr, RoundRobinStrategy()).train(linear_dataset(), max_steps=100)
-    assert [arch(d_wq, t)["subnetworks"] for t in range(2)] == [
-        arch(d_rr, t)["subnetworks"] for t in range(2)
+    assert [_members(d_wq, t) for t in range(2)] == [
+        _members(d_rr, t) for t in range(2)
     ]
 
     # Budget-stop mid-iteration 0 at an OFF-GRID step, then resume.
@@ -598,9 +626,49 @@ def test_elastic_estimator_full_search_and_resume(tmp_path):
     est.train(linear_dataset(), max_steps=100)
     assert est.latest_global_step() == 16
     assert est.latest_iteration_number() == 2
-    assert [arch(d_res, t)["subnetworks"] for t in range(2)] == [
-        arch(d_wq, t)["subnetworks"] for t in range(2)
+    assert [_members(d_res, t) for t in range(2)] == [
+        _members(d_wq, t) for t in range(2)
     ]
+
+
+def test_elastic_estimator_keep_or_grow_on_the_window_grid(tmp_path):
+    """Keep-or-grow parity, unforced, where the windows coincide: the
+    work queue against lockstep RoundRobin dispatching the same window
+    (`iterations_per_loop == window_steps`), and against its own resume
+    from a stop ON the grid. Every candidate's EMA agrees, the
+    carried-over ensemble's included, so the selection does too."""
+
+    def emas(estimator):
+        return {
+            "%d/%s" % (t, name): entry["adanet_loss_ema"]
+            for t in range(2)
+            for name, entry in estimator.candidate_metrics(t).items()
+        }
+
+    wq = _elastic_parity_estimator(
+        str(tmp_path / "wq"), ElasticWorkQueueStrategy(window_steps=4)
+    )
+    wq.train(linear_dataset(), max_steps=100)
+    rr = _elastic_parity_estimator(
+        str(tmp_path / "rr"), RoundRobinStrategy(), iterations_per_loop=4
+    )
+    rr.train(linear_dataset(), max_steps=100)
+    d_res = str(tmp_path / "resume")
+    _elastic_parity_estimator(
+        d_res, ElasticWorkQueueStrategy(window_steps=4)
+    ).train(linear_dataset(), max_steps=4)
+    resumed = _elastic_parity_estimator(
+        d_res, ElasticWorkQueueStrategy(window_steps=4)
+    )
+    assert resumed.latest_global_step() == 4
+    resumed.train(linear_dataset(), max_steps=100)
+
+    want = emas(wq)
+    assert len(want) == 5  # t0: a, b; t1: the carried-over a, a, b
+    for other in (rr, resumed):
+        assert emas(other) == pytest.approx(want, rel=1e-4)
+        for t in range(2):
+            assert _members(other.model_dir, t) == _members(wq.model_dir, t)
 
 
 def test_elastic_poisoned_candidate_joins_quarantine(tmp_path):

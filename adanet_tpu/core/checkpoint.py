@@ -48,6 +48,7 @@ from typing import Any, Dict, List, Optional
 import jax
 from flax import serialization
 
+from adanet_tpu.observability import spans as spans_lib
 from adanet_tpu.robustness import faults
 from adanet_tpu.robustness.retry import retrying_open_read
 
@@ -469,12 +470,26 @@ def save_pytree(model_dir: str, filename: str, payload: Any) -> str:
     Returns the payload's SHA-256 hex digest (also written to the
     sidecar), for callers recording it in the manifest."""
     os.makedirs(model_dir, exist_ok=True)
-    data = serialization.to_bytes(jax.device_get(payload))
-    path = os.path.join(model_dir, filename)
-    faults.trip("checkpoint.write", path=path, data=data)
-    remove_digest(model_dir, filename)
-    _atomic_write_bytes(path, data)
-    return write_digest(model_dir, filename, data)
+    tracer = spans_lib.tracer()
+    # The fetch waits for every step still in flight on the device: it
+    # is the drain of the dispatch queue, and apart from the write.
+    with tracer.span("checkpoint.fetch") as fetch_span:
+        host = jax.device_get(payload)
+        if tracer.enabled:
+            fetch_span.set(
+                bytes=sum(
+                    getattr(leaf, "nbytes", 0)
+                    for leaf in jax.tree_util.tree_leaves(host)
+                )
+            )
+    with tracer.span("checkpoint.write") as write_span:
+        data = serialization.to_bytes(host)
+        write_span.set(bytes=len(data))
+        path = os.path.join(model_dir, filename)
+        faults.trip("checkpoint.write", path=path, data=data)
+        remove_digest(model_dir, filename)
+        _atomic_write_bytes(path, data)
+        return write_digest(model_dir, filename, data)
 
 
 def _read_verified(model_dir: str, filename: str) -> bytes:

@@ -319,10 +319,25 @@ class CachedStep:
     """A jit-like callable whose compilation goes through a CompileCache.
 
     With `cache=None` it degrades to plain `jax.jit` (zero overhead for
-    users who do not opt in).
+    users who do not opt in). `name` names the compiled program
+    (`jit_<name>` in a profile and in the compile cache's key) where
+    the function's own name is not one to publish.
     """
 
-    def __init__(self, fn, cache: Optional[CompileCache], donate_argnums=()):
+    def __init__(
+        self,
+        fn,
+        cache: Optional[CompileCache],
+        donate_argnums=(),
+        name: Optional[str] = None,
+    ):
+        if name is not None:
+            inner = fn
+
+            def fn(*args):
+                return inner(*args)
+
+            fn.__name__ = fn.__qualname__ = name
         self._jit = jax.jit(fn, donate_argnums=donate_argnums)
         self._cache = cache
         self._by_spec: dict = {}

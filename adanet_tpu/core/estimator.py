@@ -548,7 +548,24 @@ class Estimator:
             if max_steps is not None:
                 raise ValueError("Set at most one of steps and max_steps.")
             max_steps = self.latest_global_step() + steps
+        # The search-scoped span is the whole call, entry to return: its
+        # correlation ID is inherited by every nested span (resume,
+        # input, iteration, work unit, checkpoint).
+        search_id = "%s-p%d" % (
+            os.path.basename(os.path.normpath(self._model_dir)) or "search",
+            os.getpid(),
+        )
+        with spans_lib.tracer().span(
+            "search",
+            correlation={"search_id": search_id},
+            max_steps=max_steps,
+        ):
+            self._train_call(input_fn, max_steps)
+        return self
 
+    def _train_call(self, input_fn, max_steps) -> None:
+        """One `train` call under its `search` span: resume (fsck, store
+        lease), the search loop, and the stop path."""
         # Multi-host SPMD data path (the analogue of the reference's
         # multi-worker data parallelism, adanet/docs/source/distributed.md:
         # 6-27): with several JAX processes, every process runs the same
@@ -612,42 +629,45 @@ class Estimator:
         # state while only the chief persists it).
         from adanet_tpu.robustness import integrity
 
-        heal = integrity.fsck(
-            self._model_dir, repair=coordination.is_chief()
-        )
-        if heal.rolled_back_to_iteration is not None:
-            # `verdict` is the ckpt_fsck CLI/CI contract: "healed" keeps
-            # a usable resume point; "unrecoverable" lost every trained
-            # generation — the search restarts from scratch rather than
-            # crash, but operators should know their checkpoints are gone.
-            log = (
-                _LOG.error
-                if heal.verdict == "unrecoverable"
-                else _LOG.warning
+        with spans_lib.tracer().span("resume.fsck") as fsck_span:
+            heal = integrity.fsck(
+                self._model_dir, repair=coordination.is_chief()
             )
-            log(
-                "Checkpoint %s: rolled back to iteration %d "
-                "(global step %s); quarantined %s.",
-                heal.verdict,
-                heal.rolled_back_to_iteration,
-                heal.rolled_back_global_step,
-                heal.quarantined or heal.issues,
-            )
-        info = heal.info or ckpt_lib.CheckpointInfo()
-        if self._artifact_store is not None and coordination.is_chief():
-            # Pin everything this search will reference against
-            # concurrent GC (TTL-leased: a SIGKILLed search costs one
-            # TTL, then its pins expire), and re-publish any completed
-            # iteration whose store ref is missing — the crash window
-            # between the artifact write and the ref write.
-            from adanet_tpu.store import leases as store_leases
+            fsck_span.set(verdict=heal.verdict)
+            if heal.rolled_back_to_iteration is not None:
+                # `verdict` is the ckpt_fsck CLI/CI contract: "healed"
+                # keeps a usable resume point; "unrecoverable" lost every
+                # trained generation — the search restarts from scratch
+                # rather than crash, but operators should know their
+                # checkpoints are gone.
+                log = (
+                    _LOG.error
+                    if heal.verdict == "unrecoverable"
+                    else _LOG.warning
+                )
+                log(
+                    "Checkpoint %s: rolled back to iteration %d "
+                    "(global step %s); quarantined %s.",
+                    heal.verdict,
+                    heal.rolled_back_to_iteration,
+                    heal.rolled_back_global_step,
+                    heal.quarantined or heal.issues,
+                )
+            info = heal.info or ckpt_lib.CheckpointInfo()
+            if self._artifact_store is not None and coordination.is_chief():
+                # Pin everything this search will reference against
+                # concurrent GC (TTL-leased: a SIGKILLed search costs one
+                # TTL, then its pins expire), and re-publish any completed
+                # iteration whose store ref is missing — the crash window
+                # between the artifact write and the ref write.
+                from adanet_tpu.store import leases as store_leases
 
-            self._store_lease = store_leases.acquire(
-                self._artifact_store,
-                owner="search-%d" % os.getpid(),
-                ttl_secs=self._store_lease_ttl_secs(),
-            )
-            self._store_reconcile(info)
+                self._store_lease = store_leases.acquire(
+                    self._artifact_store,
+                    owner="search-%d" % os.getpid(),
+                    ttl_secs=self._store_lease_ttl_secs(),
+                )
+                self._store_reconcile(info)
         # Degraded mode: set once a multi-host peer is declared lost;
         # collective agreement (stop checks, bookkeeping) then falls back
         # to process-local behavior and the search stops at the next
@@ -702,25 +722,14 @@ class Estimator:
 
         # The telemetry plane: a flight recorder rooted at the model dir
         # (shared with a serving pool on the same dir; a search over a
-        # NEW dir rebinds so its crashes dump under ITS model dir) and a
-        # search-scoped span whose correlation ID every nested span —
-        # iteration, work unit, checkpoint — inherits.
+        # NEW dir rebinds so its crashes dump under ITS model dir).
         flightrec_lib.install_default(
             os.path.join(self._model_dir, flightrec_lib.DEFAULT_SUBDIR)
         )
-        self._search_id = "%s-p%d" % (
-            os.path.basename(os.path.normpath(self._model_dir)) or "search",
-            os.getpid(),
-        )
         try:
-            with spans_lib.tracer().span(
-                "search",
-                correlation={"search_id": self._search_id},
-                max_steps=max_steps,
-            ):
-                self._train_loop(
-                    input_fn, max_steps, info, data_iter, cached_previous
-                )
+            self._train_loop(
+                input_fn, max_steps, info, data_iter, cached_previous
+            )
             if self._stop_requested:
                 # The SIGTERM checkpoint-and-stop path: leave the drain
                 # trace (dump runs OUTSIDE the signal handler).
@@ -762,7 +771,6 @@ class Estimator:
             # Abandoned mid-stream prefetch workers would otherwise park
             # on their queues until process exit.
             self._close_prefetchers()
-        return self
 
     def _should_stop(self) -> bool:
         """The stop decision, agreed across processes under SPMD.
@@ -839,6 +847,21 @@ class Estimator:
     def _train_loop(
         self, input_fn, max_steps, info, data_iter, cached_previous
     ):
+        tracer = spans_lib.tracer()
+        t = info.iteration_number
+
+        def span(name, **attrs):
+            """A span of the iteration under way (`t` at the call)."""
+            return tracer.span(name, correlation={"iteration": t}, **attrs)
+
+        def next_batch(fn, data_iter):
+            with span("input.next_batch"):
+                return self._next_batch(fn, data_iter)
+
+        def place_batch(batch, stacked=False):
+            with span("input.place_batch", stacked=stacked):
+                return self._place_batch(batch, stacked=stacked)
+
         while True:
             t = info.iteration_number
             if self._should_stop():
@@ -857,13 +880,15 @@ class Estimator:
                 cached_previous = None
                 continue
 
-            batch, data_iter = self._next_batch(input_fn, data_iter)
+            batch, data_iter = next_batch(input_fn, data_iter)
             sample_batch = batch
             data_iter = itertools.chain([batch], data_iter)
 
-            iteration = self._build_iteration(
-                t, sample_batch, cached_previous=cached_previous
-            )
+            with span("iteration.build") as build_span:
+                iteration = self._build_iteration(
+                    t, sample_batch, cached_previous=cached_previous
+                )
+                build_span.set(candidates=len(iteration.ensemble_specs))
             executor = None
             elastic = isinstance(
                 self._placement_strategy, ElasticWorkQueueStrategy
@@ -931,16 +956,16 @@ class Estimator:
             )
             profiling = False
             profiled = False
+            # An iteration's first window in this call holds the step's
+            # trace, lowering and cache load: its span says so.
+            first_window = True
             self._last_stop_check_step = steps_done
             if elastic:
                 # Queue drain replaces the lockstep round: work units are
                 # pulled under leases, dead workers' units re-issue, and
                 # freed capacity may speculate on t+1
                 # (distributed/scheduler.py, docs/scheduler.md).
-                with spans_lib.tracer().span(
-                    "iteration.drain",
-                    correlation={"iteration": t},
-                ):
+                with span("iteration.drain"):
                     state, steps_done = self._drain_elastic_iteration(
                         executor, iteration, state, info, t, steps_done,
                         max_steps, input_fn,
@@ -986,28 +1011,24 @@ class Estimator:
                     # RoundRobin paths.
                     batches = []
                     for _ in range(loop_size):
-                        batch, data_iter = self._next_batch(
-                            input_fn, data_iter
-                        )
+                        batch, data_iter = next_batch(input_fn, data_iter)
                         batches.append(batch)
                     if executor is not None:
                         one_step = executor.train_step
                         many_steps = executor.train_steps
                     else:
                         one_step = lambda s, b: iteration.train_step(
-                            s, self._place_batch(b)
+                            s, place_batch(b)
                         )
                         many_steps = lambda s, b: iteration.train_steps(
-                            s, self._place_batch(b, stacked=True)
+                            s, place_batch(b, stacked=True)
                         )
-                    with spans_lib.tracer().span(
-                        "train_window",
-                        correlation={"iteration": t},
-                        steps=loop_size,
+                    with span(
+                        "train_window", steps=loop_size, first=first_window
                     ):
                         # Dispatch span: covers host-side tracing/enqueue
                         # (device completion is async; device seconds
-                        # belong to the bench roofline).
+                        # come from a profile, benchmarks/scope_reduce.py).
                         if _same_shapes(batches):
                             stacked = jax.tree_util.tree_map(
                                 lambda *xs: np.stack(xs), *batches
@@ -1019,40 +1040,34 @@ class Estimator:
                     steps_done += loop_size
                     info.global_step += loop_size
                 elif executor is not None:
-                    batch, data_iter = self._next_batch(input_fn, data_iter)
+                    batch, data_iter = next_batch(input_fn, data_iter)
                     extra_batches = {}
                     for name, fn in extra_input_fns.items():
-                        extra_batches[name], extra_iters[name] = (
-                            self._next_batch(fn, extra_iters.get(name))
+                        extra_batches[name], extra_iters[name] = next_batch(
+                            fn, extra_iters.get(name)
                         )
-                    with spans_lib.tracer().span(
-                        "train_window",
-                        correlation={"iteration": t},
-                        steps=1,
-                    ):
+                    with span("train_window", steps=1, first=first_window):
                         state, metrics = executor.train_step(
                             state, batch, extra_batches
                         )
                     steps_done += 1
                     info.global_step += 1
                 else:
-                    batch, data_iter = self._next_batch(input_fn, data_iter)
+                    batch, data_iter = next_batch(input_fn, data_iter)
                     extra_batches = {}
                     for name, fn in extra_input_fns.items():
-                        raw, extra_iters[name] = self._next_batch(
+                        raw, extra_iters[name] = next_batch(
                             fn, extra_iters.get(name)
                         )
-                        extra_batches[name] = self._place_batch(raw)
-                    with spans_lib.tracer().span(
-                        "train_window",
-                        correlation={"iteration": t},
-                        steps=1,
-                    ):
+                        extra_batches[name] = place_batch(raw)
+                    batch = place_batch(batch)
+                    with span("train_window", steps=1, first=first_window):
                         state, metrics = iteration.train_step(
-                            state, self._place_batch(batch), extra_batches
+                            state, batch, extra_batches
                         )
                     steps_done += 1
                     info.global_step += 1
+                first_window = False
 
                 if (
                     executor is not None
@@ -1076,21 +1091,22 @@ class Estimator:
                     )
                     and coordination.is_chief()
                 ):
-                    emas = (
-                        executor.ema_losses(state)
-                        if executor is not None
-                        else iteration.ema_losses(state)
-                    )
-                    _LOG.info(
-                        "iteration %d step %d/%d adanet_loss EMAs: %s",
-                        t,
-                        steps_done,
-                        self._max_iteration_steps,
-                        {k: round(v, 6) for k, v in emas.items()},
-                    )
-                    self._write_train_summaries(
-                        iteration, metrics, emas, info.global_step, state
-                    )
+                    with span("train.log", global_step=info.global_step):
+                        emas = (
+                            executor.ema_losses(state)
+                            if executor is not None
+                            else iteration.ema_losses(state)
+                        )
+                        _LOG.info(
+                            "iteration %d step %d/%d adanet_loss EMAs: %s",
+                            t,
+                            steps_done,
+                            self._max_iteration_steps,
+                            {k: round(v, 6) for k, v in emas.items()},
+                        )
+                        self._write_train_summaries(
+                            iteration, metrics, emas, info.global_step, state
+                        )
                 if self._save_checkpoint_steps and _crossed(
                     prev_steps_done,
                     steps_done,
@@ -1824,15 +1840,30 @@ class Estimator:
     def _init_or_restore_state(
         self, iteration, sample_batch, info, replicate: bool = True
     ):
-        state = iteration.init_state(
-            self._iteration_rng(iteration.iteration_number), sample_batch
-        )
+        tracer = spans_lib.tracer()
+        tags = {"iteration": iteration.iteration_number}
+        with tracer.span("iteration.init_state", correlation=tags):
+            state = iteration.init_state(
+                self._iteration_rng(iteration.iteration_number), sample_batch
+            )
         if info.iteration_state_file:
             restored = None
             try:
-                restored = ckpt_lib.restore_pytree(
-                    self._model_dir, info.iteration_state_file, state
-                )
+                with tracer.span(
+                    "checkpoint.restore",
+                    correlation=tags,
+                    global_step=info.global_step,
+                ) as restore_span:
+                    restored = ckpt_lib.restore_pytree(
+                        self._model_dir, info.iteration_state_file, state
+                    )
+                    restore_span.set(
+                        bytes=os.path.getsize(
+                            os.path.join(
+                                self._model_dir, info.iteration_state_file
+                            )
+                        )
+                    )
             except (ckpt_lib.CheckpointCorruptionError, OSError) as exc:
                 # Verify-on-restore tripped on a file the pre-train fsck
                 # pass considered intact (bit rot between scans, or a

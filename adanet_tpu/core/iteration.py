@@ -24,6 +24,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
+import re
 from typing import Any, Dict, List, Mapping, Optional, Sequence, Tuple
 
 import flax
@@ -48,6 +49,18 @@ from adanet_tpu.utils.trees import tree_finite, tree_where
 # subnetwork trained this iteration, ("frozen", index) for a previous member.
 _NEW = "new"
 _FROZEN = "frozen"
+
+
+def scope_name(kind: str, name: str) -> str:
+    """The `jax.named_scope` of one part of the step: `<kind>.<name>`.
+
+    The scope lands in every device op's `op_name` (Flax supplies the
+    module path beneath it), which is how a profile is split by
+    candidate, ensemble and optimizer (`benchmarks/scope_reduce.py`). A
+    scope is ONE path component: whatever would read as a separator or
+    a transform wrapper (`/`, `(`, `)`, `:`) becomes `_`.
+    """
+    return "%s.%s" % (kind, re.sub(r"[^A-Za-z0-9_.\-]", "_", name))
 
 
 @struct.dataclass
@@ -257,13 +270,23 @@ class Iteration:
         # Signature-keyed executable reuse across rebuilt iterations
         # (SURVEY §7 hard part (a)); None = plain jit.
         self.compile_cache = compile_cache
+        # The programs carry stable public names: a profile reader finds
+        # `jit_adanet_train_step` after any refactor of this class.
         self._train_step = CachedStep(
-            self._train_step_impl, compile_cache, donate_argnums=0
+            self._train_step_impl,
+            compile_cache,
+            donate_argnums=0,
+            name="adanet_train_step",
         )
         self._train_multi_step = CachedStep(
-            self._train_multi_step_impl, compile_cache, donate_argnums=0
+            self._train_multi_step_impl,
+            compile_cache,
+            donate_argnums=0,
+            name="adanet_train_steps",
         )
-        self._eval_step = CachedStep(self._eval_step_impl, compile_cache)
+        self._eval_step = CachedStep(
+            self._eval_step_impl, compile_cache, name="adanet_eval_step"
+        )
 
     # ------------------------------------------------------------------ init
 
@@ -519,10 +542,16 @@ class Iteration:
 
     def frozen_outputs(self, frozen_params, features):
         """Forward passes of the frozen members (callable inside jit)."""
-        return [
-            fs.module.apply(params, features, training=False)
-            for fs, params in zip(self.frozen_subnetworks, frozen_params)
-        ]
+        outs = []
+        for fs, params in zip(self.frozen_subnetworks, frozen_params):
+            scope = scope_name(
+                "frozen", "t%d_%s" % (fs.iteration_number, fs.name)
+            )
+            with jax.named_scope(scope):
+                outs.append(
+                    fs.module.apply(params, features, training=False)
+                )
+        return outs
 
     def member_outputs(self, espec, sub_outs, frozen_outs):
         """Resolves an ensemble spec's member refs to concrete outputs."""
@@ -549,35 +578,42 @@ class Iteration:
         features, weights = split_example_weights(features, self.weight_key)
 
         def loss_fn(p):
-            variables = {**st.variables, "params": p}
-            out, mutated = self._apply_subnetwork(
-                spec, variables, features, True, {"dropout": dropout_rng}
-            )
-            loss = spec.builder.build_subnetwork_loss(
-                out, labels, self.head, loss_context
-            )
-            if loss is None:
-                loss = self.head.loss(out.logits, labels, weights)
-            return loss, (out, mutated)
+            # Opened inside the differentiated function: the forward
+            # pass arrives as `jvp(candidate.<name>)`, the backward as
+            # `transpose(jvp(candidate.<name>))`.
+            with jax.named_scope(scope_name("candidate", spec.name)):
+                variables = {**st.variables, "params": p}
+                out, mutated = self._apply_subnetwork(
+                    spec, variables, features, True, {"dropout": dropout_rng}
+                )
+                loss = spec.builder.build_subnetwork_loss(
+                    out, labels, self.head, loss_context
+                )
+                if loss is None:
+                    loss = self.head.loss(out.logits, labels, weights)
+                return loss, (out, mutated)
 
         (loss, (out, mutated)), grads = jax.value_and_grad(
             loss_fn, has_aux=True
         )(st.variables["params"])
-        updates, new_opt = spec.tx.update(
-            grads, st.opt_state, st.variables["params"]
-        )
-        stepped_vars = {
-            **st.variables,
-            **(mutated or {}),
-            "params": optax.apply_updates(st.variables["params"], updates),
-        }
-        ok = jnp.isfinite(loss) & tree_finite(grads) & ~st.dead
-        new_st = SubnetworkTrainState(
-            variables=tree_where(ok, stepped_vars, st.variables),
-            opt_state=tree_where(ok, new_opt, st.opt_state),
-            step=st.step + ok.astype(jnp.int32),
-            dead=st.dead | ~jnp.isfinite(loss),
-        )
+        with jax.named_scope(scope_name("optimizer", spec.name)):
+            updates, new_opt = spec.tx.update(
+                grads, st.opt_state, st.variables["params"]
+            )
+            stepped_vars = {
+                **st.variables,
+                **(mutated or {}),
+                "params": optax.apply_updates(
+                    st.variables["params"], updates
+                ),
+            }
+            ok = jnp.isfinite(loss) & tree_finite(grads) & ~st.dead
+            new_st = SubnetworkTrainState(
+                variables=tree_where(ok, stepped_vars, st.variables),
+                opt_state=tree_where(ok, new_opt, st.opt_state),
+                step=st.step + ok.astype(jnp.int32),
+                dead=st.dead | ~jnp.isfinite(loss),
+            )
         return new_st, out, loss
 
     def ensemble_update(
@@ -591,9 +627,10 @@ class Iteration:
         member_outs = [jax.lax.stop_gradient(o) for o in member_outs]
 
         def ensemble_loss(p):
-            ens = espec.ensembler.build_ensemble(p, member_outs)
-            loss = self.head.loss(ens.logits, labels, weights)
-            return loss + _complexity_regularization(ens), loss
+            with jax.named_scope(scope_name("ensemble", espec.name)):
+                ens = espec.ensembler.build_ensemble(p, member_outs)
+                loss = self.head.loss(ens.logits, labels, weights)
+                return loss + _complexity_regularization(ens), loss
 
         if espec.tx is None:
             adanet_loss, loss = ensemble_loss(est.params)
@@ -602,19 +639,23 @@ class Iteration:
             (adanet_loss, loss), grads = jax.value_and_grad(
                 ensemble_loss, has_aux=True
             )(est.params)
-            updates, new_opt = espec.tx.update(
-                grads, est.opt_state, est.params
-            )
-            stepped = optax.apply_updates(est.params, updates)
-            ok = jnp.isfinite(adanet_loss) & tree_finite(grads)
-            new_est = EnsembleTrainState(
-                params=tree_where(ok, stepped, est.params),
-                opt_state=tree_where(ok, new_opt, est.opt_state),
-            )
+            with jax.named_scope(
+                scope_name("ensemble_optimizer", espec.name)
+            ):
+                updates, new_opt = espec.tx.update(
+                    grads, est.opt_state, est.params
+                )
+                stepped = optax.apply_updates(est.params, updates)
+                ok = jnp.isfinite(adanet_loss) & tree_finite(grads)
+                new_est = EnsembleTrainState(
+                    params=tree_where(ok, stepped, est.params),
+                    opt_state=tree_where(ok, new_opt, est.opt_state),
+                )
         if espec.track_ema:
-            new_cstate = candidate_lib.update_candidate_state(
-                cstate, adanet_loss, self.adanet_loss_decay
-            )
+            with jax.named_scope("step.metrics"):
+                new_cstate = candidate_lib.update_candidate_state(
+                    cstate, adanet_loss, self.adanet_loss_decay
+                )
         else:
             new_cstate = cstate
         return new_est, new_cstate, adanet_loss, loss
@@ -632,7 +673,8 @@ class Iteration:
         if not self.collect_summaries:
             return {}
         hook = getattr(spec.builder, "build_subnetwork_summaries", None)
-        extra = hook(out, features, labels) if hook else None
+        with jax.named_scope("step.metrics"):
+            extra = hook(out, features, labels) if hook else None
         return {
             "summary/%s/%s" % (spec.name, tag): value
             for tag, value in (extra or {}).items()

@@ -20,11 +20,20 @@ Cost model: recording is one clock read per edge plus a deque append
 (the ring buffer is a `deque(maxlen=...)` — append is atomic under the
 GIL, no lock on the hot path; snapshots copy under a lock). DISABLED
 tracing is the contract the overhead gate in `tests/` enforces: zero
-clock reads, zero allocations beyond returning a shared no-op span.
+clock reads, no profiler annotation, zero allocations beyond returning
+a shared no-op span.
+
+Every enabled span is mirrored as a `jax.profiler.TraceAnnotation` of
+the same name (correlation and attributes as its keyword arguments),
+so any profile taken of the program shows the program's spans in the
+host plane on the clock of the device lanes. With no profiler session
+open the annotation is a flag check; the ring keeps its own clock.
+Instants stay ring-only.
 
 The clock is injected (`clock=`), monotonic by default, and must never
 be read from jit-traced code — jaxlint JL016 enforces that repo-wide;
-traced device timing belongs to `utils/device_timing.py`.
+device time comes from the profiler trace (`benchmarks/trace_reduce.py`
+for the step, `benchmarks/scope_reduce.py` by `named_scope`).
 """
 
 from __future__ import annotations
@@ -35,6 +44,8 @@ import os
 import threading
 import time
 from typing import Any, Dict, List, Optional
+
+from jax import profiler as _profiler
 
 __all__ = ["SpanEvent", "Span", "Tracer", "tracer"]
 
@@ -116,7 +127,7 @@ class Span:
     """An OPEN span: a context manager that records itself on exit."""
 
     __slots__ = ("_tracer", "name", "span_id", "parent_id",
-                 "correlation", "attrs", "_start")
+                 "correlation", "attrs", "_start", "_annotation")
 
     def __init__(self, tracer, name, span_id, parent_id, correlation, attrs):
         self._tracer = tracer
@@ -126,6 +137,7 @@ class Span:
         self.correlation = correlation
         self.attrs = attrs
         self._start = 0.0
+        self._annotation = None
 
     def set(self, **attrs) -> "Span":
         """Attaches attributes to an open span (e.g. a result count)."""
@@ -133,6 +145,12 @@ class Span:
         return self
 
     def __enter__(self) -> "Span":
+        # The profiler's mirror of this span: it encodes its arguments
+        # only while a profiler session is open.
+        self._annotation = _profiler.TraceAnnotation(
+            self.name, **{**self.correlation, **self.attrs}
+        )
+        self._annotation.__enter__()
         self._start = self._tracer._now()
         self._tracer._push(self)
         return self
@@ -141,6 +159,7 @@ class Span:
         if exc_type is not None:
             self.attrs.setdefault("error", exc_type.__name__)
         self._tracer._pop(self)
+        self._annotation.__exit__(exc_type, exc, tb)
 
 
 class _NoopSpan:
@@ -298,13 +317,6 @@ class Tracer:
                 thread=threading.current_thread().name,
             )
         )
-
-    def current_correlation(self) -> Dict[str, Any]:
-        """The ambient correlation tags on this thread (empty when no
-        span is open) — for consumers that label metrics or log lines
-        with the active trace position."""
-        stack = self._stack()
-        return dict(stack[-1].correlation) if stack else {}
 
     def events(self) -> List[SpanEvent]:
         """Snapshot of the ring, oldest first.

@@ -123,6 +123,61 @@ def test_ring_buffer_wraparound_keeps_newest():
     assert names == ["s6", "s7", "s8", "s9"]  # oldest evicted, order kept
 
 
+class _FakeAnnotation:
+    """Stands in for `jax.profiler.TraceAnnotation`: records what the
+    tracer builds and enters."""
+
+    built = []
+
+    def __init__(self, name, **kwargs):
+        self.name, self.kwargs, self.state = name, kwargs, "built"
+        _FakeAnnotation.built.append(self)
+
+    def __enter__(self):
+        self.state = "entered"
+        return self
+
+    def __exit__(self, *exc):
+        self.state = "exited"
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    import jax
+
+    monkeypatch.setattr(_FakeAnnotation, "built", [])
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _FakeAnnotation)
+    return _FakeAnnotation.built
+
+
+@pytest.mark.parametrize("enabled", [True, False])
+def test_span_mirrors_itself_as_a_trace_annotation(annotations, enabled):
+    """An enabled span enters a `TraceAnnotation` of its own name, with
+    its correlation and attributes as keyword arguments, and leaves it
+    when it closes; a disabled one builds none. Instants stay in the
+    ring alone."""
+    tracer = Tracer(capacity=8, clock=FakeClock(), enabled=enabled)
+    with tracer.span("search", correlation={"search_id": "s1"}):
+        with tracer.span(
+            "train_window", correlation={"iteration": 2}, steps=1
+        ):
+            assert [a.state for a in annotations] == (
+                ["entered", "entered"] if enabled else []
+            )
+            tracer.instant("fault.trip", site="x")
+    if not enabled:
+        assert annotations == []
+        return
+    assert [(a.name, a.kwargs, a.state) for a in annotations] == [
+        ("search", {"search_id": "s1"}, "exited"),
+        (
+            "train_window",
+            {"search_id": "s1", "iteration": 2, "steps": 1},
+            "exited",
+        ),
+    ]
+
+
 def test_disabled_tracer_reads_no_clock_and_records_nothing():
     clock = FakeClock()
     tracer = Tracer(capacity=4, clock=clock, enabled=False)
@@ -336,10 +391,13 @@ def test_flight_dump_survives_sigkill_mid_write(tmp_path):
 # ----------------------------------------------------------- overhead gate
 
 
-def test_overhead_gate_disabled_tracing_reads_no_clock(tmp_path):
+def test_overhead_gate_disabled_tracing_reads_no_clock(
+    tmp_path, annotations
+):
     """ISSUE 12 satellite: with tracing disabled, the instrumented step
     path must cost ZERO tracer clock reads (counted — wall-time noise
-    proves nothing) and append nothing to the ring."""
+    proves nothing), append nothing to the ring and build no profiler
+    annotation."""
     tracer = spans_lib.tracer()
     was_enabled = tracer.enabled
     try:
@@ -350,6 +408,7 @@ def test_overhead_gate_disabled_tracing_reads_no_clock(tmp_path):
         est.train(input_fn, max_steps=6)
         assert tracer.clock_reads == reads_before
         assert len(tracer.events()) == events_before
+        assert annotations == []
         # The control: the SAME path with tracing enabled reads the
         # clock and records spans — proving the gate watches a real
         # instrumentation seam, not dead code.
@@ -362,11 +421,142 @@ def test_overhead_gate_disabled_tracing_reads_no_clock(tmp_path):
             for e in tracer.events()[events_before:]
         ]
         assert "train_window" in new and "search" in new
+        assert {"train_window", "search"} <= {a.name for a in annotations}
     finally:
         if was_enabled:
             tracer.enable()
         else:
             tracer.disable()
+
+
+# ------------------------------------------- the spans of one train call
+
+#: Every span one resumed `Estimator.train` call leaves (docs/
+#: observability.md, "Spans of one `Estimator.train` call").
+TRAIN_CALL_SPANS = {
+    "search",
+    "resume.fsck",
+    "input.next_batch",
+    "input.place_batch",
+    "iteration.build",
+    "iteration.init_state",
+    "checkpoint.restore",
+    "train_window",
+    "train.log",
+    "checkpoint.save",
+    "checkpoint.fetch",
+    "checkpoint.write",
+    "iteration.complete",
+}
+
+
+@pytest.fixture(scope="module")
+def resumed_search_events(tmp_path_factory):
+    """The ring after a search stopped mid-iteration (3 of 6 steps) and
+    resumed to its end (2 iterations) by a fresh estimator."""
+    tracer = spans_lib.tracer()
+    was_enabled = tracer.enabled
+    tracer.enable()
+    tracer.clear()
+    try:
+        model_dir = str(tmp_path_factory.mktemp("spans") / "model")
+        build_estimator(model_dir, log_every_steps=2).train(
+            input_fn, max_steps=3
+        )
+        est = build_estimator(model_dir, log_every_steps=2)
+        est.train(input_fn, max_steps=100)
+        assert est.latest_iteration_number() == 2
+        return tracer.events()
+    finally:
+        tracer.clear()
+        if not was_enabled:
+            tracer.disable()
+
+
+def _calls(events):
+    searches = [e for e in events if e.name == "search"]
+    assert len(searches) == 2
+    return [
+        (search, [e for e in events if e.parent_id == search.span_id])
+        for search in searches
+    ]
+
+
+@pytest.mark.parametrize("name", sorted(TRAIN_CALL_SPANS))
+def test_resumed_train_call_leaves_every_span(resumed_search_events, name):
+    search, children = _calls(resumed_search_events)[1]
+    inside = {search.span_id} | {e.span_id for e in children}
+    names = {search.name} | {
+        e.name for e in resumed_search_events if e.parent_id in inside
+    }
+    assert name in names
+    for event in resumed_search_events:
+        if event.name == name:
+            assert event.correlation["search_id"]
+            assert event.end >= event.start
+
+
+def test_train_call_spans_nest_and_carry_their_sizes(resumed_search_events):
+    first, resumed = _calls(resumed_search_events)
+    # A call that starts from nothing restores nothing.
+    assert "checkpoint.restore" not in {e.name for e in first[1]}
+    search, children = resumed
+    by_name = {}
+    for event in children:
+        by_name.setdefault(event.name, []).append(event)
+    # `search` is the whole call: it opens before the fsck pass.
+    fsck = by_name["resume.fsck"][0]
+    assert search.start <= fsck.start and fsck.attrs["verdict"] == "clean"
+    assert search.attrs["max_steps"] == 100
+    restore = by_name["checkpoint.restore"][0]
+    assert restore.attrs["global_step"] == 3 and restore.attrs["bytes"] > 0
+    assert restore.correlation["iteration"] == 0
+    assert by_name["iteration.build"][0].attrs["candidates"] >= 2
+    # The first window of each iteration says so, and no other.
+    windows = by_name["train_window"]
+    assert [w.correlation["iteration"] for w in windows if w.attrs["first"]] \
+        == [0, 1]
+    assert len(windows) == 3 + 6
+    assert {e.attrs["stacked"] for e in by_name["input.place_batch"]} == {
+        False
+    }
+    assert by_name["train.log"][0].attrs["global_step"] == 4
+    # Every save is a fetch then a write, both inside it, both sized.
+    for save in by_name["checkpoint.save"]:
+        inside = [
+            e for e in resumed_search_events if e.parent_id == save.span_id
+        ]
+        assert [e.name for e in inside] == [
+            "checkpoint.fetch",
+            "checkpoint.write",
+        ]
+        fetch, write = inside
+        assert save.start <= fetch.start <= fetch.end <= write.start
+        assert write.end <= save.end
+        assert fetch.attrs["bytes"] > 0 and write.attrs["bytes"] > 0
+        assert fetch.correlation["iteration"] == save.correlation["iteration"]
+    # The resume phases of one call follow one another.
+    resume = sorted(
+        [
+            fsck,
+            by_name["input.next_batch"][0],
+            by_name["iteration.build"][0],
+            by_name["iteration.init_state"][0],
+            restore,
+            windows[0],
+        ],
+        key=lambda e: e.start,
+    )
+    assert [e.name for e in resume] == [
+        "resume.fsck",
+        "input.next_batch",
+        "iteration.build",
+        "iteration.init_state",
+        "checkpoint.restore",
+        "train_window",
+    ]
+    for before, after in zip(resume, resume[1:]):
+        assert before.end <= after.start
 
 
 # ------------------------------------------------- trace_view / acceptance

@@ -508,6 +508,12 @@ def _read_verified(model_dir: str, filename: str) -> bytes:
 def restore_pytree(model_dir: str, filename: str, target: Any) -> Any:
     """Restores a pytree saved by `save_pytree` onto a matching target.
 
+    The target gives the STRUCTURE, and each leaf its shape and dtype:
+    real arrays or `jax.ShapeDtypeStruct`s (`Iteration.state_template`)
+    serve alike, and nothing of the target is fetched. Every restored
+    leaf is checked against the target's shape and dtype, since a
+    caller holding a template has no real arrays left to disagree with.
+
     Verifies the payload digest before deserializing; wraps decode
     failures in `CheckpointCorruptionError`. Legacy NASNet checkpoints
     missing the `batch_stats` `count` leaf (written before the
@@ -523,8 +529,9 @@ def restore_pytree(model_dir: str, filename: str, target: Any) -> Any:
         raise CheckpointCorruptionError(
             path, "undecodable msgpack: %s" % exc
         ) from exc
-    template = serialization.to_state_dict(jax.device_get(target))
-    state_dict, injected = _inject_missing_count(state_dict, template)
+    state_dict, injected = _inject_missing_count(
+        state_dict, serialization.to_state_dict(target)
+    )
     if injected:
         _LOG.warning(
             "Migrated legacy checkpoint %s: injected %d missing "
@@ -534,11 +541,44 @@ def restore_pytree(model_dir: str, filename: str, target: Any) -> Any:
             injected,
         )
     try:
-        return serialization.from_state_dict(target, state_dict)
+        restored = serialization.from_state_dict(target, state_dict)
+        _check_leaves(target, restored)
     except Exception as exc:
         raise CheckpointCorruptionError(
             path, "state does not match target structure: %s" % exc
         ) from exc
+    return restored
+
+
+def _check_leaves(target, restored) -> None:
+    """Raises ValueError where a restored leaf's shape or dtype is not
+    its target's. Python scalars carry no dtype of their own (msgpack
+    keeps a float as a float) and pass on their shape."""
+    import numpy as np
+
+    leaves, treedef = jax.tree_util.tree_flatten_with_path(target)
+    for (key_path, want), got in zip(
+        leaves, treedef.flatten_up_to(restored)
+    ):
+        if not hasattr(want, "shape") or not hasattr(want, "dtype"):
+            continue
+        if not isinstance(got, (np.ndarray, np.generic, int, float)):
+            fault = "holds a %s" % type(got).__name__
+        elif np.shape(got) != tuple(want.shape):
+            fault = "has shape %s" % (np.shape(got),)
+        elif hasattr(got, "dtype") and got.dtype != want.dtype:
+            fault = "has dtype %s" % got.dtype
+        else:
+            continue
+        raise ValueError(
+            "%s %s, the target an array of shape %s and dtype %s"
+            % (
+                jax.tree_util.keystr(key_path),
+                fault,
+                tuple(want.shape),
+                want.dtype,
+            )
+        )
 
 
 def save_payload(model_dir: str, filename: str, payload: Any) -> str:

@@ -1840,91 +1840,124 @@ class Estimator:
     def _init_or_restore_state(
         self, iteration, sample_batch, info, replicate: bool = True
     ):
-        tracer = spans_lib.tracer()
-        tags = {"iteration": iteration.iteration_number}
-        with tracer.span("iteration.init_state", correlation=tags):
-            state = iteration.init_state(
-                self._iteration_rng(iteration.iteration_number), sample_batch
-            )
+        """The iteration's state: the checkpoint's where `info` names
+        one, restored over the state's TEMPLATE (no value is built to be
+        overwritten), else a real deterministic initialization."""
+        state = None
         if info.iteration_state_file:
-            restored = None
-            try:
-                with tracer.span(
-                    "checkpoint.restore",
-                    correlation=tags,
-                    global_step=info.global_step,
-                ) as restore_span:
-                    restored = ckpt_lib.restore_pytree(
-                        self._model_dir, info.iteration_state_file, state
-                    )
-                    restore_span.set(
-                        bytes=os.path.getsize(
-                            os.path.join(
-                                self._model_dir, info.iteration_state_file
-                            )
-                        )
-                    )
-            except (ckpt_lib.CheckpointCorruptionError, OSError) as exc:
-                # Verify-on-restore tripped on a file the pre-train fsck
-                # pass considered intact (bit rot between scans, or a
-                # decode-level mismatch): quarantine and degrade to
-                # "restart this iteration from its first step" on the
-                # fresh deterministic init above. OSError covers the
-                # multi-host race where the chief's concurrent heal just
-                # quarantined the file out from under this process.
-                _LOG.error(
-                    "Mid-iteration state corrupt at restore time (%s); "
-                    "rolling back to the start of iteration %d.",
-                    exc,
-                    info.iteration_number,
+            state = self._restore_over_template(iteration, sample_batch, info)
+            reason = "restore_failed"
+        elif iteration.iteration_number == 0:
+            reason = "fresh"
+        else:
+            reason = "new_iteration"
+        if state is None:
+            with spans_lib.tracer().span(
+                "iteration.init_state",
+                correlation={"iteration": iteration.iteration_number},
+                abstract=False,
+                cached=False,
+                reason=reason,
+            ):
+                state = iteration.init_state(
+                    self._iteration_rng(iteration.iteration_number),
+                    sample_batch,
                 )
-            failed = restored is None
-            if jax.process_count() > 1:
-                # The verdict must be COLLECTIVE: one process rolling
-                # back alone (only ITS read hit the rot) would carry a
-                # different global_step and fresh-init params into the
-                # replication below — silent divergence or misaligned
-                # collective boundaries. All roll back iff any failed.
-                from adanet_tpu.distributed.multihost import (
-                    allgather_host_flag,
-                )
-
-                try:
-                    failed = bool(
-                        np.max(
-                            allgather_host_flag(
-                                int(failed), label="restore agreement"
-                            )
-                        )
-                    )
-                except watchdog_lib.PeerLostError as exc:
-                    _LOG.error(
-                        "Peer lost at the restore agreement: %s", exc
-                    )
-                    self._peer_lost = exc  # degrade; local verdict stands
-            if failed:
-                stale = info.iteration_state_file
-                info.iteration_state_file = None
-                from adanet_tpu.robustness import integrity
-
-                info.global_step = integrity.end_step_of(
-                    info, self._model_dir, info.iteration_number
-                )
-                if coordination.is_chief():
-                    ckpt_lib.quarantine_file(self._model_dir, stale)
-                    ckpt_lib.write_manifest(self._model_dir, info)
-            else:
-                state = restored
-                _LOG.info(
-                    "Restored mid-iteration state from %s",
-                    info.iteration_state_file,
-                )
+            metrics_lib.registry().counter(
+                "estimator.resume.real_inits"
+            ).inc()
         if self._spmd_mesh is not None and replicate:
             # Replicate over the process-spanning mesh. Initialization is
             # deterministic (same seed, same shapes on every process), so
             # each process contributes an identical value.
             state = replicate_state(state, self._spmd_mesh)
         return state
+
+    def _restore_over_template(self, iteration, sample_batch, info):
+        """The mid-iteration state `info` names, or None after rolling
+        `info` back to the iteration's first step (the caller then runs
+        the real init, on every process alike)."""
+        tracer = spans_lib.tracer()
+        tags = {"iteration": iteration.iteration_number}
+        with tracer.span(
+            "iteration.init_state", correlation=tags, abstract=True
+        ) as template_span:
+            traces = iteration.state_template_traces
+            template = iteration.state_template(sample_batch)
+            template_span.set(
+                cached=traces == iteration.state_template_traces
+            )
+        restored = None
+        try:
+            with tracer.span(
+                "checkpoint.restore",
+                correlation=tags,
+                global_step=info.global_step,
+            ) as restore_span:
+                restored = ckpt_lib.restore_pytree(
+                    self._model_dir, info.iteration_state_file, template
+                )
+                restore_span.set(
+                    bytes=os.path.getsize(
+                        os.path.join(
+                            self._model_dir, info.iteration_state_file
+                        )
+                    )
+                )
+        except (ckpt_lib.CheckpointCorruptionError, OSError) as exc:
+            # Verify-on-restore tripped on a file the pre-train fsck
+            # pass considered intact (bit rot between scans, or a
+            # decode-level mismatch): quarantine and degrade to
+            # "restart this iteration from its first step" on a fresh
+            # deterministic init. OSError covers the multi-host race
+            # where the chief's concurrent heal just quarantined the
+            # file out from under this process.
+            _LOG.error(
+                "Mid-iteration state corrupt at restore time (%s); "
+                "rolling back to the start of iteration %d.",
+                exc,
+                info.iteration_number,
+            )
+        failed = restored is None
+        if jax.process_count() > 1:
+            # The verdict must be COLLECTIVE: one process rolling
+            # back alone (only ITS read hit the rot) would carry a
+            # different global_step and fresh-init params into the
+            # replication — silent divergence or misaligned
+            # collective boundaries. All roll back iff any failed.
+            from adanet_tpu.distributed.multihost import (
+                allgather_host_flag,
+            )
+
+            try:
+                failed = bool(
+                    np.max(
+                        allgather_host_flag(
+                            int(failed), label="restore agreement"
+                        )
+                    )
+                )
+            except watchdog_lib.PeerLostError as exc:
+                _LOG.error("Peer lost at the restore agreement: %s", exc)
+                self._peer_lost = exc  # degrade; local verdict stands
+        if failed:
+            stale = info.iteration_state_file
+            info.iteration_state_file = None
+            from adanet_tpu.robustness import integrity
+
+            info.global_step = integrity.end_step_of(
+                info, self._model_dir, info.iteration_number
+            )
+            if coordination.is_chief():
+                ckpt_lib.quarantine_file(self._model_dir, stale)
+                ckpt_lib.write_manifest(self._model_dir, info)
+            return None
+        metrics_lib.registry().counter("estimator.resume.templates").inc()
+        _LOG.info(
+            "Restored mid-iteration state from %s",
+            info.iteration_state_file,
+        )
+        return restored
 
     def _save_iteration_state(self, info, iteration_number, state) -> None:
         with spans_lib.tracer().span(

@@ -21,6 +21,7 @@ Key mappings:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import math
@@ -95,6 +96,50 @@ class IterationState:
     frozen: List[Any]  # variable collections of frozen members
     iteration_step: jnp.ndarray
     rng: Any
+
+
+def abstract_state(state):
+    """The state's template: every leaf as its shape and dtype alone.
+
+    What `Iteration.state_template` returns and what a real `init_state`
+    records of its result, through this one function, so the two compare
+    equal (a weak type or a sharding is no part of a state's structure).
+    """
+    return jax.tree_util.tree_map(
+        lambda leaf: jax.ShapeDtypeStruct(
+            jnp.shape(leaf), jnp.result_type(leaf)
+        ),
+        state,
+    )
+
+
+def _batch_signature(sample_batch):
+    """Hashable structure, shapes and dtypes of a sample batch."""
+    leaves, treedef = jax.tree_util.tree_flatten(sample_batch)
+    return treedef, tuple(
+        (jnp.shape(leaf), str(jnp.result_type(leaf))) for leaf in leaves
+    )
+
+
+@contextlib.contextmanager
+def _must_trace(kind: str, name: str):
+    """Names whose initialization cannot run under `jax.eval_shape`.
+
+    `state_template` traces the very code the real init runs, and does
+    not fall back to the eager init when that fails: a `float(...)`,
+    `np.asarray(...)` or `.item()` on a value of the state is an error
+    that says where.
+    """
+    try:
+        yield
+    except jax.errors.JAXTypeError as exc:
+        raise TypeError(
+            "Initializing %s %r read the VALUE of a traced array. "
+            "Iteration.state_template runs init_state under "
+            "jax.eval_shape, so modules, initializers, optimizers and "
+            "ensemblers may read shapes and dtypes only: %s"
+            % (kind, name, exc)
+        ) from exc
 
 
 @dataclasses.dataclass(frozen=True)
@@ -287,11 +332,47 @@ class Iteration:
         self._eval_step = CachedStep(
             self._eval_step_impl, compile_cache, name="adanet_eval_step"
         )
+        # The state's template by sample-batch signature, and how many
+        # abstract traces of `_init_state` this instance has run.
+        self._state_templates: Dict[Any, IterationState] = {}
+        self.state_template_traces = 0
 
     # ------------------------------------------------------------------ init
 
     def init_state(self, rng, sample_batch) -> IterationState:
         """Initializes every candidate's parameters and optimizer state."""
+        state = self._init_state(rng, sample_batch)
+        # A process that initialized for real never traces the template.
+        self._state_templates[_batch_signature(sample_batch)] = (
+            abstract_state(state)
+        )
+        return state
+
+    def state_template(self, sample_batch) -> IterationState:
+        """The structure `init_state` would return, with no value in it.
+
+        A pytree of `jax.ShapeDtypeStruct` from `jax.eval_shape` over the
+        code `init_state` runs (one definition of the state's structure),
+        remembered on the instance by the sample batch's shapes and
+        dtypes. For a caller about to restore a checkpoint over every
+        leaf: no op runs, on the device or eagerly on the host.
+        """
+        signature = _batch_signature(sample_batch)
+        template = self._state_templates.get(signature)
+        if template is None:
+            template = abstract_state(
+                jax.eval_shape(
+                    lambda batch: self._init_state(
+                        jax.random.PRNGKey(0), batch
+                    ),
+                    sample_batch,
+                )
+            )
+            self.state_template_traces += 1
+            self._state_templates[signature] = template
+        return template
+
+    def _init_state(self, rng, sample_batch) -> IterationState:
         features, _ = sample_batch
         features, _ = split_example_weights(
             features, self.weight_key, require=False
@@ -300,13 +381,14 @@ class Iteration:
         sub_shapes = {}
         for spec in self.subnetwork_specs:
             rng, params_rng, dropout_rng = jax.random.split(rng, 3)
-            variables = spec.module.init(
-                {"params": params_rng, "dropout": dropout_rng},
-                features,
-                training=True,
-            )
-            variables = self._graft_initial_variables(spec, variables)
-            opt_state = spec.tx.init(variables["params"])
+            with _must_trace("builder", spec.name):
+                variables = spec.module.init(
+                    {"params": params_rng, "dropout": dropout_rng},
+                    features,
+                    training=True,
+                )
+                variables = self._graft_initial_variables(spec, variables)
+                opt_state = spec.tx.init(variables["params"])
             sub_states[spec.name] = SubnetworkTrainState(
                 variables=variables,
                 opt_state=opt_state,
@@ -333,22 +415,26 @@ class Iteration:
         cand_states = {}
         for espec in self.ensemble_specs:
             rng, ens_rng = jax.random.split(rng)
-            if espec.initial_params is not None:
-                params = jax.tree_util.tree_map(
-                    jnp.asarray, espec.initial_params
+            with _must_trace("ensemble", espec.name):
+                if espec.initial_params is not None:
+                    params = jax.tree_util.tree_map(
+                        jnp.asarray, espec.initial_params
+                    )
+                else:
+                    member_shapes = [
+                        sub_shapes[ref]
+                        if kind == _NEW
+                        else frozen_shapes[ref]
+                        for kind, ref in espec.members
+                    ]
+                    params = espec.ensembler.init_ensemble(
+                        ens_rng,
+                        member_shapes,
+                        previous_params=self._warm_start_params(espec),
+                    )
+                opt_state = (
+                    espec.tx.init(params) if espec.tx is not None else ()
                 )
-            else:
-                member_shapes = [
-                    sub_shapes[ref] if kind == _NEW else frozen_shapes[ref]
-                    for kind, ref in espec.members
-                ]
-                previous_params = self._warm_start_params(espec)
-                params = espec.ensembler.init_ensemble(
-                    ens_rng, member_shapes, previous_params=previous_params
-                )
-            opt_state = (
-                espec.tx.init(params) if espec.tx is not None else ()
-            )
             ens_states[espec.name] = EnsembleTrainState(
                 params=params, opt_state=opt_state
             )

@@ -253,6 +253,88 @@ class TestCheckpoint:
             restored["p"]["dense"]["kernel"], np.ones((3, 2))
         )
 
+    def _saved_state(self, tmp_path):
+        import optax
+
+        params = {"dense": {"kernel": jnp.full((3, 2), 2.0)}}
+        state = {
+            "p": params,
+            "o": optax.adam(1e-3).init(params),
+            "step": jnp.asarray(7, jnp.int32),
+            "dead": jnp.asarray(False),
+            "frozen": [{"w": jnp.ones((2,), jnp.bfloat16)}],
+            "none": None,
+        }
+        ckpt_lib.save_pytree(str(tmp_path), "s.msgpack", state)
+        return state
+
+    def test_pytree_restores_onto_abstract_target(self, tmp_path):
+        """A target of `jax.ShapeDtypeStruct`s (`Iteration.state_template`)
+        restores what a target of real arrays restores: every leaf comes
+        from the file, and nothing of the target is fetched."""
+        import jax
+
+        from adanet_tpu.core.iteration import abstract_state
+
+        state = self._saved_state(tmp_path)
+        template = abstract_state(state)
+        onto_template = ckpt_lib.restore_pytree(
+            str(tmp_path), "s.msgpack", template
+        )
+        onto_arrays = ckpt_lib.restore_pytree(
+            str(tmp_path), "s.msgpack", state
+        )
+        assert jax.tree_util.tree_structure(
+            onto_template
+        ) == jax.tree_util.tree_structure(state)
+        for got, ref, want in zip(
+            jax.tree_util.tree_leaves(onto_template),
+            jax.tree_util.tree_leaves(onto_arrays),
+            jax.tree_util.tree_leaves(state),
+        ):
+            assert isinstance(got, np.ndarray)  # numpy, as ever
+            assert got.dtype == want.dtype and got.shape == want.shape
+            assert got.tobytes() == ref.tobytes() == np.asarray(want).tobytes()
+
+    @pytest.mark.parametrize(
+        "leaf, complaint",
+        [
+            (jnp.zeros((3, 3)), "shape"),
+            (jnp.zeros((6,)), "shape"),
+            (jnp.zeros((3, 2), jnp.bfloat16), "dtype"),
+            (jnp.zeros((3, 2), jnp.int32), "dtype"),
+        ],
+    )
+    @pytest.mark.parametrize("abstract", [True, False])
+    def test_restore_rejects_a_leaf_of_the_wrong_shape_or_dtype(
+        self, tmp_path, leaf, complaint, abstract
+    ):
+        """The restore itself holds every leaf to its target: with a
+        template nothing downstream has real arrays to disagree with."""
+        from adanet_tpu.core.iteration import abstract_state
+
+        target = self._saved_state(tmp_path)
+        target["p"]["dense"]["kernel"] = leaf
+        if abstract:
+            target = abstract_state(target)
+        with pytest.raises(
+            ckpt_lib.CheckpointCorruptionError, match=complaint
+        ) as exc:
+            ckpt_lib.restore_pytree(str(tmp_path), "s.msgpack", target)
+        assert "kernel" in str(exc.value)
+
+    def test_restore_rejects_a_subtree_where_the_target_has_a_leaf(
+        self, tmp_path
+    ):
+        import jax
+
+        target = self._saved_state(tmp_path)
+        target["p"]["dense"] = jax.ShapeDtypeStruct((3, 2), jnp.float32)
+        with pytest.raises(
+            ckpt_lib.CheckpointCorruptionError, match="dense.*holds a dict"
+        ):
+            ckpt_lib.restore_pytree(str(tmp_path), "s.msgpack", target)
+
 
 class TestCountDownTimer:
     def test_counts_down(self):

@@ -18,6 +18,10 @@ from adanet_tpu import replay
 from adanet_tpu.core.estimator import Estimator
 from adanet_tpu.core.evaluator import Evaluator
 from adanet_tpu.core.report_materializer import ReportMaterializer
+from adanet_tpu.distributed import (
+    ElasticWorkQueueStrategy,
+    RoundRobinStrategy,
+)
 from adanet_tpu.ensemble import ComplexityRegularizedEnsembler
 from adanet_tpu.subnetwork import SimpleGenerator
 
@@ -705,3 +709,174 @@ def test_enable_summaries_false_writes_no_event_files(tmp_path):
         if "tfevents" in f
     ]
     assert event_files == []
+
+
+# ------------------------------------------- resume through the template
+
+
+def _auto_ensemble_with_initial_variables(root):
+    import flax.linen as nn
+    import jax
+    import jax.numpy as jnp
+
+    from adanet_tpu import AutoEnsembleEstimator, AutoEnsembleSubestimator
+
+    class MLP(nn.Module):
+        @nn.compact
+        def __call__(self, features, training: bool = False):
+            x = nn.relu(nn.Dense(8)(jnp.asarray(features["x"], jnp.float32)))
+            return nn.Dense(1)(x)
+
+    module = MLP()
+    pretrained = jax.device_get(
+        module.init(
+            jax.random.PRNGKey(99),
+            {"x": np.zeros((2, 2), np.float32)},
+            training=True,
+        )
+    )
+    return AutoEnsembleEstimator(
+        head=adanet_tpu.RegressionHead(),
+        candidate_pool={
+            "frozen": AutoEnsembleSubestimator(
+                module, prediction_only=True, initial_variables=pretrained
+            ),
+            "finetune": AutoEnsembleSubestimator(
+                module, optimizer=optax.sgd(0.05), initial_variables=pretrained
+            ),
+        },
+        max_iteration_steps=8,
+        max_iterations=2,
+        model_dir=str(root / "model"),
+        log_every_steps=0,
+    )
+
+
+def _placed(strategy_factory):
+    return lambda root: _make_estimator(
+        root, max_iterations=2, placement_strategy=strategy_factory()
+    )
+
+
+_RESUMED = {
+    "fused": _placed(lambda: None),
+    "round_robin": _placed(RoundRobinStrategy),
+    "elastic": _placed(lambda: ElasticWorkQueueStrategy(window_steps=4)),
+    "autoensemble_initial_variables": _auto_ensemble_with_initial_variables,
+}
+
+
+def _resume_counters():
+    from adanet_tpu.observability import metrics as metrics_lib
+
+    registry = metrics_lib.registry()
+    return (
+        registry.counter("estimator.resume.templates").value,
+        registry.counter("estimator.resume.real_inits").value,
+    )
+
+
+@pytest.fixture
+def module_inits(monkeypatch):
+    """Every `flax.linen.Module.init` call from here on: True where it
+    ran abstractly (its keys were tracers), False where it ran for real."""
+    import flax.linen as nn
+    import jax
+
+    calls = []
+    real = nn.Module.init
+
+    def spy(self, rngs, *args, **kwargs):
+        calls.append(
+            all(
+                isinstance(leaf, jax.core.Tracer)
+                for leaf in jax.tree_util.tree_leaves(rngs)
+            )
+        )
+        return real(self, rngs, *args, **kwargs)
+
+    monkeypatch.setattr(nn.Module, "init", spy)
+    return calls
+
+
+@pytest.mark.parametrize("kind", sorted(_RESUMED))
+def test_resume_through_template_saves_the_same_bytes(
+    tmp_path, monkeypatch, module_inits, kind
+):
+    """A call that resumes restores over the state's template: it runs no
+    `module.init` for real, and what it trains and saves from there is
+    byte-equal to a resume that built the whole state first."""
+    import shutil
+
+    import jax
+
+    from adanet_tpu.core.iteration import Iteration
+
+    build = _RESUMED[kind]
+    stopped = tmp_path / "stopped"
+    est = build(stopped)
+    del module_inits[:]  # a builder of the test's may have made weights
+    est.train(linear_dataset(), max_steps=5)  # mid-iteration 0
+    assert module_inits and not any(module_inits)  # a fresh start is real
+    dirs = {}
+    for side in ("template", "real"):
+        dirs[side] = tmp_path / side
+        shutil.copytree(stopped, dirs[side])
+
+    templates, real_inits = _resume_counters()
+    est = build(dirs["template"])
+    del module_inits[:]
+    est.train(linear_dataset(), max_steps=6)
+    # A process that has not initialized traces the template once.
+    assert module_inits and all(module_inits)
+    assert _resume_counters() == (templates + 1, real_inits)
+    del module_inits[:]
+    est.train(linear_dataset(), max_steps=7)
+    # The kept `Iteration` remembers it: no `module.init` of any kind.
+    assert module_inits == []
+    assert _resume_counters() == (templates + 2, real_inits)
+    assert est._iteration_cache.state_template_traces == 1
+
+    # The other side restores over a state it built for real, as every
+    # resume did before the template.
+    monkeypatch.setattr(
+        Iteration,
+        "state_template",
+        lambda self, batch: self.init_state(jax.random.PRNGKey(0), batch),
+    )
+    est = build(dirs["real"])
+    del module_inits[:]
+    est.train(linear_dataset(), max_steps=6)
+    est.train(linear_dataset(), max_steps=7)
+    assert module_inits and not any(module_inits)
+
+    saved = {
+        side: (root / "model" / "ckpt-7.msgpack").read_bytes()
+        for side, root in dirs.items()
+    }
+    assert saved["template"] == saved["real"]
+
+
+def test_evaluate_and_predict_resume_through_the_template(
+    tmp_path, module_inits
+):
+    """`evaluate()` and `predict()` on a mid-iteration checkpoint come
+    through the same restore: a template, no real init."""
+    _make_estimator(tmp_path, max_iterations=2).train(
+        linear_dataset(), max_steps=5
+    )
+    del module_inits[:]
+    templates, real_inits = _resume_counters()
+    est = _make_estimator(tmp_path, max_iterations=2)
+    metrics = est.evaluate(linear_dataset())
+    assert np.isfinite(metrics["average_loss"])
+    preds = list(est.predict(linear_dataset()))
+    assert preds[0]["predictions"].shape == (16, 1)
+    assert module_inits and all(module_inits)
+    assert _resume_counters() == (templates + 2, real_inits)
+    # One template for a batch with labels, one for a batch without.
+    assert est._iteration_cache.state_template_traces == 2
+    del module_inits[:]
+    est.evaluate(linear_dataset())
+    assert module_inits == []
+    assert _resume_counters() == (templates + 3, real_inits)

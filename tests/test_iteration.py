@@ -195,3 +195,126 @@ def test_mean_ensembler_and_multiple_ensemblers():
     state = it.init_state(jax.random.PRNGKey(0), _sample_batch())
     state, metrics = it.train_step(state, _sample_batch())
     assert np.isfinite(float(metrics["adanet_loss/t0_dnn_grow_mean"]))
+
+
+# ------------------------------------------------ the state's template
+
+
+def _ensemblers():
+    """Every ensembler of `adanet_tpu/ensemble/`, each mixture-weight
+    type and the bias among them."""
+    from adanet_tpu.ensemble import MixtureWeightType
+
+    def weighted(weight_type, **kwargs):
+        return ComplexityRegularizedEnsembler(
+            optimizer=optax.adam(0.05),
+            mixture_weight_type=weight_type,
+            warm_start_mixture_weights=True,
+            **kwargs,
+        )
+
+    return {
+        "scalar": weighted(MixtureWeightType.SCALAR),
+        "vector_bias": weighted(MixtureWeightType.VECTOR, use_bias=True),
+        "matrix": weighted(MixtureWeightType.MATRIX),
+        "no_optimizer": ComplexityRegularizedEnsembler(),
+        "mean": MeanEnsembler(),
+    }
+
+
+def _template_of(state):
+    return jax.tree_util.tree_map(
+        lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype), state
+    )
+
+
+@pytest.mark.parametrize("ensembler", sorted(_ensemblers()))
+def test_state_template_is_the_shape_of_init_state(ensembler):
+    """`state_template` is `init_state` with every value left out: same
+    tree, same shapes, same dtypes, and no `module.init` run for real."""
+    fac = _builder_factory(
+        ensemblers=[_ensemblers()[ensembler]],
+        strategies=[GrowStrategy(), AllStrategy()],
+    )
+    builders = [DNNBuilder("dnn", 1), DNNBuilder("deep", 2)]
+    traced = fac.build_iteration(0, builders, None)
+    template = traced.state_template(_sample_batch())
+    assert traced.state_template_traces == 1
+    assert all(
+        isinstance(leaf, jax.ShapeDtypeStruct)
+        for leaf in jax.tree_util.tree_leaves(template)
+    )
+    # Remembered by the batch's shapes and dtypes.
+    assert traced.state_template(_sample_batch()) is template
+    assert traced.state_template_traces == 1
+
+    real = fac.build_iteration(0, builders, None)
+    state = real.init_state(jax.random.PRNGKey(0), _sample_batch())
+    assert template == _template_of(state)
+    # A real init records the template of what it returned: an instance
+    # that initialized for real never traces.
+    assert real.state_template(_sample_batch()) == template
+    assert real.state_template_traces == 0
+
+
+def test_state_template_with_frozen_member_and_warm_started_weights():
+    """Iteration 1: a frozen member's variables ride in the state, the
+    kept member's mixture weight and the bias are warm-started from the
+    previous ensemble, and the carried-over candidate's EMA is seeded."""
+    from adanet_tpu.ensemble import MixtureWeightType
+
+    fac = _builder_factory(
+        ensemblers=[
+            ComplexityRegularizedEnsembler(
+                optimizer=optax.sgd(0.05),
+                mixture_weight_type=MixtureWeightType.VECTOR,
+                warm_start_mixture_weights=True,
+                use_bias=True,
+            )
+        ]
+    )
+    it0 = fac.build_iteration(0, [DNNBuilder("dnn", 1)], None)
+    state0 = it0.init_state(jax.random.PRNGKey(0), _sample_batch())
+    for batch in linear_dataset()():
+        state0, _ = it0.train_step(state0, batch)
+    frozen = it0.freeze_candidate(
+        state0, "t0_dnn_grow_complexity_regularized", _sample_batch()
+    )
+    assert frozen.ensembler_params["weights"]  # something to warm-start
+
+    traced = fac.build_iteration(1, [DNNBuilder("dnn2", 2)], frozen)
+    template = traced.state_template(_sample_batch())
+    real = fac.build_iteration(1, [DNNBuilder("dnn2", 2)], frozen)
+    state1 = real.init_state(jax.random.PRNGKey(1), _sample_batch())
+    assert len(state1.frozen) == 1
+    assert template == _template_of(state1)
+    # Another batch shape is another template.
+    half = jax.tree_util.tree_map(lambda x: x[:8], _sample_batch())
+    assert traced.state_template(half) == template  # no leaf holds a batch
+    assert traced.state_template_traces == 2
+
+
+def test_state_template_names_the_builder_that_cannot_trace():
+    """No silent fall-back to the eager init: an initializer that reads
+    a value is an error that names its builder."""
+    import flax.linen as nn
+    import jax.numpy as jnp
+
+    from adanet_tpu.subnetwork import Subnetwork
+
+    class Concretizing(nn.Module):
+        @nn.compact
+        def __call__(self, features, training: bool = False):
+            x = jnp.asarray(features["x"], jnp.float32)
+            scale = float(jnp.mean(x))  # reads a value
+            logits = nn.Dense(1)(x) * scale
+            return Subnetwork(last_layer=x, logits=logits, complexity=1.0)
+
+    class Bad(DNNBuilder):
+        def build_subnetwork(self, logits_dimension, previous_ensemble=None):
+            return Concretizing()
+
+    it = _builder_factory().build_iteration(0, [Bad("reads_values")], None)
+    with pytest.raises(TypeError, match="builder 'reads_values'"):
+        it.state_template(_sample_batch())
+    assert it.state_template_traces == 0

@@ -559,6 +559,77 @@ def test_train_call_spans_nest_and_carry_their_sizes(resumed_search_events):
         assert before.end <= after.start
 
 
+def test_init_state_spans_say_which_init_ran(resumed_search_events):
+    """`iteration.init_state` is around whichever of the two ran: the
+    state's template (`abstract`, and `cached` where the `Iteration`
+    remembered it) before a restore, the real init (with its `reason`)
+    where the values are used."""
+    first, resumed = _calls(resumed_search_events)
+
+    def inits(call):
+        return [
+            dict(e.attrs, iteration=e.correlation["iteration"])
+            for e in call[1]
+            if e.name == "iteration.init_state"
+        ]
+
+    assert inits(first) == [
+        {"iteration": 0, "abstract": False, "cached": False,
+         "reason": "fresh"}
+    ]
+    # A fresh estimator resumes iteration 0 over a template it has to
+    # trace, then starts iteration 1 for real.
+    assert inits(resumed) == [
+        {"iteration": 0, "abstract": True, "cached": False},
+        {"iteration": 1, "abstract": False, "cached": False,
+         "reason": "new_iteration"},
+    ]
+
+
+def test_trace_view_renders_resume_attributes_and_counters(tmp_path, capsys):
+    """The operator's view of a resume: the `abstract` / `cached` /
+    `reason` arguments of `iteration.init_state` in the exported trace,
+    and the mechanism's two counters in the text summary."""
+    from adanet_tpu.observability import dump_installed
+
+    tracer = spans_lib.tracer()
+    was_enabled = tracer.enabled
+    tracer.enable()
+    tracer.clear()
+    try:
+        model_dir = str(tmp_path / "model")
+        est = build_estimator(model_dir)
+        est.train(input_fn, max_steps=2)
+        est.train(input_fn, max_steps=4)  # resumes, template remembered
+        assert dump_installed("post_resume")
+    finally:
+        tracer.clear()
+        if not was_enabled:
+            tracer.disable()
+
+    sys.path.insert(0, os.path.dirname(TESTS_DIR))
+    from tools import trace_view
+
+    export = str(tmp_path / "trace.json")
+    assert trace_view.main([model_dir, "--export", export]) == 0
+    text = capsys.readouterr().out
+    counters = dict(
+        line.split()
+        for line in text.splitlines()
+        if line.strip().startswith("estimator.resume.")
+    )
+    assert int(counters["estimator.resume.templates"]) >= 1
+    assert int(counters["estimator.resume.real_inits"]) >= 1
+    inits = [
+        e["args"]
+        for e in json.load(open(export))["traceEvents"]
+        if e.get("ph") == "X" and e["name"] == "iteration.init_state"
+    ]
+    assert [
+        (a["abstract"], a["cached"], a.get("reason")) for a in inits
+    ] == [(False, False, "fresh"), (True, True, None)]
+
+
 # ------------------------------------------------- trace_view / acceptance
 
 

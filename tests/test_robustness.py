@@ -376,10 +376,12 @@ def test_fault_site_checkpoint_write_torn(tmp_path, monkeypatch):
         ckpt_lib.restore_payload(d, "ckpt-2.msgpack")
 
 
-def test_legacy_batch_stats_count_migration(tmp_path):
+@pytest.mark.parametrize("abstract", [False, True])
+def test_legacy_batch_stats_count_migration(tmp_path, abstract):
     """Pre-round-5 NASNet checkpoints lack the batch_stats `count` leaf;
     strict restore injects it as converged instead of failing
-    (ADVICE r5)."""
+    (ADVICE r5). The injection reads the target's STRUCTURE: a target of
+    `jax.ShapeDtypeStruct`s (a resume's template) guides it as well."""
     import flax.linen as nn
     import jax.numpy as jnp
 
@@ -405,7 +407,12 @@ def test_legacy_batch_stats_count_migration(tmp_path):
     d = str(tmp_path)
     ckpt_lib.save_pytree(d, "legacy.msgpack", legacy)
 
-    restored = ckpt_lib.restore_pytree(d, "legacy.msgpack", variables)
+    target = variables
+    if abstract:
+        from adanet_tpu.core.iteration import abstract_state
+
+        target = abstract_state(variables)
+    restored = ckpt_lib.restore_pytree(d, "legacy.msgpack", target)
     count = restored["batch_stats"]["bn"]["count"]
     assert float(count) == pytest.approx(legacy_batch_stats_count())
     # The migrated model applies in eval mode (strict variable lookup).
@@ -684,6 +691,94 @@ def test_truncated_mid_iteration_state_rolls_back(oracle_dir, tmp_path):
     assert est2.latest_iteration_number() == 2
     assert os.path.exists(path + ".corrupt")
     assert _arch(d, 1) == _arch(oracle_dir, 1)
+
+
+def _corrupt_for_restore(model_dir, filename, cause, monkeypatch):
+    """Makes the mid-iteration restore fail AFTER the pre-train fsck
+    would have passed the file, one way for each cause."""
+    path = os.path.join(model_dir, filename)
+    if cause == "bit_rot":  # the sidecar digest no longer matches
+        with open(path, "r+b") as f:
+            f.seek(os.path.getsize(path) // 2)
+            byte = f.read(1)
+            f.seek(-1, os.SEEK_CUR)
+            f.write(bytes([byte[0] ^ 0xFF]))
+    elif cause == "wrong_shape":  # intact bytes of another architecture
+        state = ckpt_lib.restore_payload(model_dir, filename)
+        kernel = state["subnetworks"]["a"]["variables"]["params"][
+            "dense_0"
+        ]["kernel"]
+        state["subnetworks"]["a"]["variables"]["params"]["dense_0"][
+            "kernel"
+        ] = np.concatenate([kernel, kernel], axis=0)
+        ckpt_lib.save_pytree(model_dir, filename, state)
+    elif cause == "peer_failed":  # this read is sound, a peer's is not
+        from adanet_tpu.distributed import multihost
+
+        monkeypatch.setattr(jax, "process_count", lambda: 2)
+        monkeypatch.setattr(
+            multihost,
+            "allgather_host_flag",
+            lambda flag, label=None: np.asarray([flag, 1]),
+        )
+
+
+@pytest.mark.parametrize("cause", ["bit_rot", "wrong_shape", "peer_failed"])
+def test_failed_restore_ends_on_the_real_deterministic_init(
+    tmp_path, monkeypatch, cause
+):
+    """A resume restores over the state's TEMPLATE, so a restore that
+    fails (here, or on a peer: the verdict is collective) has no state
+    yet: it rolls the iteration back and runs the real init, the same
+    deterministic one on every process."""
+    from adanet_tpu.observability import metrics as metrics_lib
+    from adanet_tpu.observability import spans as spans_lib
+
+    d = str(tmp_path / "m")
+    build_estimator(d).train(input_fn, max_steps=4)  # mid-iteration 0
+    info = ckpt_lib.read_manifest(d)
+    stale = info.iteration_state_file
+    assert stale and info.global_step == 4
+    _corrupt_for_restore(d, stale, cause, monkeypatch)
+
+    est = build_estimator(d)
+    sample = next(input_fn())
+    iteration = est._build_iteration(0, sample)
+    registry = metrics_lib.registry()
+    templates = registry.counter("estimator.resume.templates").value
+    real_inits = registry.counter("estimator.resume.real_inits").value
+    tracer = spans_lib.tracer()
+    was_enabled = tracer.enabled
+    tracer.enable()
+    tracer.clear()
+    try:
+        state = est._init_or_restore_state(iteration, sample, info)
+        events = tracer.events()
+    finally:
+        tracer.clear()
+        if not was_enabled:
+            tracer.disable()
+
+    # Rolled back to the iteration's first step, the file set aside.
+    assert info.iteration_state_file is None and info.global_step == 0
+    assert os.path.exists(os.path.join(d, stale + ".corrupt"))
+    assert ckpt_lib.read_manifest(d).iteration_state_file is None
+    # The state is the real init from the estimator's own key.
+    fresh = iteration.init_state(est._iteration_rng(0), sample)
+    assert int(state.iteration_step) == 0
+    got, want = (jax.tree_util.tree_leaves(s) for s in (state, fresh))
+    assert len(got) == len(want)
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # One template (no resume served by it), then one real init.
+    inits = [e for e in events if e.name == "iteration.init_state"]
+    assert [e.attrs["abstract"] for e in inits] == [True, False]
+    assert inits[1].attrs["reason"] == "restore_failed"
+    assert registry.counter("estimator.resume.templates").value == templates
+    assert (
+        registry.counter("estimator.resume.real_inits").value
+        == real_inits + 1
+    )
 
 
 @pytest.fixture(scope="module")

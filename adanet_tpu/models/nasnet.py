@@ -14,16 +14,30 @@ batch-norm statistics and logits, static shapes throughout (cell wiring is
 Python-level, traced once), and the drop-path progress tracked as a model
 variable so the whole network stays a single jittable function of
 (params, batch).
+
+The 1x1 projections of the cells' inputs (`beginning_1x1` of the cell
+that continues a tensor, `prev_1x1` of the cell after it) are convolved
+by `NasNetA`, not by the cells, a tensor at a time for all its readers
+(`cell_specs`, `projection_sites`, `_project_1x1`): the backward pass
+then makes the tensor's gradient in ONE convolution by its readers'
+kernels concatenated on the output axis, where each reader's own
+convolution would write a gradient of the whole tensor. The cells own
+the kernels and the batch norms, so the parameter tree is the one of a
+network whose cells convolve for themselves.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, List, Optional, Sequence, Tuple
+import functools
+import itertools
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+
+from adanet_tpu.observability import metrics as metrics_lib
 
 # NASNet-A cell specifications (reference: nasnet_utils.py:483-532).
 _NORMAL_OPERATIONS = (
@@ -132,6 +146,191 @@ def calc_reduction_layers(
         int(float(pool_num) / (num_reduction_layers + 1) * num_cells)
         for pool_num in range(1, num_reduction_layers + 1)
     ]
+
+
+_CELL_KINDS = {
+    "normal": (
+        _NORMAL_OPERATIONS,
+        _NORMAL_HIDDENSTATE_INDICES,
+        _NORMAL_USED_HIDDENSTATES,
+    ),
+    "reduction": (
+        _REDUCTION_OPERATIONS,
+        _REDUCTION_HIDDENSTATE_INDICES,
+        _REDUCTION_USED_HIDDENSTATES,
+    ),
+}
+
+
+@dataclasses.dataclass(frozen=True)
+class CellSpec:
+    """One cell of the network and the two tensors it reads, by the name
+    of the module that wrote them ("stem" for the stem's output)."""
+
+    name: str
+    kind: str  # a key of _CELL_KINDS
+    filters: int
+    cell_num: int  # position among all cells: the drop-path depth
+    net: str
+    prev: Optional[str]
+    net_channels: int
+    # Channels of `prev` where the cell projects it with `prev_1x1`: it
+    # has the resolution of `net` and another width than the cell's. None
+    # where `prev` is absent, factorized-reduced or taken as it is.
+    prev_channels: Optional[int]
+
+
+def _stem_shape(config: NasNetConfig, image_width: int) -> Tuple[int, int]:
+    """(width, channels) of the stem convolution's output: the CIFAR
+    stem's 3x3 keeps the width, the ImageNet stem's is VALID at stride 2
+    (reference: nasnet.py:260-297)."""
+    if config.stem_type == "cifar":
+        return image_width, int(
+            config.num_conv_filters * config.stem_multiplier
+        )
+    if config.stem_type == "imagenet":
+        return (image_width - 3) // 2 + 1, int(32 * config.stem_multiplier)
+    raise ValueError(
+        "stem_type must be 'cifar' or 'imagenet', got %r"
+        % (config.stem_type,)
+    )
+
+
+def cell_specs(config: NasNetConfig, image_width: int = 32) -> List[CellSpec]:
+    """The cells in the order they run, for images `image_width` wide.
+
+    Widths and channels follow the modules below: a reduction cell
+    (stride 2, SAME) gives `ceil(w / 2)`, and a cell writes `filters`
+    channels for each hidden state it concatenates.
+    """
+    cfg = config
+    stem = _stem_shape(cfg, image_width)
+    imagenet = cfg.stem_type == "imagenet"
+    reduction_indices = calc_reduction_layers(
+        cfg.num_cells, cfg.num_reduction_layers
+    )
+    cells = []  # (kind, filters, name)
+    if imagenet:
+        # Two stride-2 stem reduction cells with sub-unit filter scaling
+        # (reference: nasnet.py:260-286).
+        for stem_num, scaling in enumerate(
+            (1.0 / cfg.filter_scaling_rate**2, 1.0 / cfg.filter_scaling_rate)
+        ):
+            cells.append((
+                "reduction",
+                max(1, int(cfg.num_conv_filters * scaling)),
+                "cell_stem_%d" % stem_num,
+            ))
+    filter_scaling = 1.0
+    for cell_num in range(cfg.num_cells):
+        if cell_num in reduction_indices:
+            filter_scaling *= cfg.filter_scaling_rate
+            cells.append((
+                "reduction",
+                int(cfg.num_conv_filters * filter_scaling),
+                "reduction_cell_%d" % reduction_indices.index(cell_num),
+            ))
+        cells.append((
+            "normal",
+            int(cfg.num_conv_filters * filter_scaling),
+            "cell_%d" % cell_num,
+        ))
+
+    # (name, width, channels) of the tensors written so far.
+    written = [("stem",) + stem]
+    specs = []
+    for cell_num, (kind, filters, name) in enumerate(cells):
+        net, width, channels = written[-1]
+        prev, prev_width, prev_channels = (
+            written[-2] if len(written) > 1 else (None, None, None)
+        )
+        projects_prev = (
+            prev is not None and prev_width == width
+            and prev_channels != filters
+        )
+        specs.append(CellSpec(
+            name=name, kind=kind, filters=filters, cell_num=cell_num,
+            net=net, prev=prev, net_channels=channels,
+            prev_channels=prev_channels if projects_prev else None,
+        ))
+        written.append((
+            name,
+            -(-width // 2) if kind == "reduction" else width,
+            filters * _CELL_KINDS[kind][2].count(0),
+        ))
+    return specs
+
+
+def projection_sites(
+    config: NasNetConfig, image_width: int = 32
+) -> Dict[str, Tuple[Tuple[str, str], ...]]:
+    """{tensor: its readers through a plain relu -> 1x1 -> bn projection},
+    each reader a (cell, kernel) pair such as `("cell_2", "prev_1x1")`.
+    `NasNetA` projects a tensor for all of them at once (`_project_1x1`):
+    a tensor with two readers is a shared site, whose gradient comes out
+    of one convolution; one with a single reader is a single site.
+    """
+    return _sites(cell_specs(config, image_width))
+
+
+def _sites(specs: Sequence[CellSpec]):
+    sites: Dict[str, List[Tuple[str, str]]] = {}
+    for spec in specs:
+        sites.setdefault(spec.net, []).append((spec.name, "beginning_1x1"))
+        if spec.prev_channels is not None:
+            sites.setdefault(spec.prev, []).append((spec.name, "prev_1x1"))
+    return {tensor: tuple(readers) for tensor, readers in sites.items()}
+
+
+def _convolve_1x1(x, kernel, dtype):
+    """relu, then what `nn.Conv(f, (1, 1), use_bias=False, dtype=dtype)`
+    computes with `kernel`: the operands in `dtype`."""
+    return jax.lax.conv_general_dilated(
+        jnp.asarray(nn.relu(x), dtype),
+        jnp.asarray(kernel, dtype),
+        window_strides=(1, 1),
+        padding="SAME",
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+    )
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _project_1x1(x, kernels, dtype):
+    """relu, then a 1x1 convolution of `x` by each of `kernels`.
+
+    Forward, a convolution a kernel, as `nn.Conv` makes them: each fuses
+    with the batch norm that reads it. (ONE convolution by the
+    concatenated kernels was measured, PERF.md section 6, PR 30: some of
+    the convolutions that then read a slice of its output read all of
+    it, and the forward pass lost more than the convolution had gained.)
+
+    Backward, ONE convolution: the transpose of the convolution by the
+    kernels concatenated on the output axis. `x`'s gradient is written
+    once and `x` read once for the relu, and the kernels' gradients take
+    one read of `relu(x)`, where a convolution a kernel writes a gradient
+    of `x` each and adds them. Every gradient is the same sum of
+    products, rounded to `dtype` once and not once a kernel. A
+    `custom_vjp` differentiates in reverse mode only, which is all that
+    trains a candidate here.
+    """
+    return [_convolve_1x1(x, kernel, dtype) for kernel in kernels]
+
+
+def _project_1x1_fwd(x, kernels, dtype):
+    return _project_1x1(x, kernels, dtype), (x, kernels)
+
+
+def _project_1x1_bwd(dtype, residuals, cotangents):
+    def together(x, kernels):
+        y = _convolve_1x1(x, jnp.concatenate(kernels, axis=-1), dtype)
+        ends = list(itertools.accumulate(k.shape[-1] for k in kernels))
+        return jnp.split(y, ends[:-1], axis=-1)
+
+    _, transpose = jax.vjp(together, *residuals)
+    return transpose(list(cotangents))
+
+
+_project_1x1.defvjp(_project_1x1_fwd, _project_1x1_bwd)
 
 
 class _DebiasedBatchNorm(nn.Module):
@@ -382,7 +581,14 @@ def _drop_path(x, keep_prob, rng):
 
 
 class _NasNetCell(nn.Module):
-    """One NASNet-A cell (reference: nasnet_utils.py:250-480)."""
+    """One NASNet-A cell (reference: nasnet_utils.py:250-480).
+
+    The cell owns the kernels of its input projections (`beginning_1x1`,
+    and `prev_1x1` where `prev_channels` says it has one) but does not
+    convolve with them: `NasNetA` reads them through `projection_kernel`,
+    projects each tensor for all its readers together (`_project_1x1`),
+    and calls the cell with what came out, which the cell normalizes.
+    """
 
     operations: Sequence[str]
     hiddenstate_indices: Sequence[int]
@@ -393,7 +599,22 @@ class _NasNetCell(nn.Module):
     total_num_cells: int
     drop_path_keep_prob: float
     compute_dtype: Any
+    net_channels: int
+    prev_channels: Optional[int] = None
     use_pallas_sep_conv: bool = False
+
+    def setup(self):
+        self.beginning_1x1 = _ConvKernel(
+            (1, 1, self.net_channels, self.filters)
+        )
+        if self.prev_channels is not None:
+            self.prev_1x1 = _ConvKernel(
+                (1, 1, self.prev_channels, self.filters)
+            )
+
+    def projection_kernel(self, name):
+        """The kernel of `beginning_1x1` or `prev_1x1`."""
+        return getattr(self, name)()
 
     def _apply_operation(
         self, x, operation, stride, is_original_input, training, progress, name
@@ -465,9 +686,12 @@ class _NasNetCell(nn.Module):
 
     def _reduce_prev_layer(self, prev_layer, curr_layer, training):
         """Matches prev layer dims to curr (reference: nasnet_utils.py:283-301)."""
-        if prev_layer is None:
-            return curr_layer
-        if prev_layer.shape[2] != curr_layer.shape[2]:
+        if self.prev_channels is not None:
+            # Projected by `prev_1x1` already.
+            prev_layer = _batch_norm(
+                prev_layer, training, "prev_bn", dtype=self.compute_dtype
+            )
+        elif prev_layer.shape[2] != curr_layer.shape[2]:
             prev_layer = nn.relu(prev_layer)
             prev_layer = _FactorizedReduction(
                 filters=self.filters,
@@ -475,31 +699,15 @@ class _NasNetCell(nn.Module):
                 compute_dtype=self.compute_dtype,
                 name="reduce_prev",
             )(prev_layer, training)
-        elif prev_layer.shape[-1] != self.filters:
-            prev_layer = nn.relu(prev_layer)
-            prev_layer = nn.Conv(
-                self.filters,
-                (1, 1),
-                use_bias=False,
-                dtype=self.compute_dtype,
-                name="prev_1x1",
-            )(prev_layer)
-            prev_layer = _batch_norm(
-                prev_layer, training, "prev_bn", dtype=self.compute_dtype
-            )
         return prev_layer
 
     @nn.compact
-    def __call__(self, net, prev_layer, training: bool, progress):
-        prev_layer = self._reduce_prev_layer(prev_layer, net, training)
-        x = nn.relu(net)
-        x = nn.Conv(
-            self.filters,
-            (1, 1),
-            use_bias=False,
-            dtype=self.compute_dtype,
-            name="beginning_1x1",
-        )(x)
+    def __call__(self, x, prev_layer, training: bool, progress):
+        """`x`: the cell's input through `beginning_1x1`. `prev_layer`:
+        the input of the cell before through `prev_1x1` where the cell
+        has one, else that input itself (this cell's own for the first
+        cell, which has none before it)."""
+        prev_layer = self._reduce_prev_layer(prev_layer, x, training)
         x = _batch_norm(
             x, training, "beginning_bn", dtype=self.compute_dtype
         )
@@ -610,61 +818,13 @@ class NasNetA(nn.Module):
         if training and not self.is_initializing():
             step.value = step.value + 1.0
 
-        if cfg.stem_type not in ("cifar", "imagenet"):
-            raise ValueError(
-                "stem_type must be 'cifar' or 'imagenet', got %r"
-                % (cfg.stem_type,)
-            )
-        num_stem_cells = 2 if cfg.stem_type == "imagenet" else 0
-        reduction_indices = calc_reduction_layers(
-            cfg.num_cells, cfg.num_reduction_layers
-        )
-        total_num_cells = (
-            cfg.num_cells + cfg.num_reduction_layers + num_stem_cells
-        )
-
-        def make_cell(kind, filters, stride, cell_num, name):
-            spec = {
-                "normal": (
-                    _NORMAL_OPERATIONS,
-                    _NORMAL_HIDDENSTATE_INDICES,
-                    _NORMAL_USED_HIDDENSTATES,
-                ),
-                "reduction": (
-                    _REDUCTION_OPERATIONS,
-                    _REDUCTION_HIDDENSTATE_INDICES,
-                    _REDUCTION_USED_HIDDENSTATES,
-                ),
-            }[kind]
-            # static_argnums counts self: (self, net, prev, training,
-            # progress) -> `training` (a Python bool steering module
-            # structure) is index 3.
-            cell_cls = (
-                nn.remat(_NasNetCell, static_argnums=(3,))
-                if cfg.remat
-                else _NasNetCell
-            )
-            return cell_cls(
-                operations=spec[0],
-                hiddenstate_indices=spec[1],
-                used_hiddenstates=spec[2],
-                filters=filters,
-                stride=stride,
-                cell_num=cell_num,
-                total_num_cells=total_num_cells,
-                drop_path_keep_prob=cfg.drop_path_keep_prob,
-                compute_dtype=cfg.compute_dtype,
-                use_pallas_sep_conv=cfg.use_pallas_sep_conv,
-                name=name,
-            )
-
-        true_cell_num = 0
+        specs = cell_specs(cfg, images.shape[2])
+        sites = _sites(specs)
+        _, stem_filters = _stem_shape(cfg, images.shape[2])
         if cfg.stem_type == "imagenet":
             # ImageNet stem: stride-2 VALID conv to halve the input, then
-            # two stride-2 stem reduction cells with sub-unit filter
-            # scaling (reference: nasnet.py:260-286) — 8x spatial
-            # reduction before the main cell stack.
-            stem_filters = int(32 * cfg.stem_multiplier)
+            # two stride-2 stem reduction cells (the first two of
+            # `cell_specs`): 8x spatial reduction before the main stack.
             net = nn.Conv(
                 stem_filters,
                 (3, 3),
@@ -677,24 +837,8 @@ class NasNetA(nn.Module):
             net = _batch_norm(
                 net, training, "conv0_bn", dtype=cfg.compute_dtype
             )
-            cell_outputs: List[Optional[jnp.ndarray]] = [None, net]
-            stem_scaling = 1.0 / (
-                cfg.filter_scaling_rate**num_stem_cells
-            )
-            for stem_num in range(num_stem_cells):
-                net = make_cell(
-                    "reduction",
-                    max(1, int(cfg.num_conv_filters * stem_scaling)),
-                    2,
-                    true_cell_num,
-                    "cell_stem_%d" % stem_num,
-                )(net, cell_outputs[-2], training, progress)
-                cell_outputs.append(net)
-                stem_scaling *= cfg.filter_scaling_rate
-                true_cell_num += 1
         else:
             # CIFAR stem: plain 3x3 conv + bn (reference: nasnet.py:288-297).
-            stem_filters = int(cfg.num_conv_filters * cfg.stem_multiplier)
             net = nn.Conv(
                 stem_filters,
                 (3, 3),
@@ -705,38 +849,86 @@ class NasNetA(nn.Module):
             net = _batch_norm(
                 net, training, "stem_bn", dtype=cfg.compute_dtype
             )
-            cell_outputs = [None, net]
 
-        aux_logits = None
-        aux_cell_index = (
-            reduction_indices[1] - 1 if len(reduction_indices) >= 2 else -1
+        # How often the shared projection engaged, for a flight dump.
+        readers = [len(site) for site in sites.values()]
+        registry = metrics_lib.registry()
+        registry.counter("nasnet.shared_1x1.sites").inc(readers.count(2))
+        registry.counter("nasnet.single_1x1.sites").inc(readers.count(1))
+        total_num_cells = (
+            cfg.num_cells
+            + cfg.num_reduction_layers
+            + (2 if cfg.stem_type == "imagenet" else 0)
         )
-        filter_scaling = 1.0
-        for cell_num in range(cfg.num_cells):
-            if cell_num in reduction_indices:
-                filter_scaling *= cfg.filter_scaling_rate
-                net = make_cell(
-                    "reduction",
-                    int(cfg.num_conv_filters * filter_scaling),
-                    2,
-                    true_cell_num,
-                    "reduction_cell_%d"
-                    % reduction_indices.index(cell_num),
-                )(net, cell_outputs[-2], training, progress)
-                true_cell_num += 1
-                cell_outputs.append(net)
-            prev_layer = cell_outputs[-2]
-            net = make_cell(
-                "normal",
-                int(cfg.num_conv_filters * filter_scaling),
-                1,
-                true_cell_num,
-                "cell_%d" % cell_num,
-            )(net, prev_layer, training, progress)
-            true_cell_num += 1
+        # static_argnums counts self: (self, x, prev, training, progress)
+        # -> `training` (a Python bool steering module structure) is
+        # index 3.
+        cell_cls = (
+            nn.remat(_NasNetCell, static_argnums=(3,))
+            if cfg.remat
+            else _NasNetCell
+        )
+        cells = {
+            spec.name: cell_cls(
+                operations=_CELL_KINDS[spec.kind][0],
+                hiddenstate_indices=_CELL_KINDS[spec.kind][1],
+                used_hiddenstates=_CELL_KINDS[spec.kind][2],
+                filters=spec.filters,
+                stride=2 if spec.kind == "reduction" else 1,
+                cell_num=spec.cell_num,
+                total_num_cells=total_num_cells,
+                drop_path_keep_prob=cfg.drop_path_keep_prob,
+                compute_dtype=cfg.compute_dtype,
+                net_channels=spec.net_channels,
+                prev_channels=spec.prev_channels,
+                use_pallas_sep_conv=cfg.use_pallas_sep_conv,
+                name=spec.name,
+            )
+            for spec in specs
+        }
+
+        written = {}
+        projected = {}
+
+        def write(name, tensor):
+            """Keeps a cell's input-to-be, and projects it for every
+            cell that reads it through a 1x1 convolution."""
+            written[name] = tensor
+            readers = sites.get(name, ())
+            if readers:
+                kernels = [
+                    cells[cell].projection_kernel(kernel)
+                    for cell, kernel in readers
+                ]
+                with jax.named_scope(
+                    "shared_1x1" if len(readers) > 1 else "single_1x1"
+                ):
+                    outputs = _project_1x1(tensor, kernels, cfg.compute_dtype)
+                projected.update(zip(readers, outputs))
+
+        write("stem", net)
+        aux_logits = None
+        reduction_indices = calc_reduction_layers(
+            cfg.num_cells, cfg.num_reduction_layers
+        )
+        aux_cell = (
+            "cell_%d" % (reduction_indices[1] - 1)
+            if len(reduction_indices) >= 2
+            else None
+        )
+        for spec in specs:
+            prev_layer = projected.get((spec.name, "prev_1x1"))
+            if prev_layer is None:
+                prev_layer = written[spec.prev or spec.net]
+            net = cells[spec.name](
+                projected[(spec.name, "beginning_1x1")],
+                prev_layer,
+                training,
+                progress,
+            )
             if (
                 cfg.use_aux_head
-                and cell_num == aux_cell_index
+                and spec.name == aux_cell
                 and cfg.num_classes
                 and training
                 # The aux head needs room for its 5x5/stride-3 pool; on
@@ -750,7 +942,7 @@ class NasNetA(nn.Module):
                     compute_dtype=cfg.compute_dtype,
                     name="aux_head",
                 )(net, training)
-            cell_outputs.append(net)
+            write(spec.name, net)
 
         # Final classifier (reference: nasnet.py:541-555).
         net = nn.relu(net)

@@ -33,8 +33,7 @@ XLA pipeline. The env fingerprint (jax, jaxlib, backend, device count;
 `utils/compile_cache_dir.py` gates the jax-internal persistent cache:
 an executable from a different build or topology is unreachable, never
 fatal. A blob that cannot be loaded or published degrades to a plain
-compile with a warning, and `store_errors` counts it (hit/miss
-accounting feeds `bench.py`'s `warm_start` section).
+compile with a warning, and `store_errors` counts it.
 """
 
 from __future__ import annotations
@@ -114,7 +113,7 @@ class CompileCache:
         self._store = store
         # Accounting lives on the process metrics registry
         # (`compile_cache.*` aggregates across every cache instance —
-        # snapshots, flight dumps, bench.py); each instance holds scoped
+        # snapshots, flight dumps); each instance holds scoped
         # CHILD counters so the long-standing per-instance attribute API
         # below (`cache.hits`, `cache.store_hits`, ...) keeps its exact
         # semantics as thin reads.

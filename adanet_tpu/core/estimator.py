@@ -284,10 +284,9 @@ class Estimator:
         worker additionally commits each batch to the accelerator
         (`jax.device_put`) before enqueueing — double-buffered device
         puts that overlap the host→device transfer of batch i+1 with
-        the device step on batch i, removing the roofline's
-        `input_pull` component from the steady-state step
-        (utils/prefetch.py `DevicePrefetchIterator`). Values are
-        unchanged; only placement/timing move.
+        the device step on batch i, taking the pull out of the
+        steady-state step (utils/prefetch.py `DevicePrefetchIterator`).
+        Values are unchanged; only placement/timing move.
       step_compute_dtype: when set (e.g. "bfloat16"), every candidate
         train step casts its float feature arrays to this dtype at the
         jit boundary (`utils/precision.py`), making the whole forward/
@@ -321,8 +320,6 @@ class Estimator:
         worker_wait_timeout_secs: float = 7200.0,
         metric_fn: Optional[Callable] = None,
         iterations_per_loop: int = 1,
-        profile_dir: Optional[str] = None,
-        profile_steps: int = 5,
         checkpoint_on_sigterm: bool = True,
         debug: bool = False,
         placement_strategy=None,
@@ -381,8 +378,6 @@ class Estimator:
         if iterations_per_loop < 1:
             raise ValueError("iterations_per_loop must be >= 1.")
         self._iterations_per_loop = int(iterations_per_loop)
-        self._profile_dir = profile_dir
-        self._profile_steps = int(profile_steps)
         # Preemption safety (SURVEY §5.3): on SIGTERM, finish the current
         # step, persist the mid-iteration state, and exit cleanly so a
         # fresh process resumes exactly. In multi-host SPMD the signal
@@ -954,8 +949,6 @@ class Estimator:
                 info.global_step,
                 iteration.candidate_names(),
             )
-            profiling = False
-            profiled = False
             # An iteration's first window in this call holds the step's
             # trace, lowering and cache load: its span says so.
             first_window = True
@@ -976,22 +969,6 @@ class Estimator:
                 and not self._should_stop_at(steps_done)
                 and (max_steps is None or info.global_step < max_steps)
             ):
-                if (
-                    self._profile_dir
-                    and not profiling
-                    and not profiled
-                    and coordination.is_chief()
-                ):
-                    # Trace the first steps of each iteration
-                    # (the aux tracing subsystem; SURVEY.md §5.1).
-                    jax.profiler.start_trace(
-                        os.path.join(
-                            self._profile_dir, "iteration_%d" % t
-                        )
-                    )
-                    profiling = True
-                    profile_stop_at = steps_done + self._profile_steps
-
                 steps_budget = self._max_iteration_steps - steps_done
                 if max_steps is not None:
                     steps_budget = min(
@@ -1079,11 +1056,6 @@ class Estimator:
                     # (collective watchdog): finish the iteration with
                     # the survivors, then stop at the boundary below.
                     self._peer_lost = executor.peer_lost_error
-                if profiling and steps_done >= profile_stop_at:
-                    jax.block_until_ready(metrics)
-                    jax.profiler.stop_trace()
-                    profiling = False
-                    profiled = True  # one trace window per iteration
                 if (
                     self._log_every_steps
                     and _crossed(
@@ -1141,10 +1113,6 @@ class Estimator:
                                 )
                     elif coordination.is_chief():
                         self._save_iteration_state(info, t, state)
-
-            if profiling:
-                jax.profiler.stop_trace()
-                profiling = False
 
             # Per-candidate bagging iterators die with the iteration;
             # close their prefetch workers now instead of letting parked

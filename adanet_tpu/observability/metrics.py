@@ -9,7 +9,7 @@ the serving front-end's watermarks). Every instrument is:
   under a per-instrument lock (no global lock on the hot path);
 - **shared**: `registry()` returns the process singleton, so one
   `snapshot()` sees every subsystem at once (the flight recorder embeds
-  it in crash dumps, `bench.py` reports it);
+  it in crash dumps);
 - **scoped**: `Counter.child()` returns a per-consumer view whose
   increments propagate to the shared aggregate while keeping an exact
   local count — how `CompileCache`/`ArtifactStore` instances keep their

@@ -64,15 +64,14 @@ class BatcherConfig:
     #: Use the generation's cascade (cheap member first, fall through
     #: to the full ensemble below the calibrated confidence margin)
     #: when one was published. False always runs the full ensemble —
-    #: the bench's cascade-off arm and the conservative default for
-    #: operators who have not validated the calibration.
+    #: the conservative choice for operators who have not validated
+    #: the calibration.
     cascade: bool = True
     #: Per-ROW cascade splitting: rows that clear the margin are
     #: answered at level 0 and only the residual rows fall through to
     #: the full ensemble as a smaller re-bucketed batch. False
     #: restores the legacy per-batch rule (any unclear row sends the
-    #: WHOLE padded batch to the full ensemble) — the bench's
-    #: split-off arm.
+    #: WHOLE padded batch to the full ensemble).
     split_rows: bool = True
     #: Shadow-canary cadence: every Nth cascade dispatch that answered
     #: rows at level 0 also runs the full ensemble on the same padded
@@ -198,9 +197,9 @@ class Batcher:
             "serving.batcher.canary_divergence"
         )
         # Cascade accounting: cheap-tier answers vs fallthroughs, and
-        # the running fallthrough rate as a gauge (the knob the ISSUE's
-        # bench section reports, and the signal an operator watches to
-        # judge whether the published threshold still fits traffic).
+        # the running fallthrough rate as a gauge (the signal an
+        # operator watches to judge whether the published threshold
+        # still fits traffic).
         self._m_cascade_cheap = reg.counter("serving.cascade.cheap_answers")
         self._m_cascade_fall = reg.counter("serving.cascade.fallthroughs")
         self._g_fallthrough = reg.gauge("serving.cascade.fallthrough_rate")

@@ -24,8 +24,8 @@ Requests arrive over the replica's unix socket (`fleet.transport`);
 the last few request batches are kept as the flip canary's live
 sample window.
 
-Runnable as a module (the unit `tools/servectl.py`, `bench.py`, and
-the chaos tests spawn):
+Runnable as a module (the unit `tools/servectl.py` and the chaos tests
+spawn):
 
     python -m adanet_tpu.serving.fleet.replica \\
         --fleet-dir /fleet --model-dir /fleet/model --replica-id r0

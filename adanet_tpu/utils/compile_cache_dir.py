@@ -138,9 +138,9 @@ def enable_persistent_cache() -> str:
     import), that directory is used exactly as given and nothing here
     sets another. Only when none is configured does the cache go to the
     fixed `versioned_cache_dir()` inside this checkout. Every entry point
-    (tests, `chip_smoke.py`, the trainer CLI, `bench.py`) comes through
-    here, so they all share one cache. Either way, entry writes become
-    crash-atomic (`install_atomic_cache_writes`).
+    (tests, `chip_smoke.py`, the trainer CLI, the benchmark) comes
+    through here, so they all share one cache. Either way, entry writes
+    become crash-atomic (`install_atomic_cache_writes`).
     """
     install_atomic_cache_writes()
     if jax.config.jax_compilation_cache_dir is not None:

@@ -22,9 +22,9 @@ exhaustion propagate to the consumer at the position they occurred.
 `prefetch_to_device`: the worker thread also *commits each batch to the
 accelerator* (`jax.device_put`) before enqueueing, so with the default
 buffer_size=2 the transfer of batch i+1 overlaps the device step on
-batch i (classic double buffering) and the roofline's `input_pull`
-component drops out of the steady-state step. Shutdown is leak-audited:
-`close()` mid-search (the Estimator's SIGTERM drain path) releases every
+batch i (classic double buffering) and the pull drops out of the
+steady-state step. Shutdown is leak-audited: `close()` mid-search
+(the Estimator's SIGTERM drain path) releases every
 device-committed buffer still parked in the queue and the worker's
 in-flight item, so neither the feeder thread nor a pinned device buffer
 outlives the iterator (tests/test_prefetch.py mocks the seam).
@@ -157,7 +157,7 @@ class DevicePrefetchIterator(PrefetchIterator):
     enqueueing, so the host→device transfer of batch i+1 proceeds while
     the consumer's step on batch i runs — with `buffer_size=2` (the
     default) this is classic double buffering and the steady-state step
-    no longer pays `input_pull` (bench.py roofline component).
+    no longer pays for the pull.
 
     `device` is forwarded to `jax.device_put`: None (commit to the
     default device), a `Device`, a `Sharding`, or a pytree of them —

@@ -477,8 +477,8 @@ def test_tiny_fleet_gate(tiny_fleet, tmp_path):
 
 def test_fleetctl_spec_builders():
     """`fleetctl launch`'s spec -> TrialSpec / dataset wiring (the
-    launch path itself runs a real fleet and is exercised by the bench
-    section; this covers the parsing layer cheaply)."""
+    launch path itself runs a real fleet; this covers the parsing layer
+    cheaply)."""
     from tools import fleetctl
 
     spec = {
@@ -596,16 +596,22 @@ def test_fleet_sigkill_at_promotion_resumes_to_oracle(
 
 
 @pytest.mark.slow
-def test_full_fleet_beats_best_single_search():
-    """The full ISSUE acceptance gate at bench scale: a 4-trial fleet
-    at equal total step budget reaches F(w) <= the best single search's
-    with >= 1 cross-trial store hit. Runs the bench section in-process
-    so the RUN_SLOW gate and BENCH_fleet_r01.json share one
-    implementation."""
-    import bench
-
-    section = bench._measure_fleet_search()
-    assert "skipped" not in section, section
-    assert section["fleet_beats_single"] is True, section
-    assert section["cross_trial_store_hits"] >= 1, section
-    assert section["equal_budget"] is True, section
+def test_full_fleet_beats_best_single_search(tmp_path):
+    """The full ISSUE acceptance gate: a 4-trial fleet at equal total
+    step budget reaches F(w) <= the best single search's with >= 1
+    cross-trial store hit."""
+    controller, comparator, make_single, input_fn = (
+        fleet_common.build_full_gate(str(tmp_path))
+    )
+    report = controller.run()
+    # Successive halving spends 4+2 iterations; the a-priori single
+    # search gets the same total.
+    single = make_single(
+        report.total_steps_trained
+        // fleet_common.FULL_GATE_ITERATION_STEPS
+    )
+    single.train(input_fn)
+    single_score = comparator.score(single, "single_baseline")
+    assert single.latest_global_step() == report.total_steps_trained
+    assert report.winner_score.objective <= single_score.objective
+    assert report.graft_hits >= 1, report

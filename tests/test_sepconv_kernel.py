@@ -164,9 +164,7 @@ def test_nasnet_pallas_flag_preserves_params_and_outputs():
 
 def test_remat_composes_with_pallas_flag():
     """NasNetConfig(remat=True, use_pallas_sep_conv=True): the
-    custom-VJP op must compose with nn.remat's checkpointing — the
-    combination the TPU perf sweep runs (bench NASNET_REMAT=1 +
-    nasnet_pallas_sepconv config)."""
+    custom-VJP op must compose with nn.remat's checkpointing."""
     from adanet_tpu.models.nasnet import NasNetA, NasNetConfig
 
     model = NasNetA(

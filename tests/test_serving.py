@@ -480,7 +480,11 @@ def test_queue_saturation_sheds_with_retry_after_then_recovers(tmp_path):
 # ----------------------------------------------------------- SIGTERM drain
 
 
-def _spawn(script, *args, env_extra=None):
+def _spawn(script, *args, env_extra=None, log_path=None):
+    """Starts a runner. Its output goes to a pipe the caller must keep
+    reading (`_wait_for_line`, `communicate`), or, with `log_path`, to
+    that file: a child nobody reads from blocks once the pipe is full
+    (one multi-KB XLA line per program loaded from a warm cache)."""
     env = dict(os.environ)
     env["PYTHONPATH"] = os.pathsep.join(
         [
@@ -491,13 +495,24 @@ def _spawn(script, *args, env_extra=None):
     )
     env.pop("ADANET_FAULTS", None)
     env.update(env_extra or {})
-    return subprocess.Popen(
-        [sys.executable, os.path.join(TESTS_DIR, script)] + list(args),
-        env=env,
-        stdout=subprocess.PIPE,
-        stderr=subprocess.STDOUT,
-        text=True,
-    )
+    cmd = [sys.executable, os.path.join(TESTS_DIR, script)] + list(args)
+    if log_path is None:
+        return subprocess.Popen(
+            cmd,
+            env=env,
+            stdout=subprocess.PIPE,
+            stderr=subprocess.STDOUT,
+            text=True,
+        )
+    with open(log_path, "ab") as log:
+        return subprocess.Popen(
+            cmd, env=env, stdout=log, stderr=subprocess.STDOUT
+        )
+
+
+def _read_log(log_path):
+    with open(log_path, errors="replace") as f:
+        return f.read()
 
 
 def _wait_for_line(proc, token, timeout=120):
@@ -570,11 +585,19 @@ def test_serve_while_search_chaos_flips_and_bit_identity(tmp_path):
     # hit) is torn mid-write + SIGKILL; gen-1's eventual flip (the
     # second serving.flip hit, after gen-0's bootstrap) is bit-rotted.
     faults.arm("serving.flip", "rot", after=1)
+    # The searchers write to files and compile into a cache of their
+    # own: nothing they print can block them, and nothing another test
+    # left in the shared cache changes what they load.
+    child_env = {"JAX_COMPILATION_CACHE_DIR": str(tmp_path / "jax_cache")}
+    log = str(tmp_path / "search.log")
     proc = _spawn(
         "serving_search_runner.py",
         model_dir,
         "3",
-        env_extra={"ADANET_FAULTS": "checkpoint.write:torn:after=1"},
+        env_extra=dict(
+            child_env, ADANET_FAULTS="checkpoint.write:torn:after=1"
+        ),
+        log_path=log,
     )
     try:
         deadline = time.time() + 240
@@ -586,8 +609,7 @@ def test_serve_while_search_chaos_flips_and_bit_identity(tmp_path):
         while proc.poll() is None and time.time() < deadline:
             send()
             time.sleep(0.02)
-        out1 = proc.stdout.read()
-        assert proc.returncode == -signal.SIGKILL, out1[-2000:]
+        assert proc.returncode == -signal.SIGKILL, _read_log(log)[-2000:]
 
         # The searcher is DEAD; the serving plane keeps answering.
         for _ in range(10):
@@ -596,11 +618,17 @@ def test_serve_while_search_chaos_flips_and_bit_identity(tmp_path):
 
         # Restart the searcher clean: fsck heals the torn write,
         # retrains iteration 1, and finishes the 3-iteration search.
-        proc = _spawn("serving_search_runner.py", model_dir, "3")
+        proc = _spawn(
+            "serving_search_runner.py",
+            model_dir,
+            "3",
+            env_extra=child_env,
+            log_path=log,
+        )
         while proc.poll() is None and time.time() < deadline:
             send()
             time.sleep(0.02)
-        out2 = proc.stdout.read()
+        out2 = _read_log(log)
         assert proc.returncode == 0, out2[-2000:]
         assert "SEARCH DONE 3" in out2
 

@@ -670,23 +670,42 @@ def test_store_chaos_two_searches_torn_rot_sigkill(oracle_dir, tmp_path):
     dir_b = str(tmp_path / "search_b")
     runner = os.path.join(TESTS_DIR, "store_chaos_runner.py")
 
-    def spawn(model_dir, faults_spec):
+    def spawn(name, model_dir, faults_spec):
+        # Output to a file (nobody reads a pipe while the two run) and a
+        # compile cache of the child's own: what a child publishes into
+        # the shared STORE is then an executable it compiled itself. One
+        # it had loaded from a warm persistent cache serializes into a
+        # blob that fails at dispatch in the sibling that deserializes
+        # it ("Function ... not found"), whichever of the two got there
+        # second.
         env = _subprocess_env()
         env["ADANET_FAULTS"] = faults_spec
-        return subprocess.Popen(
-            [sys.executable, runner, model_dir, store_root],
-            env=env,
-            stdout=subprocess.PIPE,
-            stderr=subprocess.STDOUT,
-        )
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / (name + "_cache"))
+        with open(tmp_path / (name + ".log"), "wb") as log:
+            return subprocess.Popen(
+                [sys.executable, runner, model_dir, store_root],
+                env=env,
+                stdout=log,
+                stderr=subprocess.STDOUT,
+            )
+
+    def output(name):
+        return (tmp_path / (name + ".log")).read_bytes()
+
     # A: the 5th blob publication (serving gen-0's program, mid-closure
     # publish) is torn at its final content-addressed path + SIGKILL.
     # B: the 8th (iteration 1's frozen payload) silently bit-rots; B
     # runs to completion on the corrupted store none the wiser.
-    proc_a = spawn(dir_a, "store.put:torn:after=4")
-    proc_b = spawn(dir_b, "store.put:rot:after=7")
-    out_a, _ = proc_a.communicate(timeout=300)
-    out_b, _ = proc_b.communicate(timeout=300)
+    proc_a = spawn("a", dir_a, "store.put:torn:after=4")
+    proc_b = spawn("b", dir_b, "store.put:rot:after=7")
+    try:
+        proc_a.wait(timeout=300)
+        proc_b.wait(timeout=300)
+    finally:
+        for proc in (proc_a, proc_b):
+            if proc.poll() is None:
+                proc.kill()
+    out_a, out_b = output("a"), output("b")
     assert proc_a.returncode == -signal.SIGKILL, out_a.decode()[-2000:]
     assert b"DONE" not in out_a
     assert proc_b.returncode == 0, out_b.decode()[-2000:]

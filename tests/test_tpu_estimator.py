@@ -8,6 +8,7 @@ engine runs everywhere, so these verify the host-loop batching semantics.
 import glob
 import os
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
@@ -201,15 +202,12 @@ def test_metric_fn(tmp_path):
 
 
 def test_profile_trace_written(tmp_path):
-    est = _make(
-        tmp_path,
-        max_iterations=1,
-        profile_dir=str(tmp_path / "profile"),
-        profile_steps=2,
-    )
-    est.train(linear_dataset(), max_steps=8)
+    """The operator's path: a profile taken from OUTSIDE `train`."""
+    est = _make(tmp_path, max_iterations=1)
+    trace_dir = str(tmp_path / "profile")
+    with jax.profiler.trace(trace_dir):
+        est.train(linear_dataset(), max_steps=8)
     traces = glob.glob(
-        os.path.join(str(tmp_path / "profile"), "iteration_0", "**", "*"),
-        recursive=True,
+        os.path.join(trace_dir, "**", "*.xplane.pb"), recursive=True
     )
     assert traces  # a trace directory with files was produced

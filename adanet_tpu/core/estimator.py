@@ -1638,6 +1638,13 @@ class Estimator:
                 self._summary.scalars(
                     "subnetwork", scope, scalars, global_step
                 )
+            # Summaries that a builder names for operators as well: the
+            # newest value, under the tag's own name.
+            for tag in getattr(spec.builder, "gauge_summaries", ()):
+                if tag in scalars:
+                    metrics_lib.registry().gauge(tag).set(
+                        float(scalars[tag])
+                    )
         self._summary.flush()
 
     def _iteration_rng(self, iteration_number: int):
@@ -1866,10 +1873,8 @@ class Estimator:
                     self._model_dir, info.iteration_state_file, template
                 )
                 restore_span.set(
-                    bytes=os.path.getsize(
-                        os.path.join(
-                            self._model_dir, info.iteration_state_file
-                        )
+                    **ckpt_lib.shard_stats(
+                        self._model_dir, info.iteration_state_file
                     )
                 )
         except (ckpt_lib.CheckpointCorruptionError, OSError) as exc:
@@ -1953,6 +1958,7 @@ class Estimator:
             os.remove(os.path.join(self._model_dir, filename))
         except OSError:
             pass
+        ckpt_lib.remove_shards(self._model_dir, filename)
         # The digest sidecar dies with its payload (a long search must
         # not accumulate one orphaned .sha256 per superseded ckpt).
         ckpt_lib.remove_digest(self._model_dir, filename)

@@ -16,6 +16,9 @@ from typing import Any, Dict, Mapping, Optional, Sequence, Union
 import jax
 import jax.numpy as jnp
 import optax
+from flax import struct
+
+from adanet_tpu.observability import metrics as metrics_lib
 
 
 class Head(abc.ABC):
@@ -129,6 +132,163 @@ def _broadcast_weights(weights, target):
     while w.ndim < target.ndim:
         w = w[..., None]
     return jnp.broadcast_to(w, target.shape)
+
+
+@struct.dataclass
+class BlockedLogits:
+    """Logits that are never held whole: `sum_m scale_m * (hidden_m @
+    kernel_m) + bias`, of `rows x classes`, computed `block` rows at a
+    time.
+
+    A subnetwork whose [rows, classes] float32 logits would not fit (a
+    language model's [tokens, vocabulary]) returns this in their place.
+    It takes `* weight`, `+ other` and `+ bias` as an array does, which
+    is all an ensembler with scalar or vector mixture weights asks of
+    logits, and stays unevaluated; a head then reduces it block by block
+    under `jax.checkpoint` (`reduce_rows`), so that no block outlives its
+    own loss, forward or backward. Whoever wants the array calls
+    `materialize`.
+    """
+
+    hiddens: Any  # tuple of [rows, width_m]
+    kernels: Any  # tuple of [width_m, classes]
+    scales: Any  # tuple of None, a scalar or [classes]
+    bias: Any = None  # None or [classes]
+    block: int = struct.field(pytree_node=False, default=4096)
+    compute_dtype: Any = struct.field(pytree_node=False, default=jnp.bfloat16)
+
+    @classmethod
+    def of(cls, hidden, kernel, block=4096, compute_dtype=jnp.bfloat16):
+        return cls((hidden,), (kernel,), (None,), None, block, compute_dtype)
+
+    @property
+    def shape(self):
+        return (self.hiddens[0].shape[0], self.kernels[0].shape[-1])
+
+    @property
+    def ndim(self):
+        return 2
+
+    @property
+    def dtype(self):
+        return jnp.dtype(jnp.float32)
+
+    def __mul__(self, weight):
+        if jnp.ndim(weight) > 1:
+            raise NotImplementedError(
+                "blocked logits take a scalar or per-class weight, not one "
+                "of shape %s" % (jnp.shape(weight),)
+            )
+        scaled = tuple(
+            weight if scale is None else scale * weight
+            for scale in self.scales
+        )
+        return self.replace(
+            scales=scaled,
+            bias=None if self.bias is None else self.bias * weight,
+        )
+
+    __rmul__ = __mul__
+
+    def __add__(self, other):
+        if isinstance(other, BlockedLogits):
+            if other.shape != self.shape:
+                raise ValueError(
+                    "blocked logits of shapes %s and %s do not add"
+                    % (self.shape, other.shape)
+                )
+            biases = [b for b in (self.bias, other.bias) if b is not None]
+            return self.replace(
+                hiddens=self.hiddens + other.hiddens,
+                kernels=self.kernels + other.kernels,
+                scales=self.scales + other.scales,
+                bias=sum(biases[1:], biases[0]) if biases else None,
+            )
+        if jnp.ndim(other) > 1:
+            raise NotImplementedError(
+                "blocked logits take a scalar or per-class bias, not one of "
+                "shape %s" % (jnp.shape(other),)
+            )
+        return self.replace(
+            bias=other if self.bias is None else self.bias + other
+        )
+
+    __radd__ = __add__
+
+    def _rows(self, hiddens):
+        """The logits of the rows whose hidden states are `hiddens`."""
+        total = None
+        for hidden, kernel, scale in zip(hiddens, self.kernels, self.scales):
+            part = jnp.dot(
+                hidden.astype(self.compute_dtype),
+                kernel.astype(self.compute_dtype),
+                preferred_element_type=jnp.float32,
+            )
+            if scale is not None:
+                part = part * scale
+            total = part if total is None else total + part
+        return total if self.bias is None else total + self.bias
+
+    def materialize(self):
+        return self._rows(self.hiddens)
+
+    def reduce_rows(self, fn, *per_row):
+        """Sum over all rows of `fn(logits of a block, *per_row of the
+        block)`, which returns a pytree of per-block sums. A whole number
+        of blocks; each block's logits live only inside its own
+        `jax.checkpoint`."""
+        rows = self.shape[0]
+        block = min(self.block, rows)
+        if rows % block:
+            raise ValueError(
+                "%d rows are not a whole number of blocks of %d"
+                % (rows, block)
+            )
+        metrics_lib.registry().counter("blocked_logits.row_blocks").inc(
+            rows // block
+        )
+
+        def blocks(array):
+            return array.reshape((rows // block, block) + array.shape[1:])
+
+        @jax.checkpoint
+        def one(hiddens, rest):
+            return fn(self._rows(hiddens), *rest)
+
+        def step(carry, xs):
+            part = one(*xs)
+            return jax.tree_util.tree_map(jnp.add, carry, part), None
+
+        xs = (
+            tuple(blocks(hidden) for hidden in self.hiddens),
+            tuple(blocks(array) for array in per_row),
+        )
+        first = jax.tree_util.tree_map(lambda x: x[0], xs)
+        zero = jax.tree_util.tree_map(
+            jnp.zeros_like, jax.eval_shape(one, *first)
+        )
+        with jax.named_scope("blocked_logits"):
+            total, _ = jax.lax.scan(step, zero, xs)
+        return total
+
+
+def _blocked_weighted_mean(logits, per_row_fn, labels, weights):
+    """`_weighted_mean(per_row_fn(logits, labels), weights)` of blocked
+    logits, a block at a time."""
+    rows = logits.shape[0]
+    labels = jnp.reshape(jnp.asarray(labels, jnp.int32), (rows,))
+    if weights is None:
+        weights = jnp.ones((rows,), jnp.float32)
+    weights = jnp.reshape(
+        jnp.broadcast_to(jnp.asarray(weights, jnp.float32), (rows,)), (rows,)
+    )
+
+    def sums(block_logits, block_labels, block_weights):
+        values = per_row_fn(block_logits, block_labels)
+        return jnp.sum(values * block_weights), jnp.sum(block_weights)
+
+    total, weight = logits.reduce_rows(sums, labels, weights)
+    return total / jnp.maximum(weight, 1e-12)
 
 
 def _check_logits_dimension(logits, expected: int, head_name: str) -> None:
@@ -277,6 +437,14 @@ class MultiClassHead(Head):
         return self._n_classes
 
     def loss(self, logits, labels, weights=None):
+        if isinstance(logits, BlockedLogits):
+            _check_logits_dimension(logits, self._n_classes, self.name)
+            return _blocked_weighted_mean(
+                logits,
+                optax.softmax_cross_entropy_with_integer_labels,
+                labels,
+                weights,
+            )
         logits = jnp.asarray(logits, jnp.float32)
         _check_logits_dimension(logits, self._n_classes, self.name)
         labels = jnp.reshape(jnp.asarray(labels, jnp.int32), (-1,))
@@ -286,6 +454,8 @@ class MultiClassHead(Head):
         return _weighted_mean(per_example, weights)
 
     def predictions(self, logits):
+        if isinstance(logits, BlockedLogits):
+            logits = logits.materialize()
         logits = jnp.asarray(logits, jnp.float32)
         probabilities = jax.nn.softmax(logits, axis=-1)
         return {
@@ -295,6 +465,18 @@ class MultiClassHead(Head):
         }
 
     def eval_metrics(self, logits, labels, weights=None):
+        if isinstance(logits, BlockedLogits):
+            return {
+                "average_loss": self.loss(logits, labels, weights),
+                "accuracy": _blocked_weighted_mean(
+                    logits,
+                    lambda block, ids: jnp.asarray(
+                        jnp.argmax(block, axis=-1) == ids, jnp.float32
+                    ),
+                    labels,
+                    weights,
+                ),
+            }
         logits = jnp.asarray(logits, jnp.float32)
         labels_i = jnp.reshape(jnp.asarray(labels, jnp.int32), (-1,))
         accuracy = _weighted_mean(

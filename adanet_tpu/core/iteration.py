@@ -382,13 +382,21 @@ class Iteration:
         for spec in self.subnetwork_specs:
             rng, params_rng, dropout_rng = jax.random.split(rng, 3)
             with _must_trace("builder", spec.name):
-                variables = spec.module.init(
+                init, init_optimizer = spec.module.init, spec.tx.init
+                if getattr(spec.builder, "jit_init", False):
+                    # One program for the parameters and one for the
+                    # optimizer's state, not a forward pass op by op: a
+                    # builder asks for it where its model is too large
+                    # to run eagerly.
+                    init = jax.jit(init, static_argnames=("training",))
+                    init_optimizer = jax.jit(init_optimizer)
+                variables = init(
                     {"params": params_rng, "dropout": dropout_rng},
                     features,
                     training=True,
                 )
                 variables = self._graft_initial_variables(spec, variables)
-                opt_state = spec.tx.init(variables["params"])
+                opt_state = init_optimizer(variables["params"])
             sub_states[spec.name] = SubnetworkTrainState(
                 variables=variables,
                 opt_state=opt_state,

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 from flax import struct
 
+from adanet_tpu.core.heads import BlockedLogits
 from adanet_tpu.ensemble.ensembler import Ensemble, Ensembler
 
 
@@ -276,6 +277,10 @@ class ComplexityRegularizedEnsembler(Ensembler):
         if not self._use_fused_combine or keys is not None:
             return False
         if self._mixture_weight_type == MixtureWeightType.MATRIX:
+            return False
+        if any(isinstance(s.logits, BlockedLogits) for s in subnetworks):
+            # Never stacked: the combine stays a sum that a head
+            # evaluates a block of rows at a time.
             return False
         shape = subnetworks[0].logits.shape
         return all(s.logits.shape == shape for s in subnetworks)

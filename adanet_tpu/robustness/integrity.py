@@ -31,7 +31,7 @@ import json
 import logging
 import os
 import re
-from typing import List, Optional
+from typing import List, Optional, Sequence
 
 from adanet_tpu.core import checkpoint as ckpt
 
@@ -203,12 +203,40 @@ def _quarantine(
         report.issues.append("would quarantine: %s" % filename)
 
 
-def fsck(model_dir: str, repair: bool = False) -> FsckReport:
+def rotted_sharded_states(model_dir: str) -> List[str]:
+    """The sharded states of a model dir of which some leaf fails its
+    digest: every leaf of every one is read and hashed, which `fsck`
+    leaves to a state's readers. `tools/ckpt_fsck.py` hands the names to
+    `fsck(condemned=...)`."""
+    try:
+        entries = sorted(os.listdir(model_dir))
+    except OSError:
+        return []
+    return [
+        name for name in entries
+        # Live payloads only: what was set aside (`.corrupt`, `.stale`)
+        # has lost its shards' directory with it.
+        if name.endswith(".msgpack")
+        and ckpt.corrupt_shards(model_dir, name)
+    ]
+
+
+def fsck(
+    model_dir: str, repair: bool = False, condemned: Sequence[str] = ()
+) -> FsckReport:
     """Verifies a model dir; with `repair`, quarantines and rolls back.
 
     Deterministic given the dir contents, so every process of a
     multi-host run computes the same healed `info`; only the chief
     passes `repair=True` and persists it.
+
+    Of a sharded state (`core/checkpoint.py`) this pass verifies the
+    index and that every shard file is there and as long as it says; the
+    restore that follows in `Estimator.train` reads and verifies every
+    leaf before it uses one and, where one fails, quarantines the state
+    and rolls back as this pass would. `condemned` names payloads that
+    count as corrupt whatever their digests say: what a caller learned
+    from a deeper read (`rotted_sharded_states`).
     """
     report = FsckReport()
     # Report-only mode (and non-chief processes) must not mutate the
@@ -304,7 +332,7 @@ def fsck(model_dir: str, repair: bool = False) -> FsckReport:
     # ------------------------------------------- mid-iteration state file
     if info.iteration_state_file:
         name = info.iteration_state_file
-        if not _payload_intact(model_dir, name, info):
+        if name in condemned or not _payload_intact(model_dir, name, info):
             report.issues.append(
                 "mid-iteration state corrupt (%s)" % name
             )
@@ -350,12 +378,32 @@ def fsck(model_dir: str, repair: bool = False) -> FsckReport:
         )
         _quarantine(model_dir, name, report, repair)
 
+    # Shard directories that no index names: a kill between the shards
+    # and the publish of a sharded state leaves one, whole and unused.
+    live = set()
+    for name in entries:
+        index = ckpt._read_index(os.path.join(model_dir, name))
+        if index is not None:
+            live.add(index["directory"])
+    for name in entries:
+        if (
+            re.fullmatch(r"ckpt-\d+\.msgpack\.shards-[^.]+", name)
+            and name not in live
+            and os.path.isdir(os.path.join(model_dir, name))
+        ):
+            _retire(
+                model_dir, name, report, repair,
+                reason="shards no index names",
+            )
+
     # Retained per-iteration final states: corruption never blocks the
     # search (they serve post-hoc eval), but garbage must not be served.
     for t in range(info.iteration_number):
         name = ckpt.final_state_filename(t)
         if os.path.exists(os.path.join(model_dir, name)):
-            if not _payload_intact(model_dir, name, info):
+            if name in condemned or not _payload_intact(
+                model_dir, name, info
+            ):
                 report.issues.append(
                     "retained candidate state corrupt (%s)" % name
                 )

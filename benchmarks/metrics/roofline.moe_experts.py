@@ -1,0 +1,22 @@
+"""Kernels: the grouped products over the held experts against the chip's
+roofline: the FLOP and the least bytes of the WORK of one step (forward
+and both gradients under balanced routing, from the configuration:
+`benchmarks/count_lm_flops.py::experts_work`) over the device time under
+scope `lm.moe_experts` (recomputation included in the time and not in the
+work), each against its peak of `peaks.json`; the larger share. Profiler
+trace + configuration."""
+
+from benchmarks import count_lm_flops, lm_reduce
+
+UNIT = "%"
+
+
+def read(record):
+    noted = lm_reduce.noted()
+    if noted is None:
+        return None
+    sizes, traffic = noted
+    return lm_reduce.roofline(
+        record, "lm.moe_experts",
+        count_lm_flops.experts_work(sizes, traffic["batch"] * traffic["seq"]),
+    )

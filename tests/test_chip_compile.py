@@ -266,3 +266,49 @@ def test_sep_conv_batch_norm_statistics_ride_in_the_convolution(one_chip):
         2 * shape[0] * shape[1] * shape[2] * shape[3]
     )
     assert passes <= 36, passes
+
+
+# The language-model candidate's two kernels (`models/moe_lm.py`) at the
+# widths of one chip's share of Mellum2 and a chunk of 4 sequences, forward
+# and backward, with `kernel=True` as `MoeLmConfig.kernels` resolves it on
+# the chip, and the cell's own attention block.
+
+
+@pytest.mark.parametrize("window", [1024, None], ids=["sliding", "full"])
+def test_attention_kernel_compiles_at_mellum2_widths(one_chip, window):
+    from adanet_tpu.ops.block_attention import block_attention
+
+    def step(q, k, v):
+        out = block_attention(q, k, v, window, 1024, kernel=True)
+        return jnp.sum(out * out)
+
+    compiled = _compile(
+        jax.grad(step, argnums=(0, 1, 2)), one_chip,
+        _sds((4, 8192, 4, 128), jnp.bfloat16),
+        _sds((4, 8192, 1, 128), jnp.bfloat16),
+        _sds((4, 8192, 1, 128), jnp.bfloat16),
+    )
+    # The kernel forward and its fused backward, not the blockwise path.
+    assert compiled.as_text().count("tpu_custom_call") >= 2
+
+
+@pytest.mark.parametrize("inner,outer", [(2304, 896), (896, 2304)])
+def test_grouped_dot_kernels_compile_at_mellum2_widths(
+    one_chip, inner, outer
+):
+    from adanet_tpu.ops.grouped_dot import grouped_dot
+
+    def step(lhs, rhs, sizes):
+        out = grouped_dot(lhs, rhs, sizes, True)
+        return jnp.sum(out * out)
+
+    compiled = _compile(
+        jax.grad(step, argnums=(0, 1)), one_chip,
+        _sds((40960, inner), jnp.bfloat16),
+        _sds((8, inner, outer), jnp.float32), _sds((8,), jnp.int32),
+    )
+    # Forward and both gradients are the grouped kernels: XLA's own
+    # ragged product (and its expansion of the ragged contraction) is
+    # what runs off the chip only.
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+    assert "ragged-dot" not in compiled.as_text()

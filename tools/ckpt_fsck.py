@@ -94,7 +94,13 @@ def main(argv=None) -> int:
 
     from adanet_tpu.robustness import integrity
 
-    report = integrity.fsck(args.model_dir, repair=args.repair)
+    # `fsck` leaves the leaves of a sharded state to whoever reads them;
+    # the operator's pass reads and hashes every one.
+    report = integrity.fsck(
+        args.model_dir,
+        repair=args.repair,
+        condemned=integrity.rotted_sharded_states(args.model_dir),
+    )
     # Serving audit: which generation the serving plane's ModelPool
     # would currently flip to (`serving_eligible` per published
     # generation), so operators can vet a flip BEFORE it happens.

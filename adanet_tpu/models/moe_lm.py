@@ -17,9 +17,11 @@ biases. Parameters are float32; matrix products run in `compute_dtype`
 norms, the residual stream and the loss are float32.
 
 Production paths: attention a block of queries at a time
-(`ops/block_attention.py`); sort-based dispatch and a grouped matrix
-product over the held experts (`moe_forward`, `ops/grouped_dot.py`); logits that are never held whole
-(`core/heads.py::BlockedLogits`), chosen by their shape.
+(`ops/block_attention.py`); sort-based dispatch, a grouped matrix
+product over the held experts and each token's sum over its experts' rows
+(`moe_forward`, `ops/grouped_dot.py`, `ops/row_combine.py`); logits that
+are never held whole (`core/heads.py::BlockedLogits`), chosen by their
+shape.
 """
 
 from __future__ import annotations
@@ -37,6 +39,7 @@ from adanet_tpu.core.heads import BlockedLogits
 from adanet_tpu.observability import metrics as metrics_lib
 from adanet_tpu.ops.block_attention import block_attention
 from adanet_tpu.ops.grouped_dot import grouped_dot
+from adanet_tpu.ops.row_combine import row_combine, take_rows
 from adanet_tpu.subnetwork import Builder, SimpleGenerator, Subnetwork
 
 SLIDING, FULL = "sliding_attention", "full_attention"
@@ -86,9 +89,10 @@ class MoeLmConfig:
     # Logits of more elements than this are never held whole (1 GiB of
     # float32): the choice is by their shape.
     whole_logits_limit: int = 1 << 28
-    # Whether attention and the experts' grouped products run the TPU's
-    # Pallas kernels (`ops/block_attention.py`, `ops/grouped_dot.py`) or
-    # plain XLA. None is resolved here, once: the kernels on a TPU.
+    # Whether attention, the experts' grouped products and the sums of
+    # their rows run the TPU's Pallas kernels (`ops/block_attention.py`,
+    # `ops/grouped_dot.py`, `ops/row_combine.py`) or plain XLA. None is
+    # resolved here, once: the kernels on a TPU.
     kernels: Optional[bool] = None
 
     def __post_init__(self):
@@ -222,28 +226,27 @@ def _experts_dense(x, top_p, local, gate, up, down, dtype):
 def _experts_sorted(
     x, top_p, local, sizes, gate, up, down, dtype, rows, kernel
 ):
-    """Sort-based dispatch: the pairs on held experts, sorted by expert,
-    gathered into `rows` rows; three grouped products; each row weighted
-    by its p and added to its token."""
+    """Sort-based dispatch: the pairs on held experts, sorted by expert
+    and within an expert by token, gathered into `rows` rows; three
+    grouped products; each row weighted by its p and added to its token
+    (`ops/row_combine.py`, as is the gather's transpose)."""
     count, k = gate.shape[0], local.shape[-1]
     held = (local >= 0) & (local < count)
     key = jnp.where(held, local, count).reshape(-1)
     with jax.named_scope("lm.moe_route"):
         order = jnp.argsort(key, stable=True)[:rows]
-        valid = (jnp.arange(rows) < jnp.sum(sizes))[:, None]
         token = order // k
-        weight = top_p.reshape(-1)[order][:, None]
-        # Rows past the last group belong to no product: zero going in and
-        # coming out, so that neither pass reads what nobody wrote.
-        xs = jnp.where(valid, x[token], 0)
+        weight = top_p.reshape(-1)[order]
+        # Rows past the last group belong to no product: zero going in,
+        # unread coming out, so that neither pass reads what nobody wrote.
+        xs = take_rows(x, token, local, sizes, kernel)
     with jax.named_scope("lm.moe_experts"):
         hidden = jax.nn.silu(
             grouped_dot(xs, gate, sizes, kernel)
         ) * grouped_dot(xs, up, sizes, kernel)
         out = grouped_dot(hidden.astype(dtype), down, sizes, kernel)
     with jax.named_scope("lm.moe_route"):
-        out = jnp.where(valid, out * weight, 0.0)
-        return jnp.zeros(x.shape, jnp.float32).at[token].add(out)
+        return row_combine(out, weight, token, local, sizes, kernel)
 
 
 def pair_rows(tokens: int, cfg: MoeLmConfig) -> int:

@@ -312,3 +312,109 @@ def test_grouped_dot_kernels_compile_at_mellum2_widths(
     # what runs off the chip only.
     assert compiled.as_text().count("tpu_custom_call") >= 3
     assert "ragged-dot" not in compiled.as_text()
+
+
+# The two row sums of the same dispatch (`ops/row_combine.py`), at a
+# chunk's shape: 40,960 rows of 2,304 into 32,768 tokens, 8 of 64 experts
+# held. The kernel's choice to interpret follows the backend, which is the
+# CPU here: the tests steer it, the program has no option for it.
+
+
+@pytest.fixture
+def compiled_row_kernel(monkeypatch):
+    from adanet_tpu.ops import row_combine
+
+    monkeypatch.setattr(row_combine, "_interpreted", lambda: False)
+    return row_combine
+
+
+_DISPATCH = (
+    _sds((40960,), jnp.int32), _sds((32768, 8), jnp.int32),
+    _sds((8,), jnp.int32),
+)
+
+
+def _scatters_of_tokens(text):
+    """XLA's scatters whose result is a chunk's [tokens, hidden], alone
+    or as the root of a fusion."""
+    return [
+        line for line in text.splitlines()
+        if re.search(r"= \w+\[32768,2304\]", line)
+        and (re.search(r"\} scatter\(", line) or '/scatter-add"' in line)
+    ]
+
+
+def _row_kernels(text):
+    return re.findall(r"%\S*row_combine\S* = \S+ custom-call\(", text)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_row_combine_compiles_at_mellum2_widths(
+    one_chip, compiled_row_kernel, dtype
+):
+    def combine(rows, weight, token, local, sizes):
+        return compiled_row_kernel.row_combine(
+            rows, weight, token, local, sizes, True
+        )
+
+    compiled = _compile(
+        combine, one_chip, _sds((40960, 2304), dtype),
+        _sds((40960,), jnp.float32), *_DISPATCH,
+    )
+    assert len(_row_kernels(compiled.as_text())) == 1
+    assert not _scatters_of_tokens(compiled.as_text())
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_gather_transpose_compiles_at_mellum2_widths(
+    one_chip, compiled_row_kernel, dtype
+):
+    def transpose(x, upstream, token, local, sizes):
+        _, pull = jax.vjp(
+            lambda x: compiled_row_kernel.take_rows(
+                x, token, local, sizes, True
+            ),
+            x,
+        )
+        return pull(upstream)[0]
+
+    compiled = _compile(
+        transpose, one_chip, _sds((32768, 2304), dtype),
+        _sds((40960, 2304), dtype), *_DISPATCH,
+    )
+    assert len(_row_kernels(compiled.as_text())) == 1
+    assert not _scatters_of_tokens(compiled.as_text())
+
+
+def test_expert_layer_gradient_holds_the_row_kernel(
+    one_chip, compiled_row_kernel
+):
+    """The gradient of `moe_forward` at the chunk's shape, as the chip
+    runs it (`kernels=True`): megablox's products, the row kernel for the
+    combine and for the gather's transpose, and no scatter whose result
+    is [32768, 2304] in either branch of the `cond`."""
+    import dataclasses
+    import json
+
+    from adanet_tpu.models import moe_lm
+    from benchmarks.factories import moe_lm as factory
+
+    with open("benchmarks/configs/mellum2_12b_ep8_4l.json") as handle:
+        sizes = json.load(handle)["members"]["mellum2_ep8_4l"]["sizes"]
+    config = factory.model_config(sizes, 12288)
+    assert config.kernels is False  # resolved by the backend: the CPU
+    config = dataclasses.replace(config, kernels=True)
+
+    def step(x, router, gate, up, down):
+        out, _ = moe_lm.moe_forward(x, router, gate, up, down, config)
+        return jnp.sum(out * out)
+
+    compiled = _compile(
+        jax.grad(step, argnums=(0, 1, 2, 3, 4)), one_chip,
+        _sds((32768, 2304), jnp.float32), _sds((2304, 64), jnp.float32),
+        _sds((8, 2304, 896), jnp.float32), _sds((8, 2304, 896), jnp.float32),
+        _sds((8, 896, 2304), jnp.float32),
+    )
+    text = compiled.as_text()
+    assert len(_row_kernels(text)) == 2
+    assert not _scatters_of_tokens(text)

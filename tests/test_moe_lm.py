@@ -2,6 +2,7 @@
 paths it forced, at a toy size on the CPU, against the plain reference
 (`benchmarks/reference/mellum2_moe.py`) on seeded weights."""
 
+import dataclasses
 import importlib
 import math
 
@@ -408,3 +409,131 @@ def test_counters_count_each_trace():
     MultiClassHead(VOCAB, top_k=0).loss(out.logits, labels)
     after = [registry.counter(name).value for name in names]
     assert [b - a for a, b in zip(before, after)] == [1, 1, 4, 4]
+
+
+# ---------------------------------------------- the row sums' kernel path
+
+_ROW_SITES = tuple(
+    "moe.row_combine.%s_sites" % kind for kind in ("kernel", "plain")
+)
+
+
+def _row_sites():
+    from adanet_tpu.observability import metrics as metrics_lib
+
+    registry = metrics_lib.registry()
+    return np.array([registry.counter(name).value for name in _ROW_SITES])
+
+
+def _wide(**changes):
+    """Sizes the row sums' kernel takes: 128 wide, and every expert held
+    (so 512 tokens x 4 choices are 2,048 pairs, whatever the router)."""
+    return sizes_of(
+        hidden_size=128, head_dim=32, experts_held=[0, 16], **changes
+    )
+
+
+@pytest.mark.parametrize(
+    "rows", [2304, 2048, 256], ids=["below", "at", "past_dense"]
+)
+def test_expert_layer_kernel_path_matches_plain(rows):
+    """`moe_forward` with the row sums' kernel (interpreted) against the
+    plain path, value and gradients to x, the router and the three expert
+    kernels, with the pairs below, at and past the buffer's rows (the last
+    takes the dense branch in both)."""
+    plain = factory.model_config(_wide(), VOCAB)
+    assert plain.kernels is False
+    kernel = dataclasses.replace(plain, kernels=True)
+    keys = jax.random.split(jax.random.PRNGKey(7), 6)
+    x = jax.random.normal(keys[0], (512, 128), jnp.float32)
+    weights = (
+        jax.random.normal(keys[1], (128, 16)) * 0.3,
+        jax.random.normal(keys[2], (16, 128, 16)) * 0.1,
+        jax.random.normal(keys[3], (16, 128, 16)) * 0.1,
+        jax.random.normal(keys[4], (16, 16, 128)) * 0.1,
+    )
+    probe = jax.random.normal(keys[5], x.shape)
+
+    def run(config):
+        before = _row_sites()
+        value, grads = jax.value_and_grad(
+            lambda x, w: jnp.sum(
+                moe_lm.moe_forward(x, *w, config, rows=rows)[0] * probe
+            ),
+            (0, 1),
+        )(x, weights)
+        return value, grads, list(_row_sites() - before)
+
+    got, got_grads, kernel_sites = run(kernel)
+    want, want_grads, plain_sites = run(plain)
+    # Both branches of the `cond` are traced whatever the load: one
+    # dispatch, two sites (the gather and the combine).
+    assert kernel_sites == [2, 0] and plain_sites == [0, 2]
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    for a, b in zip(jax.tree_util.tree_leaves(got_grads),
+                    jax.tree_util.tree_leaves(want_grads)):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+
+
+def _count_eqns(jaxpr, found):
+    """`found(eqn)` summed over a jaxpr and every jaxpr inside it."""
+    total = 0
+    for eqn in jaxpr.eqns:
+        total += found(eqn)
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (tuple, list)) else [value]:
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    total += _count_eqns(inner, found)
+    return total
+
+
+# Sites counted by one trace of the gradient below: JAX traces each of the
+# 2 layers' dispatch twice (the scan's body under `nn.remat`, and again
+# for its linearisation), two sites each.
+ROW_SITES_A_GRADIENT = 8
+
+
+@pytest.mark.parametrize("kernels", [True, False], ids=["kernel", "plain"])
+def test_gradient_holds_the_row_kernel_twice_a_layer(kernels):
+    """One `jax.make_jaxpr` of the candidate's gradient (2 layers, each
+    under `nn.remat` in a scan over chunks of 512 tokens): the kernel
+    stands twice a layer (the combine, and the gather's transpose; the
+    recomputed combine is dead) where XLA's two scatter-adds of [tokens,
+    hidden] stood, and the counters count two sites a trace of a layer."""
+    sizes = _wide(whole_logits_limit=0)
+    config = dataclasses.replace(
+        factory.model_config(sizes, VOCAB), kernels=kernels
+    )
+    module = moe_lm.MoeLm(config, VOCAB)
+    tokens = jnp.zeros((2, 256), jnp.int32)
+    params = jax.eval_shape(
+        lambda: module.init(jax.random.PRNGKey(0), {"tokens": tokens})
+    )["params"]
+    head = MultiClassHead(VOCAB, top_k=0)
+
+    def loss(params):
+        out = module.apply({"params": params}, {"tokens": tokens})
+        return head.loss(out.logits, tokens) + out.extras["balance_loss"]
+
+    before = _row_sites()
+    jaxpr = jax.make_jaxpr(jax.grad(loss))(params)
+    sites = list(_row_sites() - before)
+    calls = _count_eqns(
+        jaxpr.jaxpr,
+        lambda eqn: eqn.primitive.name == "pallas_call"
+        and "row_combine" in str(eqn.params.get("name", ""))
+        + str(eqn.params.get("name_and_src_info", "")),
+    )
+    scatters = _count_eqns(
+        jaxpr.jaxpr,
+        lambda eqn: eqn.primitive.name == "scatter-add"
+        and eqn.outvars[0].aval.shape == (512, 128),
+    )
+    layers = len(config.layer_types)
+    if kernels:
+        assert (calls, scatters) == (2 * layers, 0)
+        assert sites[1] == 0 and sites[0] == ROW_SITES_A_GRADIENT
+    else:
+        assert (calls, scatters) == (0, 2 * layers)
+        assert sites[0] == 0 and sites[1] == ROW_SITES_A_GRADIENT

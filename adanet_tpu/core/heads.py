@@ -11,6 +11,7 @@ and logits are `jnp` arrays (or dicts of them for `MultiHead`).
 from __future__ import annotations
 
 import abc
+import functools
 from typing import Any, Dict, Mapping, Optional, Sequence, Union
 
 import jax
@@ -145,9 +146,10 @@ class BlockedLogits:
     It takes `* weight`, `+ other` and `+ bias` as an array does, which
     is all an ensembler with scalar or vector mixture weights asks of
     logits, and stays unevaluated; a head then reduces it block by block
-    under `jax.checkpoint` (`reduce_rows`), so that no block outlives its
-    own loss, forward or backward. Whoever wants the array calls
-    `materialize`.
+    (`reduce_rows`), so that no block outlives its own loss. A loss that
+    is differentiated (`_blocked_loss`) computes its gradients in the
+    same pass over the blocks, and its backward pass only scales them.
+    Whoever wants the array calls `materialize`.
     """
 
     hiddens: Any  # tuple of [rows, width_m]
@@ -215,28 +217,36 @@ class BlockedLogits:
 
     __radd__ = __add__
 
-    def _rows(self, hiddens):
-        """The logits of the rows whose hidden states are `hiddens`."""
-        total = None
-        for hidden, kernel, scale in zip(hiddens, self.kernels, self.scales):
-            part = jnp.dot(
+    def _parts(self, hiddens):
+        """Each member's unscaled `hidden_m @ kernel_m` over the rows
+        whose hidden states are `hiddens`."""
+        return [
+            jnp.dot(
                 hidden.astype(self.compute_dtype),
                 kernel.astype(self.compute_dtype),
                 preferred_element_type=jnp.float32,
             )
+            for hidden, kernel in zip(hiddens, self.kernels)
+        ]
+
+    def _combine(self, parts):
+        total = None
+        for part, scale in zip(parts, self.scales):
             if scale is not None:
                 part = part * scale
             total = part if total is None else total + part
         return total if self.bias is None else total + self.bias
 
+    def _rows(self, hiddens):
+        """The logits of the rows whose hidden states are `hiddens`."""
+        return self._combine(self._parts(hiddens))
+
     def materialize(self):
         return self._rows(self.hiddens)
 
-    def reduce_rows(self, fn, *per_row):
-        """Sum over all rows of `fn(logits of a block, *per_row of the
-        block)`, which returns a pytree of per-block sums. A whole number
-        of blocks; each block's logits live only inside its own
-        `jax.checkpoint`."""
+    def _blocks(self, *per_row):
+        """The hidden rows and `per_row` arrays cut into a whole number
+        of blocks, stacked on a new leading axis."""
         rows = self.shape[0]
         block = min(self.block, rows)
         if rows % block:
@@ -251,7 +261,17 @@ class BlockedLogits:
         def blocks(array):
             return array.reshape((rows // block, block) + array.shape[1:])
 
-        @jax.checkpoint
+        return (
+            tuple(blocks(hidden) for hidden in self.hiddens),
+            tuple(blocks(array) for array in per_row),
+        )
+
+    def reduce_rows(self, fn, *per_row):
+        """Sum over all rows of `fn(logits of a block, *per_row of the
+        block)`, which returns a pytree of per-block sums: one product a
+        block, and nothing kept for a backward pass (a loss that is
+        differentiated goes through `_blocked_loss`)."""
+
         def one(hiddens, rest):
             return fn(self._rows(hiddens), *rest)
 
@@ -259,10 +279,7 @@ class BlockedLogits:
             part = one(*xs)
             return jax.tree_util.tree_map(jnp.add, carry, part), None
 
-        xs = (
-            tuple(blocks(hidden) for hidden in self.hiddens),
-            tuple(blocks(array) for array in per_row),
-        )
+        xs = self._blocks(*per_row)
         first = jax.tree_util.tree_map(lambda x: x[0], xs)
         zero = jax.tree_util.tree_map(
             jnp.zeros_like, jax.eval_shape(one, *first)
@@ -272,9 +289,9 @@ class BlockedLogits:
         return total
 
 
-def _blocked_weighted_mean(logits, per_row_fn, labels, weights):
-    """`_weighted_mean(per_row_fn(logits, labels), weights)` of blocked
-    logits, a block at a time."""
+def _blocked_rows(logits, labels, weights):
+    """Labels and example weights as [rows] arrays (weights of one where
+    there are none)."""
     rows = logits.shape[0]
     labels = jnp.reshape(jnp.asarray(labels, jnp.int32), (rows,))
     if weights is None:
@@ -282,13 +299,159 @@ def _blocked_weighted_mean(logits, per_row_fn, labels, weights):
     weights = jnp.reshape(
         jnp.broadcast_to(jnp.asarray(weights, jnp.float32), (rows,)), (rows,)
     )
+    return labels, weights
 
-    def sums(block_logits, block_labels, block_weights):
-        values = per_row_fn(block_logits, block_labels)
-        return jnp.sum(values * block_weights), jnp.sum(block_weights)
 
-    total, weight = logits.reduce_rows(sums, labels, weights)
-    return total / jnp.maximum(weight, 1e-12)
+def _weight_sum(weights):
+    return jnp.maximum(jnp.sum(weights), 1e-12)
+
+
+def _blocked_weighted_mean(logits, per_row_fn, labels, weights):
+    """`_weighted_mean(per_row_fn(logits, labels), weights)` of blocked
+    logits, a block at a time, for a mean that is not differentiated."""
+    labels, weights = _blocked_rows(logits, labels, weights)
+    total = logits.reduce_rows(
+        lambda block, ids, w: jnp.sum(per_row_fn(block, ids) * w),
+        labels,
+        weights,
+    )
+    return total / _weight_sum(weights)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _blocked_loss(per_row_fn, logits, labels, weights):
+    """`_blocked_weighted_mean` of a loss over [rows] labels and weights.
+    Undifferentiated, the same scan; differentiated, `_blocked_loss_fwd`
+    computes the gradients in that scan."""
+    metrics_lib.registry().counter("blocked_logits.forward_only_sites").inc()
+    return _blocked_weighted_mean(logits, per_row_fn, labels, weights)
+
+
+def _sum_to(x, shape):
+    """`x` summed over the axes that broadcasting `shape` to it added."""
+    x = jnp.sum(x, axis=tuple(range(x.ndim - len(shape))))
+    kept = tuple(
+        axis for axis, size in enumerate(shape) if size == 1 < x.shape[axis]
+    )
+    return jnp.sum(x, axis=kept, keepdims=True) if kept else x
+
+
+def _blocked_loss_fwd(per_row_fn, logits, labels, weights):
+    """One scan over the blocks: a block's logits, its losses and their
+    cotangent under the per-row weight `w / sum(w)`, then the gradients
+    of the operands that are differentiated (`perturbed`): the rows'
+    and the kernels' through the transposed products that JAX would
+    write for `BlockedLogits._parts` (same operands, casts and float32
+    products), the scales' and the bias's as sums over the rows. The
+    residuals are these gradients, for a unit cotangent."""
+    wrt = jax.tree_util.tree_map(lambda p: p.perturbed, (logits, weights))
+    logits, labels, weights = jax.tree_util.tree_map(
+        lambda p: p.value, (logits, labels, weights)
+    )
+    wrt_logits, wrt_weights = wrt
+    metrics_lib.registry().counter(
+        "blocked_logits.grad_in_forward_sites"
+        if any(jax.tree_util.tree_leaves(wrt))
+        else "blocked_logits.forward_only_sites"
+    ).inc()
+    # Cast once, outside the scan (`astype` to its own dtype is a no-op
+    # in `_parts`).
+    cast = logits.replace(
+        kernels=tuple(k.astype(logits.compute_dtype) for k in logits.kernels)
+    )
+    total_weight = _weight_sum(weights)
+
+    def zeros_where(flags, arrays):
+        return tuple(
+            jnp.zeros(jnp.shape(a), jnp.float32) if flag else None
+            for flag, a in zip(flags, arrays)
+        )
+
+    def step(carry, xs):
+        loss, dkernels, dscales, dbias = carry
+        hiddens, (ids, w) = xs
+        parts = cast._parts(hiddens)
+        values, pull = jax.vjp(
+            lambda block: per_row_fn(block, ids), cast._combine(parts)
+        )
+        (dlogits,) = pull(w / total_weight)
+        loss = loss + jnp.sum(values * w)
+        dhiddens, new_dkernels, new_dscales = [], [], []
+        for m, (hidden, kernel, scale, part) in enumerate(
+            zip(hiddens, cast.kernels, cast.scales, parts)
+        ):
+            dpart = dlogits if scale is None else dlogits * scale
+            dhidden = dkernel = dscale = None
+            if wrt_logits.hiddens[m]:
+                dhidden = jax.lax.dot_general(
+                    dpart, kernel, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                ).astype(cast.compute_dtype).astype(hidden.dtype)
+            if wrt_logits.kernels[m]:
+                dkernel = dkernels[m] + jax.lax.dot_general(
+                    dpart, hidden.astype(cast.compute_dtype),
+                    (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                ).T.astype(cast.compute_dtype).astype(jnp.float32)
+            if dscales[m] is not None:
+                dscale = dscales[m] + _sum_to(
+                    dlogits * part, jnp.shape(scale)
+                )
+            dhiddens.append(dhidden)
+            new_dkernels.append(dkernel)
+            new_dscales.append(dscale)
+        if dbias is not None:
+            dbias = dbias + _sum_to(dlogits, jnp.shape(cast.bias))
+        carry = (loss, tuple(new_dkernels), tuple(new_dscales), dbias)
+        return carry, (tuple(dhiddens), values if wrt_weights else None)
+
+    with jax.named_scope("blocked_logits"):
+        zero = (
+            jnp.zeros((), jnp.float32),
+            zeros_where(wrt_logits.kernels, logits.kernels),
+            zeros_where(wrt_logits.scales, logits.scales),
+            zeros_where((wrt_logits.bias,), (logits.bias,))[0],
+        )
+        (total, dkernels, dscales, dbias), (dhiddens, values) = jax.lax.scan(
+            step, zero, logits._blocks(labels, weights)
+        )
+        loss = total / total_weight
+        grads = logits.replace(
+            hiddens=tuple(
+                None if d is None else d.reshape(h.shape)
+                for d, h in zip(dhiddens, logits.hiddens)
+            ),
+            kernels=tuple(
+                None if d is None else d.astype(k.dtype)
+                for d, k in zip(dkernels, logits.kernels)
+            ),
+            scales=tuple(
+                None if d is None else d.astype(jnp.result_type(s))
+                for d, s in zip(dscales, logits.scales)
+            ),
+            bias=None if dbias is None else dbias.astype(
+                jnp.result_type(logits.bias)
+            ),
+        )
+        # d(sum(v w) / sum(w)) / dw = (v - loss) / sum(w).
+        dweights = (
+            None if values is None
+            else (values.reshape(weights.shape) - loss) / total_weight
+        )
+    return loss, (grads, dweights)
+
+
+def _blocked_loss_bwd(per_row_fn, residuals, cotangent):
+    del per_row_fn
+    grads, dweights = residuals
+    with jax.named_scope("blocked_logits"):
+        grads, dweights = jax.tree_util.tree_map(
+            lambda g: (g * cotangent).astype(g.dtype), (grads, dweights)
+        )
+    return grads, None, dweights
+
+
+_blocked_loss.defvjp(_blocked_loss_fwd, _blocked_loss_bwd, symbolic_zeros=True)
 
 
 def _check_logits_dimension(logits, expected: int, head_name: str) -> None:
@@ -439,11 +602,10 @@ class MultiClassHead(Head):
     def loss(self, logits, labels, weights=None):
         if isinstance(logits, BlockedLogits):
             _check_logits_dimension(logits, self._n_classes, self.name)
-            return _blocked_weighted_mean(
-                logits,
+            return _blocked_loss(
                 optax.softmax_cross_entropy_with_integer_labels,
-                labels,
-                weights,
+                logits,
+                *_blocked_rows(logits, labels, weights),
             )
         logits = jnp.asarray(logits, jnp.float32)
         _check_logits_dimension(logits, self._n_classes, self.name)

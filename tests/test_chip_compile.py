@@ -418,3 +418,28 @@ def test_expert_layer_gradient_holds_the_row_kernel(
     text = compiled.as_text()
     assert len(_row_kernels(text)) == 2
     assert not _scatters_of_tokens(text)
+
+
+def test_head_gradient_is_three_vocabulary_products(one_chip):
+    """The gradient of the blocked head's loss at the Mellum2 share's
+    widths (131,072 bfloat16 rows of 2,304, a float32 kernel to 12,288
+    classes, blocks of 4,096): the forward's product and the two
+    gradients' in one loop over the blocks, and no fourth product over
+    the vocabulary (a recomputed forward)."""
+    from adanet_tpu.core.heads import BlockedLogits, MultiClassHead
+
+    head = MultiClassHead(12288, top_k=0)
+
+    def loss(hidden, kernel, labels):
+        logits = BlockedLogits.of(hidden, kernel, 4096, jnp.bfloat16)
+        return head.loss(logits, labels)
+
+    compiled = _compile_xla(
+        jax.value_and_grad(loss, (0, 1)), one_chip,
+        _sds((131072, 2304), jnp.bfloat16), _sds((2304, 12288), jnp.float32),
+        _sds((131072,), jnp.int32),
+    )
+    # Every product of this program is over the vocabulary: the rows'
+    # gradient contracts it, the other two write it.
+    products = re.findall(r" (?:convolution|dot)\(", compiled.as_text())
+    assert len(products) == 3

@@ -183,11 +183,35 @@ def test_key_span_skips_blocks_outside_the_window(index, window, want):
     assert key_span(index, 8, window) == want
 
 
+_SITES = tuple(
+    "blocked_logits.%s_sites" % kind
+    for kind in ("grad_in_forward", "forward_only")
+)
+
+
+def _sites():
+    from adanet_tpu.observability import metrics as metrics_lib
+
+    return [metrics_lib.registry().counter(name).value for name in _SITES]
+
+
+# Which operands the gradient is taken to (the arguments of `loss` below):
+# the member's rows and kernel (a candidate's loss), the ensemble's scale
+# and bias (an ensemble whose mixture weights train), or all of them and
+# the example weights.
+_WRT = {"rows_and_kernel": (1, 2), "scales_and_bias": (0,),
+        "all": (0, 1, 2, 3)}
+
+
+@pytest.mark.parametrize("wrt", sorted(_WRT))
 @pytest.mark.parametrize("weighted", [False, True])
-def test_blocked_loss_through_the_ensembler(weighted):
+def test_blocked_loss_through_the_ensembler(weighted, wrt):
     """`w * logits + bias` and the loss over blocks of rows against the
-    whole array: values and gradients to the ensemble's parameters and to
-    the member's hidden rows and kernel."""
+    whole array: values and gradients to the ensemble's parameters, to
+    the member's hidden rows and kernel and to the example weights,
+    whichever are differentiated; the gradients come from the forward
+    pass (`blocked_logits.grad_in_forward_sites`), an undifferentiated
+    loss is one scan (`forward_only_sites`)."""
     keys = jax.random.split(jax.random.PRNGKey(0), 4)
     hidden = jax.random.normal(keys[0], (64, 16))
     kernel = jax.random.normal(keys[1], (16, VOCAB)) * 0.3
@@ -195,10 +219,11 @@ def test_blocked_loss_through_the_ensembler(weighted):
     example_weights = (
         jax.random.uniform(keys[3], (64,)) if weighted else None
     )
+    argnums = _WRT[wrt][: 3 if example_weights is None else None]
     head = MultiClassHead(VOCAB, top_k=0)
     ensembler = ComplexityRegularizedEnsembler(use_bias=True)
 
-    def loss(params, hidden, kernel, blocked):
+    def loss(params, hidden, kernel, example_weights, blocked):
         logits = BlockedLogits.of(hidden, kernel, 16, jnp.float32)
         if not blocked:
             logits = logits.materialize()
@@ -208,18 +233,66 @@ def test_blocked_loss_through_the_ensembler(weighted):
         return head.loss(ensemble.logits, labels, example_weights)
 
     params = {"weights": [jnp.float32(0.7)], "bias": jnp.linspace(-1, 1, VOCAB)}
-    got = jax.value_and_grad(loss, (0, 1, 2))(params, hidden, kernel, True)
-    want = jax.value_and_grad(loss, (0, 1, 2))(params, hidden, kernel, False)
+    args = (params, hidden, kernel, example_weights)
+    before = _sites()
+    got = jax.value_and_grad(loss, argnums)(*args, True)
+    assert [b - a for a, b in zip(before, _sites())] == [1, 0]
+    want = jax.value_and_grad(loss, argnums)(*args, False)
     for a, b in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(want)):
         np.testing.assert_allclose(a, b, rtol=2e-5, atol=1e-6)
+    before = _sites()
     metrics = head.eval_metrics(
         BlockedLogits.of(hidden, kernel, 16, jnp.float32), labels,
         example_weights,
     )
+    assert [b - a for a, b in zip(before, _sites())] == [0, 1]
     whole = head.eval_metrics(hidden @ kernel, labels, example_weights)
     for name in ("average_loss", "accuracy"):
         np.testing.assert_allclose(metrics[name], whole[name], rtol=1e-5)
+
+
+def _vocabulary_products(jaxpr, classes):
+    """The `dot_general`s of `jaxpr` and every jaxpr inside it with a
+    dimension of `classes` in an operand or the result."""
+    from jax.extend import core as jax_core
+
+    count = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            count += any(
+                classes in var.aval.shape
+                for var in (*eqn.invars, *eqn.outvars)
+            )
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (tuple, list)) else [param]:
+                if isinstance(sub, jax_core.ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, jax_core.Jaxpr):
+                    count += _vocabulary_products(sub, classes)
+    return count
+
+
+@pytest.mark.parametrize("wrt,products", [
+    ((0, 1), 3), ((2,), 1), ((), 1),
+])
+def test_blocked_loss_gradient_is_three_products_a_block(wrt, products):
+    """A block's products over the vocabulary, as the cell runs them
+    (bfloat16 rows, a float32 kernel): the forward's, then the rows' and
+    the kernel's gradients in the same scan, and no recomputation; the
+    forward's alone where only the scale is differentiated, or nothing."""
+    hidden = jnp.ones((64, 16), jnp.bfloat16)
+    kernel = jnp.ones((16, VOCAB), jnp.float32)
+    labels = jnp.zeros((64,), jnp.int32)
+    head = MultiClassHead(VOCAB, top_k=0)
+
+    def loss(hidden, kernel, scale):
+        logits = BlockedLogits.of(hidden, kernel, 16, jnp.bfloat16)
+        return head.loss(logits * scale, labels)
+
+    fn = jax.value_and_grad(loss, wrt) if wrt else loss
+    jaxpr = jax.make_jaxpr(fn)(hidden, kernel, jnp.float32(0.5)).jaxpr
+    assert _vocabulary_products(jaxpr, VOCAB) == products
 
 
 def test_logits_are_blocked_by_shape_alone():
@@ -401,14 +474,14 @@ def test_counters_count_each_trace():
 
     registry = metrics_lib.registry()
     names = ("moe_lm.layers.sliding", "moe_lm.layers.full",
-             "moe.experts_held", "blocked_logits.row_blocks")
+             "moe.experts_held", "blocked_logits.row_blocks") + _SITES
     module, _, nested = planted(sizes_of(whole_logits_limit=0))
     tokens, labels = tokens_of()
     before = [registry.counter(name).value for name in names]
     out = module.apply({"params": nested}, {"tokens": tokens})
     MultiClassHead(VOCAB, top_k=0).loss(out.logits, labels)
     after = [registry.counter(name).value for name in names]
-    assert [b - a for a, b in zip(before, after)] == [1, 1, 4, 4]
+    assert [b - a for a, b in zip(before, after)] == [1, 1, 4, 4, 0, 1]
 
 
 # ---------------------------------------------- the row sums' kernel path

@@ -48,6 +48,7 @@ from typing import Any, Optional, Tuple
 import jax
 import numpy as np
 
+from adanet_tpu.observability import spans as spans_lib
 from adanet_tpu.robustness import faults
 from adanet_tpu.robustness.retry import with_retries
 
@@ -250,8 +251,42 @@ class CompileCache:
 
     def compile(self, jitted, *args):
         """Lower `jitted` for `args`; reuse an executable when the lowered
-        program and device assignment match a previous compile."""
-        lowered = jitted.lower(*args)
+        program and device assignment match a previous compile.
+
+        Each call is one `compile` span (`program`, `chars`, `outcome`:
+        `memory`, `store` or `backend`) whose children are its stages in
+        order: `compile.trace`, `compile.lower`, `compile.key`, then on a
+        memory miss `compile.store_load` (with a store), `compile.backend`
+        (without one, or on a store miss) and `compile.store_save` (the
+        fresh executable published). A memory hit still traces, lowers and
+        keys."""
+        tracer = spans_lib.tracer()
+        with tracer.span(
+            "compile", program=getattr(jitted, "__name__", "")
+        ) as span:
+            with tracer.span("compile.trace"):
+                traced = jitted.trace(*args)
+            with tracer.span("compile.lower"):
+                lowered = traced.lower()
+            with tracer.span("compile.key"):
+                text, key = self._key(lowered, args)
+            span.set(chars=len(text))
+            executable = self._executables.get(key)
+            if executable is not None:
+                span.set(outcome="memory")
+                self._executables.move_to_end(key)
+                self._m_hits.inc()
+                return executable
+            executable, outcome = self._load_or_compile(lowered, key)
+            span.set(outcome=outcome)
+            self._executables[key] = executable
+            while len(self._executables) > self._max_entries:
+                self._executables.popitem(last=False)
+            return executable
+
+    @staticmethod
+    def _key(lowered, args):
+        """(the canonical lowered text, the cache key) of one program."""
         # The module symbol carries the python function's name
         # (`module @jit_f`); canonicalize it so identical programs from
         # differently-named closures (each Iteration builds fresh ones)
@@ -270,45 +305,41 @@ class CompileCache:
             out_tree = jax.tree_util.tree_structure(lowered.out_info)
         except Exception:  # out_info unavailable on exotic stages
             out_tree = None
-        device_fp = _device_fingerprint(args)
-        key = (digest, device_fp, in_tree, out_tree)
-        executable = self._executables.get(key)
-        if executable is None:
-            ref_name = None
-            if self._store is not None:
-                # Persistent tier: another run sharing the store may
-                # have already paid this compile.
-                ref_name = self._store_ref_name(
-                    digest, device_fp, in_tree, out_tree
-                )
+        return text, (digest, _device_fingerprint(args), in_tree, out_tree)
+
+    def _load_or_compile(self, lowered, key):
+        """(executable, outcome): from the persistent tier (`store`), else
+        from XLA (`backend`; it may itself read its on-disk cache) and
+        published."""
+        tracer = spans_lib.tracer()
+        ref_name = None
+        if self._store is not None:
+            # Persistent tier: another run sharing the store may have
+            # already paid this compile.
+            with tracer.span("compile.store_load"):
+                ref_name = self._store_ref_name(*key)
                 executable = self._store_load(ref_name, lowered)
             if executable is not None:
                 self._m_store_hits.inc()
-            else:
-                # The compile may read a persistent on-disk XLA cache
-                # (see utils/compile_cache_dir.py): a transient I/O
-                # error there — or at the `compile_cache.read` fault
-                # site chaos runs arm — is retried with bounded
-                # deterministic backoff instead of killing a multi-hour
-                # search over one EIO.
-                def compile_once():
-                    faults.trip("compile_cache.read")
-                    return lowered.compile()
+                return executable, "store"
 
-                executable = with_retries(
-                    compile_once, label="compile-cache read"
-                )
-                self._m_misses.inc()
-                if ref_name is not None:
-                    self._m_store_misses.inc()
-                    self._store_save(ref_name, executable)
-            self._executables[key] = executable
-            while len(self._executables) > self._max_entries:
-                self._executables.popitem(last=False)
-        else:
-            self._executables.move_to_end(key)
-            self._m_hits.inc()
-        return executable
+        # The compile may read a persistent on-disk XLA cache (see
+        # utils/compile_cache_dir.py): a transient I/O error there — or
+        # at the `compile_cache.read` fault site chaos runs arm — is
+        # retried with bounded deterministic backoff instead of killing
+        # a multi-hour search over one EIO.
+        def compile_once():
+            faults.trip("compile_cache.read")
+            return lowered.compile()
+
+        with tracer.span("compile.backend"):
+            executable = with_retries(compile_once, label="compile-cache read")
+        self._m_misses.inc()
+        if ref_name is not None:
+            self._m_store_misses.inc()
+            with tracer.span("compile.store_save"):
+                self._store_save(ref_name, executable)
+        return executable, "backend"
 
     def clear(self) -> None:
         self._executables.clear()

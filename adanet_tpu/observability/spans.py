@@ -30,6 +30,17 @@ host plane on the clock of the device lanes. With no profiler session
 open the annotation is a flag check; the ring keeps its own clock.
 Instants stay ring-only.
 
+JAX's own compile work becomes ATTRIBUTES, never spans: one listener on
+`jax.monitoring`'s time-span events (`Tracer.jax_event`) adds each
+trace, lowering and backend compile to the innermost open span of the
+thread that did it, as the seconds of the union of that kind's
+intervals (`jax.trace_s`, `jax.lower_s`, `jax.compile_s`: a jit traced
+inside another is not counted twice), the union of all three (`jax.s`:
+a trace inside a lowering, or an eager compile inside a trace, counts
+once), the count of outermost backend compiles (`jax.compiles`) and
+their programs' names (`jax.programs`, the first 8). A set-up emits
+tens of thousands of such events; the ring holds 4,096 spans.
+
 The clock is injected (`clock=`), monotonic by default, and must never
 be read from jit-traced code — jaxlint JL016 enforces that repo-wide;
 device time comes from the profiler trace (`benchmarks/trace_reduce.py`
@@ -43,14 +54,29 @@ import itertools
 import os
 import threading
 import time
+from array import array
 from typing import Any, Dict, List, Optional
 
+from jax import monitoring as _monitoring
 from jax import profiler as _profiler
 
 __all__ = ["SpanEvent", "Span", "Tracer", "tracer"]
 
 #: Ring capacity of the default tracer (overridable at construction).
 DEFAULT_CAPACITY = int(os.environ.get("ADANET_TRACE_CAPACITY", "4096"))
+
+#: JAX's compile events (`jax/_src/dispatch.py`) -> the attribute of the
+#: span they land on.
+JAX_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax.trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower_s",
+    "/jax/core/compile/backend_compile_duration": "jax.compile_s",
+}
+#: The attribute of the union of all three kinds: a trace inside a
+#: lowering, or an eager compile inside a trace, counts once.
+JAX_ALL = "jax.s"
+#: How many outermost compiles' names `jax.programs` keeps.
+JAX_PROGRAMS_KEPT = 8
 
 
 class SpanEvent:
@@ -127,7 +153,7 @@ class Span:
     """An OPEN span: a context manager that records itself on exit."""
 
     __slots__ = ("_tracer", "name", "span_id", "parent_id",
-                 "correlation", "attrs", "_start", "_annotation")
+                 "correlation", "attrs", "_start", "_annotation", "_jax")
 
     def __init__(self, tracer, name, span_id, parent_id, correlation, attrs):
         self._tracer = tracer
@@ -138,11 +164,38 @@ class Span:
         self.attrs = attrs
         self._start = 0.0
         self._annotation = None
+        # attribute -> (starts, ends) of its union's disjoint runs in
+        # order, once JAX reports work here (`_absorb`).
+        self._jax = None
 
     def set(self, **attrs) -> "Span":
         """Attaches attributes to an open span (e.g. a result count)."""
         self.attrs.update(attrs)
         return self
+
+    def _absorb(self, attr, start, end) -> int:
+        """Adds one of JAX's intervals to the union kept under `attr` and
+        returns how many disjoint runs the union holds. They arrive as
+        they END, on this span's own thread, and nest as the calls that
+        made them do, so what the new one overlaps is the newest kept: an
+        outer trace, reported after the traces nested in it, swallows
+        them. The runs are kept as two arrays of floats (no object the
+        garbage collector walks), until the span closes."""
+        if self._jax is None:
+            self._jax = {}
+        union = self._jax.get(attr)
+        if union is None:
+            union = self._jax[attr] = (array("d"), array("d"))
+        starts, ends = union
+        total = self.attrs.get(attr, 0.0)
+        while ends and ends[-1] >= start:
+            first, last = starts.pop(), ends.pop()
+            total -= last - first
+            start, end = min(start, first), max(end, last)
+        starts.append(start)
+        ends.append(end)
+        self.attrs[attr] = total + (end - start)
+        return len(ends)
 
     def __enter__(self) -> "Span":
         # The profiler's mirror of this span: it encodes its arguments
@@ -160,6 +213,7 @@ class Span:
             self.attrs.setdefault("error", exc_type.__name__)
         self._tracer._pop(self)
         self._annotation.__exit__(exc_type, exc, tb)
+        self._jax = None
 
 
 class _NoopSpan:
@@ -318,6 +372,31 @@ class Tracer:
             )
         )
 
+    def jax_event(self, event, start_time, end_time, fun_name="", **_):
+        """`jax.monitoring`'s time-span listener: JAX's trace, lowering
+        or backend compile, on the innermost span open on the calling
+        thread (`JAX_EVENTS`); dropped where none is open. Disabled, it
+        returns before any clock read, allocation or lock (the times
+        are JAX's own: enabled, it reads no clock either)."""
+        if not self._enabled:
+            return
+        attr = JAX_EVENTS.get(event)
+        stack = getattr(self._local, "stack", None)
+        if attr is None or not stack:
+            return
+        span = stack[-1]
+        span._absorb(JAX_ALL, start_time, end_time)
+        runs = span._absorb(attr, start_time, end_time)
+        if attr != "jax.compile_s":
+            return
+        # The outermost compiles: a compile that swallowed the ones
+        # nested in it takes their place, and their names.
+        span.attrs["jax.compiles"] = runs
+        if runs <= JAX_PROGRAMS_KEPT:
+            programs = span.attrs.setdefault("jax.programs", [])
+            del programs[runs - 1:]
+            programs.append(fun_name)
+
     def events(self) -> List[SpanEvent]:
         """Snapshot of the ring, oldest first.
 
@@ -340,6 +419,7 @@ class Tracer:
 
 
 _TRACER = Tracer()
+_monitoring.register_event_time_span_listener(_TRACER.jax_event)
 
 
 def tracer() -> Tracer:

@@ -8,12 +8,15 @@ because it keeps one live graph per iteration.
 import jax
 import numpy as np
 import optax
+import pytest
 
 from adanet_tpu.core.compile_cache import CachedStep, CompileCache
 from adanet_tpu.core.heads import RegressionHead
 from adanet_tpu.core.iteration import IterationBuilder
 from adanet_tpu.distributed import RoundRobinExecutor, RoundRobinStrategy
 from adanet_tpu.ensemble import ComplexityRegularizedEnsembler, GrowStrategy
+from adanet_tpu.observability import spans as spans_lib
+from adanet_tpu.store import ArtifactStore
 
 from helpers import DNNBuilder, linear_dataset
 
@@ -127,3 +130,106 @@ def test_estimator_search_reuses_candidate_programs(tmp_path):
         est._compile_cache.hits,
         est._compile_cache.misses,
     )
+
+
+# ------------------------------------------------- the compile path's spans
+
+
+@pytest.fixture
+def traced():
+    """The process tracer, enabled and emptied; restored after."""
+    tracer = spans_lib.tracer()
+    was_enabled = tracer.enabled
+    tracer.enable()
+    tracer.clear()
+    try:
+        yield tracer
+    finally:
+        tracer.clear()
+        if not was_enabled:
+            tracer.disable()
+
+
+def _compiles(tracer):
+    """[(the `compile` span, its children in order)] from the ring."""
+    events = tracer.events()
+    return [
+        (event, sorted(
+            (e for e in events if e.parent_id == event.span_id),
+            key=lambda e: e.start,
+        ))
+        for event in events
+        if event.name == "compile"
+    ]
+
+
+def _adanet_train_step(x):
+    return x * 4.0 - 1.0
+
+
+def test_compile_span_has_its_stages_as_children_in_order(traced):
+    x = np.ones((5,), np.float32)
+    CachedStep(_adanet_train_step, CompileCache())(x)
+    [(span, children)] = _compiles(traced)
+    assert [e.name for e in children] == [
+        "compile.trace", "compile.lower", "compile.key", "compile.backend",
+    ]
+    assert span.attrs["program"] == "_adanet_train_step"
+    assert span.attrs["chars"] > 0
+    for before, after in zip(children, children[1:]):
+        assert before.end <= after.start
+    # JAX's own work lands on the stage that caused it.
+    assert children[0].attrs["jax.trace_s"] > 0
+    assert children[1].attrs["jax.lower_s"] > 0
+    assert children[3].attrs["jax.compiles"] == 1
+    assert children[3].attrs["jax.programs"] == ["jit(_adanet_train_step)"]
+
+
+def test_compile_outcome_is_backend_then_memory(traced):
+    """A second `CachedStep` of the same function reuses the executable,
+    and still traces, lowers and keys: its span says so."""
+    cache = CompileCache()
+    x = np.ones((6,), np.float32)
+    CachedStep(_adanet_train_step, cache)(x)
+    CachedStep(_adanet_train_step, cache)(x)
+    (first, _), (second, children) = _compiles(traced)
+    assert (first.attrs["outcome"], second.attrs["outcome"]) == (
+        "backend", "memory",
+    )
+    assert [e.name for e in children] == [
+        "compile.trace", "compile.lower", "compile.key",
+    ]
+    assert second.attrs["chars"] == first.attrs["chars"]
+
+
+def test_compile_store_load_span_appears_with_a_store(traced, tmp_path):
+    store = ArtifactStore(str(tmp_path / "store"))
+    x = np.arange(7, dtype=np.float32)
+    CachedStep(_adanet_train_step, CompileCache(store=store))(x)
+    fresh = CompileCache(store=store)
+    CachedStep(_adanet_train_step, fresh)(x)
+    assert fresh.store_hits == 1
+    (first, published), (second, loaded) = _compiles(traced)
+    assert [e.name for e in published] == [
+        "compile.trace", "compile.lower", "compile.key",
+        "compile.store_load", "compile.backend", "compile.store_save",
+    ]
+    assert first.attrs["outcome"] == "backend"
+    assert [e.name for e in loaded] == [
+        "compile.trace", "compile.lower", "compile.key",
+        "compile.store_load",
+    ]
+    assert second.attrs["outcome"] == "store"
+
+
+def test_compile_children_lie_within_the_span(traced):
+    cache = CompileCache()
+    for n in (3, 3, 9):
+        CachedStep(_adanet_train_step, cache)(np.ones((n,), np.float32))
+    compiles = _compiles(traced)
+    assert len(compiles) == 3
+    for span, children in compiles:
+        assert all(
+            span.start <= e.start <= e.end <= span.end for e in children
+        )
+        assert sum(e.duration for e in children) <= span.duration

@@ -189,6 +189,151 @@ def test_disabled_tracer_reads_no_clock_and_records_nothing():
     assert tracer.events() == []
 
 
+# ------------------------------------------------ JAX's compile work
+
+
+_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_COMPILE = "/jax/core/compile/backend_compile_duration"
+_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+
+
+def test_jax_events_add_the_union_of_their_intervals_to_the_span():
+    """Nested intervals count once; disjoint ones add; each kind keeps
+    its own union and `jax.s` the union of all three, so a trace inside
+    a lowering counts once there; a backend compile also counts and
+    names itself. Events arrive in the order they end, as JAX reports
+    them."""
+    tracer = Tracer(capacity=4, clock=FakeClock())
+    with tracer.span("init_state"):
+        tracer.jax_event(_TRACE, 10.0, 11.0, fun_name="inner")
+        tracer.jax_event(_TRACE, 12.0, 13.0, fun_name="inner2")
+        tracer.jax_event(_TRACE, 9.5, 14.0, fun_name="outer")
+        tracer.jax_event(_COMPILE, 14.0, 15.0, fun_name="jit(a)")
+        tracer.jax_event(_TRACE, 16.25, 16.75, fun_name="in_lowering")
+        tracer.jax_event(_LOWER, 16.0, 17.0, fun_name="lowered")
+        tracer.jax_event(_COMPILE, 17.5, 19.5, fun_name="jit(b)")
+        tracer.jax_event(_TRACE, 20.0, 20.5, fun_name="later")
+        tracer.jax_event("/jax/unrelated", 0.0, 99.0)
+    [event] = tracer.events()
+    assert event.attrs == {
+        "jax.trace_s": pytest.approx(5.5),
+        "jax.lower_s": pytest.approx(1.0),
+        "jax.compile_s": pytest.approx(3.0),
+        "jax.s": pytest.approx(5.5 + 1.0 + 2.0 + 0.5),
+        "jax.compiles": 2,
+        "jax.programs": ["jit(a)", "jit(b)"],
+    }
+    kinds = sum(event.attrs[k] for k in
+                ("jax.trace_s", "jax.lower_s", "jax.compile_s"))
+    assert event.attrs["jax.s"] < kinds
+
+
+def test_jax_programs_keeps_the_first_names_and_counts_every_compile():
+    """`jax.programs` names the first `JAX_PROGRAMS_KEPT` outermost
+    compiles; `jax.compiles` counts all of them, and a compile that
+    swallows the ones nested in it counts once."""
+    tracer = Tracer(capacity=4, clock=FakeClock())
+    kept = spans_lib.JAX_PROGRAMS_KEPT
+    with tracer.span("init_state"):
+        for n in range(kept + 3):
+            tracer.jax_event(_COMPILE, 2.0 * n, 2.0 * n + 1.0,
+                             fun_name="jit(op%d)" % n)
+        at = 2.0 * (kept + 3)
+        tracer.jax_event(_COMPILE, at + 0.25, at + 0.5, fun_name="inner")
+        tracer.jax_event(_COMPILE, at, at + 1.0, fun_name="outer")
+    [event] = tracer.events()
+    assert event.attrs["jax.compiles"] == kept + 4
+    assert event.attrs["jax.programs"] == [
+        "jit(op%d)" % n for n in range(kept)
+    ]
+    assert event.attrs["jax.compile_s"] == pytest.approx(kept + 4.0)
+    assert event.attrs["jax.s"] == event.attrs["jax.compile_s"]
+
+
+def test_jit_inside_jit_counts_its_trace_once_under_one_span():
+    """Under a real nested jit, the span's `jax.trace_s` is no larger
+    than the span and smaller than the sum of JAX's nested events."""
+    import jax
+    import jax.numpy as jnp
+    from jax import monitoring
+
+    seen = []
+
+    def listen(event, start, end, **_):
+        if event == _TRACE:
+            seen.append(end - start)
+
+    def leaf(x):
+        return jnp.tanh(x) * 3.0
+
+    def middle(x):
+        return jax.jit(leaf)(x) + 1.0
+
+    def top(x):
+        return jax.jit(middle)(x) * 2.0
+
+    tracer = spans_lib.tracer()
+    was_enabled = tracer.enabled
+    tracer.enable()
+    tracer.clear()
+    monitoring.register_event_time_span_listener(listen)
+    try:
+        with tracer.span("nested"):
+            jax.jit(top).trace(jnp.ones((3,), jnp.float32))
+        [event] = [e for e in tracer.events() if e.name == "nested"]
+    finally:
+        monitoring.unregister_event_time_span_listener(listen)
+        tracer.clear()
+        if not was_enabled:
+            tracer.disable()
+    assert len(seen) >= 3
+    assert 0 < event.attrs["jax.trace_s"] <= event.duration
+    assert event.attrs["jax.trace_s"] < sum(seen)
+    assert event.attrs["jax.trace_s"] <= event.attrs["jax.s"]
+    assert event.attrs["jax.s"] <= event.duration
+
+
+def test_each_thread_adds_to_its_own_innermost_span():
+    import threading
+
+    tracer = Tracer(capacity=8, clock=FakeClock())
+    opened, reported = threading.Barrier(2), threading.Barrier(2)
+
+    def work(name, length):
+        with tracer.span(name):
+            with tracer.span(name + ".inner"):
+                opened.wait(timeout=10)
+                tracer.jax_event(_TRACE, 1.0, 1.0 + length)
+                reported.wait(timeout=10)
+
+    threads = [
+        threading.Thread(target=work, args=("a", 2.0)),
+        threading.Thread(target=work, args=("b", 0.5)),
+    ]
+    for thread in threads:
+        thread.start()
+    for thread in threads:
+        thread.join(timeout=10)
+        assert not thread.is_alive()
+    attrs = {e.name: e.attrs for e in tracer.events()}
+    assert attrs == {
+        "a.inner": {"jax.trace_s": 2.0, "jax.s": 2.0},
+        "b.inner": {"jax.trace_s": 0.5, "jax.s": 0.5},
+        "a": {},
+        "b": {},
+    }
+
+
+def test_jax_event_with_no_open_span_is_dropped():
+    tracer = Tracer(capacity=4, clock=FakeClock())
+    tracer.jax_event(_COMPILE, 1.0, 2.0, fun_name="jit(f)")
+    assert tracer.events() == []
+    with tracer.span("later"):
+        pass
+    [event] = tracer.events()
+    assert event.attrs == {}
+
+
 # ---------------------------------------------------------------- metrics
 
 
@@ -429,6 +574,43 @@ def test_overhead_gate_disabled_tracing_reads_no_clock(
             tracer.disable()
 
 
+def test_overhead_gate_disabled_tracing_compiles_read_no_clock(annotations):
+    """A `CachedStep` compile and a plain `jax.jit` compile under a
+    disabled tracer read no clock, build no annotation and leave no
+    attribute, even on a span left open from before."""
+    import jax
+    import numpy as np
+
+    from adanet_tpu.core.compile_cache import CachedStep, CompileCache
+
+    def gated_step(x):
+        return x * 5.0 + 2.0
+
+    def gated_plain(x):
+        return x * 7.0 - 3.0
+
+    tracer = spans_lib.tracer()
+    was_enabled = tracer.enabled
+    tracer.enable()
+    tracer.clear()
+    try:
+        with tracer.span("open") as span:
+            tracer.disable()
+            reads_before, built_before = tracer.clock_reads, len(annotations)
+            x = np.ones((11,), np.float32)
+            CachedStep(gated_step, CompileCache())(x)
+            jax.jit(gated_plain)(x).block_until_ready()
+            assert tracer.clock_reads == reads_before
+            assert len(annotations) == built_before
+            tracer.enable()
+        assert span.attrs == {}
+        assert [e.name for e in tracer.events()] == ["open"]
+    finally:
+        tracer.clear()
+        if not was_enabled:
+            tracer.disable()
+
+
 # ------------------------------------------- the spans of one train call
 
 #: Every span one resumed `Estimator.train` call leaves (docs/
@@ -567,12 +749,18 @@ def test_init_state_spans_say_which_init_ran(resumed_search_events):
     first, resumed = _calls(resumed_search_events)
 
     def inits(call):
+        # Less JAX's own work on the span (`jax.*`), asserted below.
         return [
-            dict(e.attrs, iteration=e.correlation["iteration"])
+            dict(
+                {k: v for k, v in e.attrs.items() if not k.startswith("jax.")},
+                iteration=e.correlation["iteration"],
+            )
             for e in call[1]
             if e.name == "iteration.init_state"
         ]
 
+    [fresh] = [e for e in first[1] if e.name == "iteration.init_state"]
+    assert fresh.attrs["jax.trace_s"] > 0
     assert inits(first) == [
         {"iteration": 0, "abstract": False, "cached": False,
          "reason": "fresh"}
